@@ -23,8 +23,12 @@ RUN pip install --no-cache-dir .
 # Shared file storage; chmod 777 so arbitrary-UID clusters can write
 # (reference Dockerfile:21).
 RUN mkdir -p /storage && chmod 777 /storage
+# The compile cache is placed from outside (config.jax_cache_dir): executor
+# pods mount their cache volume at this path, the one executor/Dockerfile
+# names too.
 ENV APP_FILE_STORAGE_PATH=/storage \
-    APP_EXECUTOR_BACKEND=kubernetes
+    APP_EXECUTOR_BACKEND=kubernetes \
+    JAX_COMPILATION_CACHE_DIR=/var/tmp/tpu-code-interpreter/jax-cache
 
 EXPOSE 8000 50051
 ENTRYPOINT ["python", "-m", "bee_code_interpreter_fs_tpu"]

@@ -5,7 +5,7 @@
 # verify uses bash-only ${PIPESTATUS[0]} (the ROADMAP tier-1 command verbatim).
 SHELL := /bin/bash
 
-.PHONY: all executor run health-check test test-sanitizers verify bench proto clean
+.PHONY: all executor run health-check test test-sanitizers verify bench chip-smoke proto clean
 
 all: executor
 
@@ -34,8 +34,17 @@ test-sanitizers:
 	TSAN_OPTIONS=halt_on_error=1 TEST_EXECUTOR_BINARY=$(CURDIR)/executor/build/executor-server-tsan \
 		python -m pytest tests/unit/test_executor_server.py tests/unit/test_executor_limits.py tests/unit/test_executor_cgroup.py tests/unit/test_executor_perf.py -q
 
-bench: executor
+# Both run on the chip only and rebuild the executor from source every time
+# (executor/build/ is git-ignored; a stale binary would be what runs).
+bench:
+	$(MAKE) -B -C executor
 	python bench.py
+
+# The quickest proof that the served path still starts on the chip; through
+# the builder's tool: `chiprun -- python3 chip_smoke.py` (`--chips 4` for the
+# path across four chips). It builds the executor itself.
+chip-smoke:
+	python chip_smoke.py
 
 proto:
 	scripts/genproto.sh

@@ -516,7 +516,7 @@ def forward(params, tokens, cfg: LlamaConfig, *, mesh: Mesh | None = None):
             mesh=mesh,
             in_specs=(P("dp", "sp", "tp", None),) * 3,
             out_specs=P("dp", "sp", "tp", None),
-            check_rep=False,
+            check_vma=False,
         )
 
     x = params["embed"].astype(dt)[tokens]  # [b, t, dim]

@@ -4,7 +4,7 @@ The reference project serves model workloads one Execute call at a time
 (`/root/reference/src/code_interpreter/services/code_executor.py` runs each
 request in its own sandbox); concurrent inference is purely
 process-per-request. This module adds the TPU-native alternative for the
-config-5 concurrency story (BASELINE.md): ONE resident model instance that
+config-5 concurrency story (BASELINE.json): ONE resident model instance that
 serves many requests by iteration-level (continuous) batching, the way
 production LLM servers schedule — requests join and leave the running batch
 at token boundaries instead of waiting for a full-batch generation to
@@ -24,8 +24,7 @@ TPU-first design constraints drive the shape of everything here:
 - **Fused decode bursts.** Between scheduler syncs the engine runs
   `steps_per_sync` decode steps as one `lax.scan` program (one device
   dispatch), amortizing the host<->device round trip that dominates
-  per-token dispatch on a networked accelerator (BASELINE.md: 5 663 vs
-  190 tok/s for fused vs per-step on this rig). Per-slot sequence lengths
+  per-token dispatch. Per-slot sequence lengths
   ride through the whole model as a [n_slots] position vector (per-slot
   RoPE offsets + per-slot causal masks), and cache writes are per-slot
   scatters at each slot's own frontier.

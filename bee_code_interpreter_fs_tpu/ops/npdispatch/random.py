@@ -45,13 +45,19 @@ class RandomShim(types.ModuleType):
     def __init__(self, threshold: int):
         super().__init__("numpy.random")
         self._threshold = threshold
-        # Fresh entropy per process: unseeded runs must differ across sandbox
-        # executions (Monte Carlo across runs relies on it).
-        self._key = jax.random.PRNGKey(
-            int.from_bytes(os.urandom(4), "little") & 0x7FFFFFFF
-        )
+        # Made at the first big draw, not here: a key is a device array, and
+        # install() runs in EVERY sandbox Python process (sitecustomize) —
+        # beside a runner that holds the chip, and before
+        # jax.distributed.initialize on a slice, neither of which survives
+        # an import-time backend init.
+        self._key = None
+        # Fresh entropy per process: unseeded runs must differ across
+        # sandbox executions (Monte Carlo across runs relies on it).
+        self._seed = int.from_bytes(os.urandom(4), "little")
 
     def _next_key(self):
+        if self._key is None:
+            self._key = jax.random.PRNGKey(self._seed & 0x7FFFFFFF)
         self._key, sub = jax.random.split(self._key)
         return sub
 
@@ -63,7 +69,7 @@ class RandomShim(types.ModuleType):
         real_np.random.seed(seed)
         if seed is None:
             seed = int.from_bytes(os.urandom(4), "little")
-        self._key = jax.random.PRNGKey(int(seed) & 0x7FFFFFFF)
+        self._seed, self._key = int(seed), None
 
     def default_rng(self, seed=None):
         return real_np.random.default_rng(seed)  # host generator API

@@ -22,30 +22,11 @@ from dataclasses import dataclass
 
 import jax
 import numpy as np
+from jax import shard_map  # noqa: F401 — the framework's one import point
 from jax.experimental import mesh_utils
 from jax.sharding import Mesh
 
 AXES = ("dp", "sp", "ep", "tp")
-
-
-def shard_map(f, *, mesh=None, in_specs=None, out_specs=None, check_rep=None):
-    """`jax.shard_map` with a stable keyword surface across jax versions.
-
-    jax >= 0.8 moved shard_map out of jax.experimental and renamed
-    `check_rep` to `check_vma`; older versions only have the experimental
-    one. Framework code calls this wrapper so the per-version shimming
-    lives in exactly one place.
-    """
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    if hasattr(jax, "shard_map"):
-        if check_rep is not None:
-            kwargs["check_vma"] = check_rep
-        return jax.shard_map(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as _legacy
-
-    if check_rep is not None:
-        kwargs["check_rep"] = check_rep
-    return _legacy(f, **kwargs)
 
 
 def job_mesh(n_jobs: int | None = None, *, devices=None) -> Mesh:

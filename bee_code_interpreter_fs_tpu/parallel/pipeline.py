@@ -145,7 +145,7 @@ def pipelined_transformer(params, tokens, cfg, *, mesh: Mesh,
         mesh=mesh,
         in_specs=(stage_spec, P()),
         out_specs=P("pp"),
-        check_rep=False,
+        check_vma=False,
     )(stages, micro)
     # out_specs exposes pp as the leading dim: [S*M, mb, t, dim]; only the
     # last stage's slab holds the processed microbatches.

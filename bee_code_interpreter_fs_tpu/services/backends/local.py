@@ -5,13 +5,14 @@ fresh workspace directory per sandbox. Serves three roles:
 
 1. The fake-executor test backend the reference lacked (SURVEY.md §4) — full
    e2e coverage of the orchestrator/API stack without Kubernetes.
-2. Single-host TPU dev mode: the sandbox's warm runner initializes the local
-   TPU and user code runs on it directly.
-3. The bench path: bench.py drives Execute through this backend on real TPU.
+2. Single-host TPU serving: the sandbox's warm runner attaches the local
+   TPU and user code runs on it directly (chip_smoke.py drives this path
+   through `python -m bee_code_interpreter_fs_tpu`).
 
-All sandboxes share one JAX persistent compilation cache directory, so XLA
-compiles survive across sandbox generations (SURVEY.md §7 hard part #2 —
-single-use sandboxes must not mean recompiling every request).
+All sandboxes share one JAX persistent compilation cache directory
+(config.jax_cache_dir), so XLA compiles survive across sandbox generations
+(SURVEY.md §7 hard part #2 — single-use sandboxes must not mean recompiling
+every request).
 """
 
 from __future__ import annotations
@@ -27,7 +28,7 @@ from pathlib import Path
 
 import httpx
 
-from ...config import Config
+from ...config import REPO_ROOT, Config
 from ..limits import sandbox_limit_env
 from .base import (
     Sandbox,
@@ -44,7 +45,6 @@ def _httpx_client() -> httpx.AsyncClient:
     # Control-plane↔sandbox calls are localhost; 10s covers a loaded machine.
     return httpx.AsyncClient(timeout=httpx.Timeout(10.0))
 
-REPO_ROOT = Path(__file__).resolve().parent.parent.parent.parent
 DEFAULT_BINARY = REPO_ROOT / "executor" / "build" / "executor-server"
 
 
@@ -99,7 +99,6 @@ class LocalSandboxBackend(SandboxBackend):
         config: Config | None = None,
         *,
         warm_import_jax: bool | None = None,
-        numpy_dispatch: bool = False,
     ) -> None:
         self.config = config or Config()
         binary = self.config.executor_binary or str(DEFAULT_BINARY)
@@ -113,7 +112,6 @@ class LocalSandboxBackend(SandboxBackend):
             if warm_import_jax is None
             else warm_import_jax
         )
-        self.numpy_dispatch = numpy_dispatch
         self._procs: dict[str, tuple[asyncio.subprocess.Process, str]] = {}
         # libtpu is exclusive-access: only `local_tpu_slots` warm-JAX
         # sandboxes may hold the local TPU at once. Spawns acquire a slot
@@ -125,41 +123,32 @@ class LocalSandboxBackend(SandboxBackend):
         self._build_lock = asyncio.Lock()
         self._build_failed = False  # memo: never re-run a failed auto-build
         self._slot_holders: set[str] = set()  # sandbox/host ids holding a slot
-        self._fresh_cache_epoch()
+        # Entries already in the shared cache dir when this control plane
+        # started were written by parties it never saw (a previous lifetime's
+        # tenants, or whoever handed the directory in).
+        cache_dir = self.config.jax_compilation_cache_dir
+        self._cache_dir_preexisting = bool(
+            cache_dir
+            and not self.config.compile_cache_per_sandbox
+            and os.path.isdir(cache_dir)
+            and os.listdir(cache_dir)
+        )
 
     @property
     def compile_cache_dir_scope(self) -> str:
         """Shared-dir mode (the default: one host dir, zero-copy across
         sandboxes — and the fleet-constant path jax's key hashing demands
         for cross-sandbox hits) is writable by every sandbox on this
-        control plane; per-sandbox mode gives each its own dir."""
-        return (
-            "private" if self.config.compile_cache_per_sandbox else "shared"
-        )
-
-    def _fresh_cache_epoch(self) -> None:
-        """Shared-dir mode + fleet cache on: start the shared cache dir
-        EMPTY. Its contents are harvest-vouchable only while every write
-        came from this control plane's trusted-only epoch (see
-        CodeExecutor._harvest_compile_cache) — a dir surviving a previous
-        control-plane lifetime could hold that lifetime's TENANT writes,
-        which a fresh untainted pre-warm sandbox would then present as its
-        own. The warm-start cost is bounded: the fleet store survives
-        restarts and reseeds the dir at first spawn. Kill switch off =
-        dir untouched (exact pre-cache, host-local behavior)."""
-        cache_dir = self.config.jax_compilation_cache_dir
-        if not (
-            cache_dir
-            and self.config.compile_cache_enabled
-            and not self.config.compile_cache_per_sandbox
-        ):
-            return
-        if Path(cache_dir).exists():
-            logger.info(
-                "shared JAX cache dir %s: wiping for a fresh trusted epoch",
-                cache_dir,
-            )
-            shutil.rmtree(cache_dir, ignore_errors=True)
+        control plane; per-sandbox mode gives each its own dir. Harvest only
+        vouches for entries written in this control plane's trusted-only
+        epoch (before its first tenant execute, see
+        CodeExecutor._harvest_compile_cache), so a shared dir that was not
+        empty at start is "external": sandboxes still hit it, the fleet
+        store never harvests from it. The dir itself is never wiped — it
+        may be the caller's JAX_COMPILATION_CACHE_DIR."""
+        if self.config.compile_cache_per_sandbox:
+            return "private"
+        return "external" if self._cache_dir_preexisting else "shared"
 
     def _tpu_exclusive(self) -> bool:
         """Would a warm-JAX runner grab a real (exclusive-access) TPU?
@@ -182,9 +171,8 @@ class LocalSandboxBackend(SandboxBackend):
     async def _build_binary(self) -> None:
         """Build the executor server on first use if the checkout is fresh.
 
-        `executor/build/` is gitignored, so a re-imaged machine (or a clean
-        clone) has sources but no binary — which would fail every spawn,
-        including the driver's round-end bench. Only attempted for the
+        `executor/build/` is gitignored, so a clean clone has sources but no
+        binary — which would fail every spawn. Only attempted for the
         default in-repo path; a custom `executor_binary` is the operator's
         to provide."""
         if self.binary != DEFAULT_BINARY:
@@ -374,6 +362,10 @@ class LocalSandboxBackend(SandboxBackend):
         deadline = (
             asyncio.get_running_loop().time() + self.config.executor_warm_ready_timeout
         )
+        # This control plane holds a chip slot for the sandbox: one that
+        # warmed on anything else would serve every request on the host CPU,
+        # green. (A no-JAX plumbing runner attaches nothing to check.)
+        expects_tpu = self.warm_import_jax and self._tpu_exclusive()
         async with _httpx_client() as client:
             for url in urls:
                 resp = await client.post(f"{url}/warmup")
@@ -384,6 +376,13 @@ class LocalSandboxBackend(SandboxBackend):
                     health = (await client.get(f"{url}/healthz")).json()
                     state = health.get("warm_state")
                     if health.get("warm"):
+                        if expects_tpu and health.get("backend") != "tpu":
+                            tail = self._stderr_tail([host_id])
+                            raise SandboxSpawnError(
+                                f"sandbox {host_id} warmed on backend "
+                                f"{health.get('backend')!r}, not the TPU this "
+                                f"host is expected to hold\n{tail}"
+                            )
                         del pending[host_id]
                     elif state == "failed":
                         tail = self._stderr_tail([host_id])
@@ -473,10 +472,14 @@ class LocalSandboxBackend(SandboxBackend):
         # sitecustomize (media/json patches + the gated numpy shim) is always
         # on the path — in the sandbox image it lives in site-packages
         # unconditionally; only the dispatch shim inside it is env-gated.
-        # REPO_ROOT (which exposes the npdispatch package, and with it the
-        # whole control-plane tree) is added only when the shim is on.
+        # The shim rides with the JAX runner, as in the sandbox image
+        # (executor/Dockerfile) and the kubernetes backend: a sandbox that
+        # attaches the device serves numpy code on it; the no-JAX plumbing
+        # mode (warm_import_jax=False) keeps stock numpy. REPO_ROOT (which
+        # exposes the npdispatch package, and with it the whole control-plane
+        # tree) is added only when the shim is on.
         path_entries = [str(REPO_ROOT / "executor")]
-        if self.numpy_dispatch:
+        if self.warm_import_jax:
             env["APP_NUMPY_DISPATCH"] = "1"
             path_entries.append(str(REPO_ROOT))
         env["PYTHONPATH"] = os.pathsep.join(

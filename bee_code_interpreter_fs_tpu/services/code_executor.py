@@ -398,8 +398,9 @@ class CodeExecutor:
         # for the dir — any tenant run on ANY sandbox writes the same
         # path every other sandbox's harvest manifest lists — so the
         # first tenant execute ends harvesting for this control plane's
-        # lifetime (the dir persists; the backend starts it empty, see
-        # LocalSandboxBackend._fresh_cache_epoch). Pre-warm runs before
+        # lifetime (the dir persists; a dir that was not empty at start is
+        # "external" and never harvested, see
+        # LocalSandboxBackend.compile_cache_dir_scope). Pre-warm runs before
         # tenant load, so the store still fills in the trusted-only epoch.
         self._shared_cache_tainted = False
         # Per-chip lease fencing (services/leases.py): every spawn mints a
@@ -4823,8 +4824,9 @@ class CodeExecutor:
         (local backend default — the fleet-constant path jax's key
         hashing demands) any tenant run anywhere taints the whole dir,
         so harvest stops control-plane-wide at the first tenant execute
-        (the backend starts the dir empty, so the trusted-only epoch is
-        airtight); an EXTERNAL dir (k8s PVC/hostPath) is writable by
+        (the backend reports a dir that was not empty at start as external,
+        so the trusted-only epoch is airtight); an EXTERNAL dir (k8s
+        PVC/hostPath, or a local dir with a past) is writable by
         parties this control plane never sees and is never harvested. A
         tainted dir is attacker-writable and its artifacts are serialized
         executables every seeded sandbox would run, so it gets no harvest
@@ -5232,7 +5234,7 @@ class CodeExecutor:
     def statusz(self) -> dict:
         """The consolidated operator snapshot behind GET /statusz: one JSON
         joining what previously took a Prometheus query, a /healthz read,
-        N sandbox ssh sessions, and the onchip_watch.sh grep loop — lanes
+        N sandbox ssh sessions, and a grep loop — lanes
         (queue pressure, pool depth, occupancy, breaker), hosts with their
         device-health verdicts, sessions, compile-cache store state, and
         the telemetry plane's own health (probe liveness, OTLP backlog)."""

@@ -752,21 +752,15 @@ print("prewarm reduction ok")
         # matmul block per device — what a fused multi-chip dispatch
         # compiles. Warm fleet-wide, the first batch of a shape loads from
         # cache instead of eating an XLA compile inside the batching
-        # window. Version-defensive shard_map resolution mirrors
-        # parallel/mesh.shard_map (the snippet must stand alone in the
-        # sandbox, where this package is not importable).
+        # window.
         "batched_dispatch",
         """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 devs = jax.devices()
 mesh = Mesh(np.array(devs), ("jobs",))
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
 f = jax.jit(
-    _shard_map(
+    jax.shard_map(
         lambda a, b: a @ b,
         mesh=mesh,
         in_specs=(P("jobs"), P("jobs")),

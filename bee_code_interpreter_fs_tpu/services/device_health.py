@@ -1,10 +1,9 @@
 """Device-health probe daemon: the detection half of wedge recovery.
 
 The repo's own bench history (BENCH_r03–r05) records the production failure
-mode this module exists for: a TPU attach blocking 50–76 minutes after a
-mid-device-op SIGKILL, with ``/healthz`` answering "ok" the whole time —
-nothing distinguished *busy* from *wedged*, and the recovery story was an
-operator ssh-ing into a watcher script (``scripts/onchip_watch.sh``). The
+mode this module exists for: a TPU attach that never completes, with
+``/healthz`` answering "ok" the whole time — nothing distinguished *busy*
+from *wedged*, and the recovery story was an operator in a shell. The
 ROADMAP's fencing item needs observation before it can get actuation; this
 daemon is that observation layer. A ``wedged`` verdict marks the host
 (``sandbox.meta["device_health"]``), fires ``device_wedge_detected_total``,

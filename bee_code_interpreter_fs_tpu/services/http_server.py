@@ -258,7 +258,7 @@ def _perf_series_text(key: str, row: dict) -> str:
 
 def statusz_text(body: dict) -> str:
     """Human-readable /statusz (`?format=text`): the at-a-glance view
-    that replaces the ssh-and-grep loop onchip_watch.sh encoded.
+    that replaces an operator's ssh-and-grep loop.
     Module-level (not a handler closure) so the renderer is directly
     testable against edge-case bodies — empty fleet, overflow rows,
     wedged hosts with evidence."""

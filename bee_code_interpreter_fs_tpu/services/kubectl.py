@@ -16,6 +16,8 @@ from __future__ import annotations
 import asyncio
 import json
 import logging
+import subprocess
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any
 
 logger = logging.getLogger(__name__)
@@ -51,6 +53,11 @@ class Kubectl:
     def __init__(self, binary: str = "kubectl", **defaults: Any) -> None:
         self.binary = binary
         self.defaults = defaults
+        # One blocked thread per kubectl in flight (a `wait` can sit for the
+        # whole pod-ready budget), kept off the loop's shared default pool.
+        self._threads = ThreadPoolExecutor(
+            max_workers=64, thread_name_prefix="kubectl"
+        )
 
     async def _run(
         self,
@@ -63,14 +70,28 @@ class Kubectl:
             stdin = json.dumps(stdin)
         if isinstance(stdin, str):
             stdin = stdin.encode()
-        proc = await asyncio.create_subprocess_exec(
-            self.binary,
-            *full,
-            stdin=asyncio.subprocess.PIPE if stdin is not None else None,
-            stdout=asyncio.subprocess.PIPE,
-            stderr=asyncio.subprocess.PIPE,
+        # Popen plus a worker thread, not asyncio's subprocess transport: a
+        # task cancelled while that transport is still connecting its pipes
+        # waits for an exit notification CPython 3.12 never delivers, so the
+        # fire-and-tracked deletes of a failed group spawn hung the loop's
+        # teardown for good. Here a cancelled caller returns at once. A
+        # create or delete still runs to its end in the thread (a pod half
+        # created or never deleted is the leak); any other verb has nothing
+        # to finish and is killed.
+        proc = subprocess.Popen(
+            [self.binary, *full],
+            stdin=subprocess.PIPE if stdin is not None else None,
+            stdout=subprocess.PIPE,
+            stderr=subprocess.PIPE,
         )
-        stdout, stderr = await proc.communicate(stdin)
+        try:
+            stdout, stderr = await asyncio.get_running_loop().run_in_executor(
+                self._threads, proc.communicate, stdin
+            )
+        except asyncio.CancelledError:
+            if argv[0] not in ("create", "delete"):
+                proc.kill()
+            raise
         if proc.returncode != 0:
             raise KubectlError(full, proc.returncode, stderr.decode())
         return stdout.decode()

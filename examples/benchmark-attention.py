@@ -3,7 +3,10 @@ ordinary Execute payload. Causal attention at t=16384 — a sequence length
 whose dense score matrix (t² floats per head) would be gigabytes — runs in
 one kernel with K/V tiles streaming through VMEM. Steady state over chained
 iterations (each consumes the previous output as queries) with one final
-sync, per the rig's benchmarking methodology."""
+sync.
+
+A TPU payload: the kernel lowers through Mosaic, never interpreted, and on
+any other backend the script exits non-zero."""
 
 import os
 import time
@@ -14,16 +17,18 @@ import jax.numpy as jnp
 
 from bee_code_interpreter_fs_tpu.ops.flash_attention import flash_attention
 
-ON_TPU = jax.devices()[0].platform == "tpu"
-B, T, H, D = (1, 16384, 4, 128) if ON_TPU else (1, 128, 2, 16)
+DEVICE = jax.devices()[0]
+if DEVICE.platform != "tpu":
+    raise SystemExit(
+        f"benchmark-attention.py is a TPU payload; jax attached {DEVICE.platform}"
+    )
+B, T, H, D = 1, 16384, 4, 128
 # Tile-sweep knobs (powers of two; see flash_attention's clamp rule).
 BLOCK_Q = int(os.environ.get("BENCH_BLOCK_Q", "512"))
 BLOCK_K = int(os.environ.get("BENCH_BLOCK_K", "1024"))
 T = int(os.environ.get("BENCH_SEQ_LEN", str(T)))
-# Enough chained iterations that the rig's ~65 ms host<->device sync is
-# amortized into noise (at 4 iters the sync dominated and underreported the
-# kernel ~8x).
-ITERS = 32 if ON_TPU else 2
+# One host sync ends the whole chain, so its cost is amortized over ITERS.
+ITERS = 32
 
 key = jax.random.PRNGKey(0)
 q, k, v = (
@@ -36,7 +41,7 @@ q, k, v = (
 def chain(q, k, v):
     def body(_, q):
         return flash_attention(
-            q, k, v, block_q=BLOCK_Q, block_k=BLOCK_K, interpret=not ON_TPU
+            q, k, v, block_q=BLOCK_Q, block_k=BLOCK_K
         ).astype(q.dtype)
 
     out = jax.lax.fori_loop(0, ITERS, body, q)
@@ -59,7 +64,7 @@ from bee_code_interpreter_fs_tpu.ops.flash_attention import effective_blocks
 
 eff_q, eff_k = effective_blocks(T, BLOCK_Q, BLOCK_K)
 print(
-    f"backend: {jax.devices()[0].platform} t={T} iters={ITERS} "
+    f"backend: {DEVICE.platform} t={T} iters={ITERS} "
     f"blocks={eff_q}x{eff_k}"
 )
 print(f"ATTN_TFLOPS={flops / best / 1e12:.2f}")

@@ -6,10 +6,11 @@ number that shows whether Execute-submitted user code can reach the systolic
 array's peak. Pure JAX user code (no numpy shim needed): a lax.fori_loop
 chain of DIM×DIM @ DIM×DIM bf16 matmuls — each iteration consumes the
 previous product, so XLA cannot collapse the chain — with one host sync at
-the end. Reports achieved TFLOPS and model-flops-utilization against the
-v5e bf16 peak (197 TFLOPS/chip).
+the end. Reports achieved TFLOPS and, on the device kind the peak belongs
+to, model-flops-utilization against it.
 
-On non-TPU backends (tests, CI) the shape shrinks so the script stays fast.
+A TPU payload: on any other backend it exits non-zero instead of printing the
+same markers from a shrunken CPU run.
 """
 
 import time
@@ -18,13 +19,17 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-ON_TPU = jax.devices()[0].platform == "tpu"
-DIM = 8192 if ON_TPU else 256
-# Long enough that the rig's ~65 ms host<->device sync amortizes into noise:
-# at 32 iters the sync was ~25% of the measurement and MFU read 65%; at 256
-# the same chip reads 85% (measured sweep 32/128/256 -> 65/81.5/85.0%).
-ITERS = 256 if ON_TPU else 2
-V5E_BF16_PEAK_TFLOPS = 197.0
+DEVICE = jax.devices()[0]
+if DEVICE.platform != "tpu":
+    raise SystemExit(
+        f"benchmark-matmul.py is a TPU payload; jax attached {DEVICE.platform}"
+    )
+DIM = 8192
+# One host sync ends the whole chain, so its cost is amortized over ITERS.
+ITERS = 256
+# Published bf16 peak per chip, keyed by device_kind (Google Cloud
+# documentation, "TPU v5e"). A kind that is not here gets no MFU line.
+BF16_PEAK_TFLOPS = {"TPU v5 lite": 197.0, "TPU v5e": 197.0}
 
 
 @partial(jax.jit, static_argnums=(1,))
@@ -53,8 +58,9 @@ for _ in range(3):
     best = min(best, time.perf_counter() - t0)
 
 tflops = ITERS * 2 * DIM**3 / best / 1e12
-print(f"backend: {jax.devices()[0].platform} dim={DIM} iters={ITERS}")
+print(f"backend: {DEVICE.platform} kind={DEVICE.device_kind} dim={DIM} iters={ITERS}")
 print(f"elapsed_s={best:.4f}")
 print(f"TFLOPS={tflops:.2f}")
-if ON_TPU:
-    print(f"MFU_vs_v5e_peak_pct={tflops / V5E_BF16_PEAK_TFLOPS * 100:.1f}")
+if DEVICE.device_kind not in BF16_PEAK_TFLOPS:
+    raise SystemExit(f"no published bf16 peak for device kind {DEVICE.device_kind!r}")
+print(f"MFU_vs_v5e_peak_pct={tflops / BF16_PEAK_TFLOPS[DEVICE.device_kind] * 100:.1f}")

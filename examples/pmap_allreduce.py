@@ -5,14 +5,8 @@ mesh; on a single chip it degenerates gracefully."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-# Examples are standalone sandbox payloads — the control-plane package (and
-# its parallel.mesh.shard_map compat wrapper) is not importable in the
-# sandbox, so the jax-version fallback is inlined here.
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:
-    from jax.experimental.shard_map import shard_map
 
 devices = jax.devices()
 n = len(devices)

@@ -67,46 +67,30 @@ def _log(msg: str) -> None:
         pass
 
 # Persistent-compilation-cache traffic, counted via jax.monitoring events
-# (registered in _warm_import, best-effort): the per-request delta rides the
-# execute reply so the fleet compile cache's hit rate is observable per run.
-_CACHE_EVENTS = {"hits": 0, "requests": 0, "misses": 0}
+# (registered in _warm_import): the per-request delta rides the execute
+# reply so the fleet compile cache's hit rate is observable per run.
+_CACHE_EVENTS = {"hits": 0, "misses": 0}
 _CACHE_LISTENING = False
 
 
 def _register_cache_listener() -> None:
-    """Count compilation-cache hit/miss monitoring events. jax's public
-    surface for this moved across versions, so resolve defensively — a miss
-    just means hit/miss counts stay unreported (the server's cache-dir diff
-    still reports new entries)."""
+    """Count jax's compilation-cache hit/miss monitoring events."""
     global _CACHE_LISTENING
-    try:
-        from jax._src import monitoring
-    except ImportError:
-        return
+    from jax._src import monitoring
 
     def on_event(event: str, *args, **kwargs) -> None:
         if event == "/jax/compilation_cache/cache_hits":
             _CACHE_EVENTS["hits"] += 1
-        elif event == "/jax/compilation_cache/compile_requests_use_cache":
-            _CACHE_EVENTS["requests"] += 1
         elif event == "/jax/compilation_cache/cache_misses":
             _CACHE_EVENTS["misses"] += 1
 
-    try:
-        monitoring.register_event_listener(on_event)
-        _CACHE_LISTENING = True
-    except Exception:  # noqa: BLE001 — observability must not break warm-up
-        traceback.print_exc()
+    monitoring.register_event_listener(on_event)
+    _CACHE_LISTENING = True
 
 
 def _cache_counts() -> tuple[int, int]:
-    """(hits, misses) so far. Misses prefer the explicit event; older jax
-    only emits requests+hits, where misses = requests - hits."""
-    hits = _CACHE_EVENTS["hits"]
-    misses = _CACHE_EVENTS["misses"] or max(
-        0, _CACHE_EVENTS["requests"] - hits
-    )
-    return hits, misses
+    """(hits, misses) so far."""
+    return _CACHE_EVENTS["hits"], _CACHE_EVENTS["misses"]
 
 
 def _send(obj: dict) -> None:
@@ -132,10 +116,7 @@ def _distributed_init(jax) -> None:
     host_id = int(os.environ.get("APP_HOST_ID", "0"))
     # On the CPU platform (tests, dev) cross-process collectives need gloo;
     # the knob is ignored by the TPU backend, which uses ICI.
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # noqa: BLE001 — older jaxlib without the knob
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator,
         num_processes=num_hosts,
@@ -143,10 +124,24 @@ def _distributed_init(jax) -> None:
     )
 
 
+def _expected_platform() -> str:
+    """The platform this runner was started for: the first entry of
+    JAX_PLATFORMS when the operator stated one (tests and chipless dev say
+    `cpu`), else the TPU this service exists to serve."""
+    stated = os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip().lower()
+    # jax names the GPU platforms by vendor, their devices say "gpu"
+    return {"cuda": "gpu", "rocm": "gpu"}.get(stated, stated) or "tpu"
+
+
 def _warm_import() -> dict:
-    """Pre-import jax and touch the devices so TPU init happens now."""
+    """Pre-import jax and touch the devices so TPU init happens now.
+
+    A runner that cannot import jax, or gets another platform than the one
+    it was started for (jax itself falls back to the CPU with a warning
+    when it finds no TPU), reports ready=false: the server marks warm-up
+    failed and the control plane refuses the sandbox, instead of user code
+    quietly running on the host."""
     info = {"ready": True, "backend": "none", "device_count": 0}
-    num_hosts = int(os.environ.get("APP_NUM_HOSTS", "1") or "1")
     if os.environ.get("APP_WARM_IMPORT_JAX", "1") in ("0", "false"):
         # Explicit escape hatch (plumbing tests / no-JAX dev); on a slice
         # this forgoes the mesh knowingly.
@@ -168,14 +163,17 @@ def _warm_import() -> dict:
             jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
             jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         devices = jax.devices()
-        info["backend"] = devices[0].platform if devices else "none"
+        info["backend"] = devices[0].platform
         info["device_count"] = len(devices)  # global across the slice
-        # Device kind for the telemetry plane ("TPU v5e" etc.; CPU devices
-        # report "cpu") — surfaced via GET /device-stats so operators see
-        # what hardware a lane's hosts actually hold.
-        info["device_kind"] = (
-            str(getattr(devices[0], "device_kind", "")) if devices else ""
-        )
+        # Device kind for the telemetry plane ("TPU v5 lite" etc.; CPU
+        # devices report "cpu") — surfaced via GET /device-stats so operators
+        # see what hardware a lane's hosts actually hold.
+        info["device_kind"] = str(devices[0].device_kind)
+        if info["backend"] != (expected := _expected_platform()):
+            raise RuntimeError(
+                f"runner started for platform {expected!r} but jax attached "
+                f"{info['backend']!r} ({info['device_kind']})"
+            )
         if jax.process_count() > 1:
             info["process_count"] = jax.process_count()
             info["process_index"] = jax.process_index()
@@ -184,16 +182,10 @@ def _warm_import() -> dict:
         import jax.numpy as jnp
 
         jnp.add(jnp.ones(()), 1.0).block_until_ready()
-    except Exception:  # noqa: BLE001 — sandbox must still run CPU-only code
+    except Exception:  # noqa: BLE001 — reported to the server, then fatal
         traceback.print_exc()
-        if num_hosts > 1:
-            # A host that failed jax/distributed init must NOT report ready:
-            # the pod would pass its probe and hand out a slice whose mesh
-            # silently doesn't exist. Exiting keeps the server from ever
-            # listening (server.cpp refuses multi-host without the runner).
-            _log("fatal: jax init failed on a multi-host slice")
-            os._exit(1)
-        info["backend"] = "import-failed"
+        _log("fatal: jax warm-up failed")
+        info["ready"] = False
     return info
 
 
@@ -463,6 +455,17 @@ _OPERATOR_ONLY = (
 )
 
 
+def _exit_code_of(e: SystemExit) -> int:
+    """What the interpreter does with sys.exit(x): None is 0, an int is the
+    code, anything else is printed to stderr and exits 1."""
+    if e.code is None:
+        return 0
+    if isinstance(e.code, int):
+        return e.code
+    print(e.code, file=sys.stderr)
+    return 1
+
+
 def _run_one(req: dict) -> tuple[int, str | None]:
     """Execute one request; returns (exit_code, violation) where violation
     is the typed limit kind when an in-process resource guard ended the run
@@ -509,8 +512,7 @@ def _run_one(req: dict) -> tuple[int, str | None]:
         sys.argv = [source_path]  # argv[0] stays the user's path
         runpy.run_path(run_path, run_name="__main__")
     except SystemExit as e:
-        code = e.code
-        exit_code = code if isinstance(code, int) else (0 if code is None else 1)
+        exit_code = _exit_code_of(e)
     except _CpuTimeExceeded:
         # Restore first: the soft RLIMIT_CPU re-fires SIGXCPU every second
         # past the ceiling, and the next one must not land mid-report.
@@ -640,24 +642,18 @@ def _unshare_fs() -> bool:
 def _job_device_ctx(device_index, fallback_index: int):
     """Pin the job thread's jax dispatches to one local device (the batch's
     device-axis placement). jax config context managers are thread-local,
-    so concurrent jobs land on distinct chips. No jax / no devices / old
-    jax without default_device → a null context (CPU-only jobs run fine)."""
+    so concurrent jobs land on distinct chips. Only a runner that never
+    imported jax (APP_WARM_IMPORT_JAX=0: CPU-only jobs) gets a null
+    context; with jax loaded a placement failure fails the job, it never
+    silently lands every job on device 0."""
     import contextlib
 
-    try:
-        jax = sys.modules.get("jax")
-        if jax is not None and hasattr(jax, "default_device"):
-            devices = jax.devices()
-            if devices:
-                index = (
-                    device_index
-                    if isinstance(device_index, int)
-                    else fallback_index
-                )
-                return jax.default_device(devices[index % len(devices)])
-    except Exception:  # noqa: BLE001 — placement is best-effort
-        pass
-    return contextlib.nullcontext()
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    devices = jax.local_devices()
+    index = device_index if isinstance(device_index, int) else fallback_index
+    return jax.default_device(devices[index % len(devices)])
 
 
 def _run_batch_job(index: int, job: dict, results: list, mem_limited: bool,
@@ -710,10 +706,7 @@ def _run_batch_job(index: int, job: dict, results: list, mem_limited: bool,
                 },
             )
     except SystemExit as e:
-        code_ = e.code
-        entry["exit_code"] = (
-            code_ if isinstance(code_, int) else (0 if code_ is None else 1)
-        )
+        entry["exit_code"] = _exit_code_of(e)
     except MemoryError:
         traceback.print_exc()  # routed to this job's stderr by the proxy
         entry["exit_code"] = 1
@@ -1206,10 +1199,16 @@ def main() -> None:
     os.close(devnull)
 
     _start_server_watchdog()
-    _send(_warm_import())
+    info = _warm_import()
+    _send(info)
+    if not info["ready"]:
+        # Exit, never linger: a half-initialized runner may hold the chip.
+        # atexit is skipped (jax.distributed's shutdown barrier would block
+        # on peers that are dying too).
+        os._exit(1)
     # Boot snapshot for generation resets — taken AFTER the warm import so
-    # anything jax init itself set (TPU env, plugin paths, worker threads)
-    # persists and is never misread as user residue.
+    # anything jax init itself set (TPU env, worker threads) persists and
+    # is never misread as user residue.
     import threading
 
     snapshot = {

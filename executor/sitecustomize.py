@@ -16,35 +16,6 @@ import builtins
 import os
 import sys
 
-# Python imports exactly one `sitecustomize` module; if the host platform
-# ships its own (e.g. a PJRT plugin registration shim) further down sys.path,
-# chain-load it FIRST — plugin registration must precede any jax import below.
-def _chain_shadowed_sitecustomize() -> None:
-    import importlib.util
-
-    my_file = os.path.realpath(__file__)
-    for entry in sys.path:
-        if not entry:
-            continue
-        candidate = os.path.join(entry, "sitecustomize.py")
-        # realpath both sides: a symlink alias of this dir must not make us
-        # exec ourselves recursively.
-        if os.path.exists(candidate) and os.path.realpath(candidate) != my_file:
-            try:
-                spec = importlib.util.spec_from_file_location(
-                    "_chained_sitecustomize", candidate
-                )
-                module = importlib.util.module_from_spec(spec)
-                spec.loader.exec_module(module)
-            except Exception:  # noqa: BLE001 — platform shim is best-effort
-                import traceback
-
-                traceback.print_exc()
-            break
-
-
-_chain_shadowed_sitecustomize()
-
 _PATCHED: set[str] = set()
 
 
@@ -178,8 +149,12 @@ if os.environ.get("APP_NUMPY_DISPATCH", "0") not in ("0", "false", ""):
         from bee_code_interpreter_fs_tpu.ops.npdispatch import install as _install_np
 
         _install_np()
-    except Exception:  # noqa: BLE001 — fall back to stock numpy
+    except Exception:  # noqa: BLE001 — reported, then fatal
         import traceback
 
         sys.stderr.write("[sitecustomize] numpy dispatch install failed:\n")
         traceback.print_exc()
+        # A failed start, not a warning: with the shim asked for, user array
+        # code on stock host numpy would succeed on the wrong device.
+        # SystemExit because site.py swallows every Exception raised here.
+        raise SystemExit(1) from None

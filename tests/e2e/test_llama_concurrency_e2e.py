@@ -1,6 +1,6 @@
 """BASELINE config 5 scale-down: 16 concurrent Llama-class Executes.
 
-The capstone concurrency story (SURVEY.md §7.6, BASELINE.md config 5:
+The capstone concurrency story (SURVEY.md §7.6, BASELINE.json config 5:
 "Llama-2-7B JAX inference via Execute, 16 concurrent requests") previously
 existed only as an unexecuted benchmark script (VERDICT r1 #10). This drives
 16 simultaneous Executes of the in-repo Llama model — each through the full
@@ -53,7 +53,7 @@ async def llama_executor(tmp_path):
         default_execution_timeout=240.0,
         jax_compilation_cache_dir=str(tmp_path / "jax-cache"),
     )
-    backend = LocalSandboxBackend(config, warm_import_jax=True, numpy_dispatch=True)
+    backend = LocalSandboxBackend(config, warm_import_jax=True)
     executor = CodeExecutor(backend, Storage(config.file_storage_path), config)
     yield executor, backend
     await executor.close()

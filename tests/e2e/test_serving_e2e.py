@@ -69,8 +69,7 @@ async def stack(tmp_path):
         default_execution_timeout=240.0,
         jax_compilation_cache_dir=str(tmp_path / "jax-cache"),
     )
-    backend = LocalSandboxBackend(config, warm_import_jax=True,
-                                  numpy_dispatch=True)
+    backend = LocalSandboxBackend(config, warm_import_jax=True)
     executor = CodeExecutor(backend, Storage(config.file_storage_path), config)
     yield executor
     await executor.close()

@@ -3,7 +3,7 @@
 reference sandbox's own stack, the classic divergent import→distribution
 names, and namespace packages — resolved by executor/deps.py with the
 installed-package check disabled (so the MAPPING is what's measured, not
-what this rig happens to have installed).
+what this machine happens to have installed).
 
 The bar: the reference ships replit upm's full pypi_map.sqlite
 (/root/reference/executor/Dockerfile:122-124); deps.py replaces it with a
@@ -13,13 +13,18 @@ miss is listed so a regression names itself.
 """
 
 import importlib.util
-import sys
 from pathlib import Path
 
 import pytest
 
-sys.path.insert(0, str(Path(__file__).resolve().parents[2] / "executor"))
-import deps  # noqa: E402
+# Loaded under a name of its own: a bare `import deps` with executor/ left on
+# sys.path would put a generically named module (and that directory's
+# sitecustomize) in the way of every test collected after this one.
+_spec = importlib.util.spec_from_file_location(
+    "executor_deps", Path(__file__).resolve().parents[2] / "executor" / "deps.py"
+)
+deps = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(deps)
 
 
 # (import statement's module, expected pip distribution(s) — a tuple lists

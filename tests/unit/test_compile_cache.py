@@ -808,21 +808,22 @@ async def test_prewarm_stops_once_shared_dir_tainted(tmp_path):
         await executor.close()
 
 
-async def test_local_backend_shared_dir_fresh_epoch(tmp_path):
-    """Local backend, shared-dir mode, fleet cache on: the shared cache
-    dir starts EMPTY — a dir surviving a previous control-plane lifetime
-    could hold that lifetime's tenant writes, which this lifetime's
-    trusted-only epoch would then harvest as its own. Per-sandbox mode
-    and the kill switch leave the dir alone (host-local warm starts are
-    the point there)."""
+async def test_local_backend_shared_dir_trusted_epoch(tmp_path):
+    """Local backend, shared-dir mode: a dir surviving a previous
+    control-plane lifetime could hold that lifetime's tenant writes, which
+    this lifetime's trusted-only epoch would then harvest as its own — so a
+    shared dir that is NOT EMPTY at start is never harvested ("external").
+    The dir itself is never wiped: it may be the caller's
+    JAX_COMPILATION_CACHE_DIR. Per-sandbox dirs are private regardless."""
     from bee_code_interpreter_fs_tpu.services.backends.local import (
         LocalSandboxBackend,
     )
 
-    def make_local(subdir, **overrides):
+    def make_local(subdir, stale=True, **overrides):
         cache = tmp_path / subdir / "shared-cache"
         cache.mkdir(parents=True)
-        (cache / "jit_stale-cache").write_bytes(b"last-epoch-tenant-bytes")
+        if stale:
+            (cache / "jit_stale-cache").write_bytes(b"last-epoch-tenant-bytes")
         config = Config(
             local_sandbox_root=str(tmp_path / subdir / "sb"),
             file_storage_path=str(tmp_path / subdir / "storage"),
@@ -831,16 +832,16 @@ async def test_local_backend_shared_dir_fresh_epoch(tmp_path):
         )
         return cache, LocalSandboxBackend(config, warm_import_jax=False)
 
-    cache, backend = make_local("shared")
-    assert backend.compile_cache_dir_scope == "shared"
-    assert not cache.exists()  # fresh trusted epoch
+    cache, backend = make_local("stale")
+    assert backend.compile_cache_dir_scope == "external"
+    assert (cache / "jit_stale-cache").exists()  # handed in, never wiped
+
+    cache, backend = make_local("empty", stale=False)
+    assert backend.compile_cache_dir_scope == "shared"  # trusted epoch
 
     cache, backend = make_local("private", compile_cache_per_sandbox=True)
     assert backend.compile_cache_dir_scope == "private"
-    assert cache.exists()  # per-sandbox dirs are elsewhere; dir untouched
-
-    cache, backend = make_local("disabled", compile_cache_enabled=False)
-    assert (cache / "jit_stale-cache").exists()  # exact pre-cache behavior
+    assert (cache / "jit_stale-cache").exists()
 
 
 async def test_execute_surfaces_hit_miss_phases(tmp_path):

@@ -128,7 +128,7 @@ def test_ring_attention_with_flash_kernel():
         mesh=mesh,
         in_specs=(P("dp", "sp", "tp", None),) * 3,
         out_specs=P("dp", "sp", "tp", None),
-        check_rep=False,
+        check_vma=False,
     )(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
@@ -164,7 +164,7 @@ def test_ring_flash_non_divisible_chunks():
         mesh=mesh,
         in_specs=(P("dp", "sp", "tp", None),) * 3,
         out_specs=P("dp", "sp", "tp", None),
-        check_rep=False,
+        check_vma=False,
     )(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)

@@ -533,6 +533,34 @@ async def test_group_watch_failures_feed_lane_breaker(fake_kubectl):
     with pytest.raises(SandboxSpawnError):
         await backend.spawn(chip_count=8)  # 2 hosts -> 2 failed watches
     assert board.lane(8)._failures == 2
+    await backend.close()  # drain the fire-and-tracked failure-path deletes
+
+
+def test_loop_teardown_with_undrained_deletes_ends(fake_kubectl):
+    """A loop closed while the failure path's tracked deletes are still in
+    flight (close() never awaited) must end, and the deletes must still
+    reach kubectl: asyncio's teardown cancels every task, and a kubectl call
+    cancelled mid-start used to wait forever."""
+    import asyncio
+    import threading
+
+    kubectl, state, calls = fake_kubectl
+    (state / "fail_wait").touch()
+
+    async def failed_group_spawn():
+        backend = _backend(kubectl, tpu_chips_per_host=4)
+        with pytest.raises(SandboxSpawnError):
+            await backend.spawn(chip_count=8)
+
+    runner = threading.Thread(
+        target=asyncio.run, args=(failed_group_spawn(),), daemon=True
+    )
+    runner.start()
+    runner.join(timeout=30.0)
+    assert not runner.is_alive()
+    kubectl._threads.shutdown(wait=True)
+    deleted = {c["argv"][2] for c in calls() if c["argv"][0] == "delete"}
+    assert len(deleted) == 3  # both pods and the group's headless service
 
 
 async def test_single_host_watch_failure_leaves_strike_to_executor(fake_kubectl):

@@ -98,7 +98,7 @@ def test_pipeline_apply_identity_schedule():
         mesh=mesh,
         in_specs=(P(),),
         out_specs=P("pp"),
-        check_rep=False,
+        check_vma=False,
     )(micro)
     # pp is the leading out dim: [4*6, 2, 3]; the last stage's slab holds
     # the processed microbatches.
@@ -245,7 +245,7 @@ def test_ring_attention_matches_plain():
         mesh=mesh,
         in_specs=(P("dp", "sp", "tp", None),) * 3,
         out_specs=P("dp", "sp", "tp", None),
-        check_rep=False,
+        check_vma=False,
     )
     got = jax.jit(ring)(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
@@ -265,7 +265,7 @@ def test_ring_attention_sp4():
         mesh=mesh,
         in_specs=(P("dp", "sp", None, None),) * 3,
         out_specs=P("dp", "sp", None, None),
-        check_rep=False,
+        check_vma=False,
     )
     got = jax.jit(ring)(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
@@ -290,7 +290,7 @@ def test_ulysses_attention_matches_plain():
             mesh=mesh,
             in_specs=(P("dp", "sp", "tp", None),) * 3,
             out_specs=P("dp", "sp", "tp", None),
-            check_rep=False,
+            check_vma=False,
         )
     )(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
@@ -318,7 +318,7 @@ def test_ulysses_attention_sp4_with_flash():
             mesh=mesh,
             in_specs=(P("dp", "sp", "tp", None),) * 3,
             out_specs=P("dp", "sp", "tp", None),
-            check_rep=False,
+            check_vma=False,
         )
     )(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
@@ -348,7 +348,7 @@ def test_ulysses_gqa_unexpanded_kv_both_paths():
                 mesh=mesh,
                 in_specs=(P("dp", "sp", None, None),) * 3,
                 out_specs=P("dp", "sp", None, None),
-                check_rep=False,
+                check_vma=False,
             )
         )(q, k, v)
         np.testing.assert_allclose(np.asarray(got), np.asarray(expected),

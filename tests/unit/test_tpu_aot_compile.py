@@ -1,0 +1,221 @@
+"""Ask the TPU's compiler, without a TPU: the kernels and programs of the
+served main path, compiled ahead of time at their real sizes for a DESCRIBED
+v5e:2x2 topology (on-chip-measurement guide §2, third rehearsal). What the
+chip's compiler refuses — a tile not aligned to Mosaic's rules, a kernel over
+its VMEM budget, a program that does not fit 16 GB — fails here and costs no
+chip time. Nothing runs on a device, so this says nothing about results or
+speed; chip_smoke.py does.
+
+The topology is described inside a module-scoped fixture and only there:
+describing it loads the TPU's library, which one process at a time may hold,
+so nothing here touches it at import, in a skipif, in a parametrize argument
+or in conftest.py, and every compile happens in the test's own process. Keep
+these tests in this one file (a second file may go to another xdist worker,
+where the fixture would skip in silence).
+"""
+
+import runpy
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from bee_code_interpreter_fs_tpu.ops.flash_attention import (
+    flash_attention,
+    flash_attention_partial,
+)
+
+REPO_ROOT = Path(__file__).resolve().parent.parent.parent
+V5E_HBM_BYTES = 16 * 1024**3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps it from being described
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without the chip; keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (
+        m.argument_size_in_bytes
+        + m.output_size_in_bytes
+        + m.temp_size_in_bytes
+        - m.alias_size_in_bytes
+    )
+
+
+class _AotJit:
+    """Stands in for `jax.jit` around code that jits and calls in one go (the
+    shim's lazy engine, the pre-warm snippets): a call compiles the function
+    for the described chip at the arguments' shapes, keeps the executable,
+    and returns zeros of the output shape — nothing executes anywhere."""
+
+    def __init__(self, sharding):
+        self.real_jit = jax.jit
+        self.sharding = sharding  # None: the program's own mesh names devices
+        self.compiled = []
+
+    def __call__(self, fn, **jit_kwargs):
+        def call(*args):
+            specs = jax.tree.map(
+                lambda a: _spec(a.shape, a.dtype, self.sharding), args
+            )
+            self.compiled.append(
+                self.real_jit(fn, **jit_kwargs).lower(*specs).compile()
+            )
+            return jax.tree.map(
+                lambda o: jnp.zeros(o.shape, o.dtype), jax.eval_shape(fn, *args)
+            )
+
+        return call
+
+
+@pytest.mark.parametrize(
+    "shape,dtype,kwargs",
+    [
+        # chip_smoke.py's shape (examples/benchmark-attention.py)
+        ((1, 16384, 4, 128), jnp.bfloat16, {}),
+        # Llama width: 32 heads of 128
+        ((1, 4096, 32, 128), jnp.bfloat16, {}),
+        # a ragged length: the padding + block-clamp path
+        ((1, 900, 4, 128), jnp.bfloat16, {}),
+        ((1, 2048, 8, 64), jnp.bfloat16, {}),
+        # sliding window with attention sinks, f32
+        ((1, 4096, 8, 128), jnp.float32, {"window": 1024, "sinks": 4}),
+    ],
+)
+def test_flash_attention_compiles_for_v5e(one_chip, shape, dtype, kwargs):
+    x = _spec(shape, dtype, one_chip)
+    compiled = (
+        jax.jit(lambda q, k, v: flash_attention(q, k, v, **kwargs))
+        .lower(x, x, x)
+        .compile()
+    )
+    assert "tpu_custom_call" in compiled.as_text()  # Mosaic, not the interpreter
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+def test_flash_attention_partial_compiles_for_v5e(one_chip):
+    b, t, h, d = 1, 4096, 8, 128
+    x = _spec((b, t, h, d), jnp.bfloat16, one_chip)
+    acc = _spec((b, h, t, d), jnp.float32, one_chip)
+    stat = _spec((b, h, t), jnp.float32, one_chip)
+    compiled = (
+        jax.jit(
+            lambda q, k, v, acc, m, l: flash_attention_partial(
+                q, k, v, acc, m, l, q_offset=0, k_offset=0
+            )
+        )
+        .lower(x, x, x, acc, stat, stat)
+        .compile()
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_llama_flash_forward_compiles_for_v5e(one_chip, monkeypatch):
+    """One models/llama.py forward at Llama-2-7B widths (depth cut to two
+    layers) with attn_impl="flash". The model picks interpret mode from
+    jax.default_backend(), which under AOT still says `cpu` — the test says
+    `tpu`, so the Mosaic branch is what gets compiled."""
+    from bee_code_interpreter_fs_tpu.models.llama import (
+        LlamaConfig,
+        forward,
+        init_params,
+    )
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = LlamaConfig(n_layers=2, attn_impl="flash")
+    params = jax.tree.map(
+        lambda a: _spec(a.shape, a.dtype, one_chip),
+        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)),
+    )
+    tokens = _spec((1, cfg.max_seq_len), jnp.int32, one_chip)
+    compiled = (
+        jax.jit(lambda p, t: forward(p, t, cfg)).lower(params, tokens).compile()
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+def test_headline_payload_programs_fit_one_chip(one_chip, monkeypatch):
+    """examples/benchmark-numpy.py, unchanged at N = 1e8, run against the
+    shim with its lazy engine's jit swapped for the AOT one: every fused
+    program the engine emits for it ((b*b).sum(), b + 1e-9, the random
+    draw) compiles for one v5e chip and fits its 16 GB beside the live
+    arrays the payload holds (a, b and a temporary: 400 MB each in f32)."""
+    from bee_code_interpreter_fs_tpu.ops import npdispatch
+    from bee_code_interpreter_fs_tpu.ops.npdispatch import lazy
+
+    aot = _AotJit(one_chip)
+
+    class JaxWithAotJit:
+        jit = staticmethod(aot)
+
+        def __getattr__(self, name):
+            return getattr(jax, name)
+
+    monkeypatch.setattr(lazy, "jax", JaxWithAotJit())
+    monkeypatch.setattr(lazy, "_exec_cache", {})
+    npdispatch.install()
+    try:
+        runpy.run_path(
+            str(REPO_ROOT / "examples" / "benchmark-numpy.py"), run_name="__main__"
+        )
+    finally:
+        npdispatch.uninstall()
+    assert len(aot.compiled) >= 3  # the draw, the single shot, the chained passes
+    live_arrays = 4 * 100_000_000 * 4
+    for compiled in aot.compiled:
+        assert _device_bytes(compiled) + live_arrays < V5E_HBM_BYTES
+
+
+def test_prewarm_kernel_set_compiles_for_v5e(topo, one_chip, monkeypatch):
+    """The pre-warm snippets run at every service start
+    (services/compile_cache.PREWARM_SOURCES). Each is executed with jax.jit
+    swapped for the AOT one; the fused-dispatch snippet builds its "jobs"
+    mesh from jax.devices(), which here are the described four chips."""
+    from bee_code_interpreter_fs_tpu.services.compile_cache import PREWARM_SOURCES
+
+    for name, source in PREWARM_SOURCES:
+        on_mesh = name == "batched_dispatch"
+        aot = _AotJit(None if on_mesh else one_chip)
+        with monkeypatch.context() as patch:
+            patch.setattr(jax, "jit", aot)
+            if on_mesh:
+                patch.setattr(jax, "devices", lambda: list(topo.devices))
+            exec(compile(source, f"<prewarm {name}>", "exec"), {"__name__": "__main__"})
+        assert len(aot.compiled) == 1, name
+        if on_mesh:
+            # one program over all four chips: each holds its own block
+            assert len(aot.compiled[0].input_shardings[0][0].device_set) == 4
+    assert {name for name, _ in PREWARM_SOURCES} >= {"matmul", "batched_dispatch"}
