@@ -12,10 +12,13 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import time
 from dataclasses import dataclass, field
 from typing import Protocol, runtime_checkable
 
 import httpx
+
+from ...utils import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -31,22 +34,39 @@ async def reset_sandbox_over_http(
     sandbox; all must answer 200 + ok. Returns the sandbox with its
     generation bumped, or None (caller must dispose). Backend-specific
     prechecks (process liveness, pod registry) stay in the backends."""
+    # The turnover's trace context, where it has one: the executor then
+    # stamps its own stages (runner_reset, wipe) into the reply, and they are
+    # left on `meta["reset_trace"]`, one block per host, for the caller to
+    # graft under its `sandbox.reset` span.
+    headers = tracing.trace_headers()
+    started = time.perf_counter()
     try:
         async with httpx.AsyncClient(timeout=httpx.Timeout(timeout)) as client:
+            # Building the client (its TLS context among it) is synchronous
+            # work on the event loop, once per turnover: the caller gives it
+            # a span of its own.
+            sandbox.meta["reset_client_s"] = time.perf_counter() - started
             resps = await asyncio.gather(
-                *(client.post(f"{url}/reset") for url in sandbox.host_urls),
+                *(
+                    client.post(f"{url}/reset", headers=headers)
+                    for url in sandbox.host_urls
+                ),
                 return_exceptions=True,
             )
     except Exception:  # noqa: BLE001 — reuse is best-effort
         return None
+    blocks = []
     for resp in resps:
         if isinstance(resp, BaseException) or resp.status_code != 200:
             return None
         try:
-            if not resp.json().get("ok"):
-                return None
+            body = resp.json()
         except ValueError:
             return None
+        if not body.get("ok"):
+            return None
+        blocks.append(body.get("trace"))
+    sandbox.meta["reset_trace"] = blocks
     sandbox.meta["generation"] = sandbox.meta.get("generation", 0) + 1
     logger.info(
         "recycled sandbox %s (generation %d)",

@@ -107,6 +107,46 @@ LATENCY_PHASES = frozenset(
     {"queue_wait", "upload", "exec", "download", "restore"}
 )
 
+# Where a served turn's time went outside the four latency phases, stamped
+# into Result.phases by the serial path (seconds; 0.0 where the stage did not
+# run, or the executor binary sends no stages). None is in LATENCY_PHASES:
+# the histogram and the perf observer's baselines see nothing new.
+#   edge_before / edge_after   the request's arrival to the queue, and the
+#                              download's end to the body being serialised
+#   turnover_before            the pool.turnover that put this sandbox back
+#   pool_idle_before           that turnover's end (pooled_at) to the pop
+#   exec_wire                  phases.exec minus the sandbox handler's total_s
+#   sandbox_before_run / sandbox_after_run
+#                              the handler's stages before the pipe write
+#                              into the warm runner and after its reply line
+#   runner_pickup              pipe write until the runner read the line
+#   runner_before_user / runner_user_code / runner_after_user
+#                              the runner's stages around runpy.run_path
+STAGE_PHASES = (
+    "edge_before",
+    "edge_after",
+    "turnover_before",
+    "pool_idle_before",
+    "exec_wire",
+    "sandbox_before_run",
+    "sandbox_after_run",
+    "runner_pickup",
+    "runner_before_user",
+    "runner_user_code",
+    "runner_after_user",
+)
+_RUNNER_BEFORE_USER = ("runner.prepare", "runner.profile_start", "runner.limits_arm")
+_RUNNER_AFTER_USER = ("runner.limits_restore", "runner.profile_stop", "runner.finish")
+
+# The request's marks on either side of the executor's work, on the tracer's
+# clock: {"t0", "before": [(name, started)], "queued_at", "after": [...],
+# "download_at"}. Set by execute(); read where the queue is entered, where
+# the download ends and where the body is about to be serialised. A
+# contextvar for the same reason as the three below.
+_edge_var: contextvars.ContextVar[dict | None] = contextvars.ContextVar(
+    "request_edge_marks", default=None
+)
+
 # True only inside _execute_trusted (the compile-cache pre-warm): the running
 # request's source is control-plane-authored, so it does NOT taint its
 # sandbox's compile-cache provenance. Everything else — every API-originated
@@ -174,6 +214,9 @@ class Result:
     # the run didn't declare purity, an old binary didn't echo, or the
     # hashes disagreed — nothing is recorded then (services/result_memo.py).
     pure_echo: str | None = None
+    # The request's edge marks (execute() leaves them here for the API
+    # surface's close_edge(); None once closed). Never on the wire.
+    edge: dict | None = field(default=None, repr=False, compare=False)
 
 
 @dataclass
@@ -1474,6 +1517,7 @@ class CodeExecutor:
                     ticket, timeout_at=timeout_at
                 )
                 now = self.scheduler.now()
+                woke_at = self.tracer.clock()
                 spawning = self._spawning.get(chip_count, 0)
                 in_use = self._in_use.get(chip_count, 0)
                 session_held = self._session_held_constrained()
@@ -1506,6 +1550,21 @@ class CodeExecutor:
                 if granted and pool:
                     sandbox = self._pop_pool_sandbox(pool)
                     if sandbox is not None:
+                        # What the holder did between this sandbox's last
+                        # turn and this one: the turnover that put it back
+                        # (0 for a pre-warmed spawn), then idle in the pool.
+                        sandbox.meta["acquired"] = {
+                            "turnover_before": float(
+                                sandbox.meta.get("turnover_s", 0.0)
+                            ),
+                            "pool_idle_before": max(
+                                0.0,
+                                now - float(sandbox.meta.get("pooled_at", now)),
+                            ),
+                        }
+                        self._record_timed(
+                            "pool.acquire", woke_at, attributes={"source": "pool"}
+                        )
                         break
                     # Pool holds only recovering/draining quarantined hosts:
                     # nothing servable to pop — fall through to the
@@ -1628,6 +1687,10 @@ class CodeExecutor:
                             metered=not _trusted_source_var.get(),
                         )
                         continue
+                    sandbox.meta.pop("acquired", None)
+                    self._record_timed(
+                        "pool.acquire", woke_at, attributes={"source": "spawn"}
+                    )
                     break
                 if granted:
                     # Nothing to pop and must not spawn: back to sleep in
@@ -1771,6 +1834,7 @@ class CodeExecutor:
         requests are never retried on infrastructure failure: a retry would
         land on a fresh sandbox and silently drop the session's state.
         """
+        edge = self._edge_begin()
         env, executor_id = self._normalize_request(env, profile, executor_id)
         usage_tenant = self._usage_tenant(tenant)
         self._check_admission_open()
@@ -1783,6 +1847,7 @@ class CodeExecutor:
         quota = self._quota_admit(
             usage_tenant, chip_count=chip_count, timeout=timeout
         )
+        self._edge_mark(edge, "edge.memo_lookup")
         # Result-memo admission check: AFTER the quota gate (hits are still
         # request-rate-governed — free answers are not unmetered answers)
         # and BEFORE the auto-profile arm below (a served-from-record
@@ -1810,6 +1875,7 @@ class CodeExecutor:
                 finally:
                     self.quotas.release(quota)
             memo_state = "miss"
+        self._edge_mark(edge, "edge.resolve")
         # Auto-triggered profiling: a pending arm on this request's lane
         # (set by the drift detector or a p99 outlier) is consumed here,
         # AFTER admission — a denied request must not eat the arm. The
@@ -1821,6 +1887,7 @@ class CodeExecutor:
         # record could come of it (a miss): _run_on_sandbox forwards it to
         # the executor for the hashed echo.
         pure_token = _pure_run_var.set(memo_state == "miss")
+        edge_token = _edge_var.set(edge)
         self._inflight += 1
         try:
             if executor_id is not None:
@@ -1891,8 +1958,11 @@ class CodeExecutor:
             self.quotas.release(quota)
             _auto_profile_var.reset(profile_token)
             _pure_run_var.reset(pure_token)
+            _edge_var.reset(edge_token)
+        self._edge_mark(edge, "edge.memo_record")
         await self._memo_finish(memo_key, memo_state, result, auto_profile)
         self._apply_quota_phases(result, quota)
+        self._edge_mark(edge, "edge.observe")
         self._count_execution(
             result,
             session=executor_id is not None,
@@ -1900,7 +1970,113 @@ class CodeExecutor:
             lane=self._lane_hint(chip_count),
             tenant=tenant,
         )
+        result.edge = edge
+        self.close_edge(result, final=False)
         return result
+
+    # ------------------------------------------------------- request edges
+
+    def _edge_begin(self) -> dict:
+        """Open the request's edge marks (see `_edge_var`). The origin is
+        the root span's start where this task has one (the HTTP middleware's:
+        body parse, validation and session routing then fall into
+        `edge.parse`), else now."""
+        now = self.tracer.clock()
+        t0 = getattr(tracing.current_span(), "_start_mono", None)
+        if t0 is None:
+            t0 = now
+        return {
+            "t0": t0,
+            "before": [("edge.parse", t0), ("edge.quota", now)],
+            "queued_at": None,
+            "after": [],
+            "download_at": None,
+        }
+
+    def _edge_mark(self, edge: dict | None, name: str) -> None:
+        """`name` begins now; the mark before it on that side ends."""
+        if edge is None:
+            return
+        side = "before" if edge["queued_at"] is None else "after"
+        edge[side].append((name, self.tracer.clock()))
+
+    def _edge_queued(self) -> None:
+        """The request enters the queue: `edge.before_queue` ends, and is
+        recorded with its marks as children. A retry's second entry is not
+        an edge any more."""
+        edge = _edge_var.get()
+        if edge is None or edge["queued_at"] is not None:
+            return
+        edge["queued_at"] = now = self.tracer.clock()
+        self._record_marks("edge.before_queue", edge["t0"], now, edge["before"])
+
+    def _edge_downloaded(self) -> None:
+        """The download ended (this attempt's): what follows is the edge."""
+        edge = _edge_var.get()
+        if edge is not None and edge["queued_at"] is not None:
+            edge["download_at"] = now = self.tracer.clock()
+            edge["after"] = [("edge.result", now)]
+
+    def close_edge(self, result: Result, *, final: bool = True) -> None:
+        """Stamp `edge_before` / `edge_after` into the result's phases. The
+        API surface calls this at the last point before it serialises the
+        body (`final`): `edge.after_download` then ends and is recorded with
+        its marks as children. execute() itself stamps a first reading, for
+        callers that never serialise."""
+        edge = result.edge
+        if edge is None or edge["queued_at"] is None:
+            return
+        now = self.tracer.clock()
+        result.phases["edge_before"] = round(edge["queued_at"] - edge["t0"], 6)
+        if edge["download_at"] is None:
+            return
+        result.phases["edge_after"] = round(now - edge["download_at"], 6)
+        if final:
+            result.edge = None
+            self._record_marks(
+                "edge.after_download", edge["download_at"], now, edge["after"]
+            )
+
+    def _record_marks(
+        self, name: str, started: float, ended: float, marks: list
+    ) -> None:
+        """Export `name` as a child of the current span and each mark as a
+        child of it, every mark ending where the next begins."""
+        parent = tracing.current_span()
+        if parent is None or not parent.recording:
+            return
+        span_id = self._record_timed(name, started, ended)
+        for (mark, begun), (_next, until) in zip(
+            marks, marks[1:] + [("", ended)]
+        ):
+            self._record_timed(mark, begun, until, parent_id=span_id)
+
+    def _record_timed(
+        self,
+        name: str,
+        started: float,
+        ended: float | None = None,
+        *,
+        parent_id: str | None = None,
+        attributes: dict | None = None,
+    ) -> str | None:
+        """Export a span for work already timed on the tracer's clock
+        (`started` to `ended`, default now) in the current span's trace, as
+        a child of `parent_id` (default: the current span). Returns its id;
+        None where nothing records."""
+        current = tracing.current_span()
+        if current is None or not current.recording:
+            return None
+        ended = self.tracer.clock() if ended is None else ended
+        return self.tracer.record_span(
+            name,
+            trace_id=current.trace_id,
+            parent_id=parent_id or current.span_id,
+            # anchored to the current span's own start, on the one clock
+            start_unix=current.start_unix + (started - current._start_mono),
+            duration_s=ended - started,
+            attributes=attributes,
+        )
 
     # ------------------------------------------------------ result memoization
 
@@ -2956,16 +3132,20 @@ class CodeExecutor:
         # request is counted once, at the API surface.
         usage = self._usage_draft(tenant)
 
+        self._edge_queued()
         with timer.phase("queue_wait"):
             sandbox = await self._acquire(
                 lane, tenant=tenant, priority=priority, deadline=deadline
             )
+        acquired = sandbox.meta.pop("acquired", None)
         reusable = False
         try:
             result, _continuable = await self._run_on_sandbox(
                 sandbox, source_code, source_file, files, timeout, env, timer,
                 limits=limits_payload, emit=emit, usage=usage,
             )
+            if acquired:
+                result.phases.update(acquired)
             # The request completed (user errors included). Whether the
             # sandbox is actually safe to recycle is the server's call —
             # /reset refuses (409) when its runner was killed by a timeout
@@ -2983,10 +3163,12 @@ class CodeExecutor:
             # Attribution commits on EVERY exit — success, violation, or
             # fault: a request that fails after consuming device time is
             # still billed (the draft holds whatever the attempt measured).
+            self._edge_mark(_edge_var.get(), "edge.usage_commit")
             self.usage.commit(usage)
             # Sandbox release off the hot path: recycle the warm device
             # process back into the pool (generation turnover via /reset),
             # or dispose it when it can't be safely reused.
+            self._edge_mark(_edge_var.get(), "edge.release")
             self._release_soon(sandbox, lane, reusable)
 
     def _validate_request(
@@ -3180,6 +3362,7 @@ class CodeExecutor:
                 download_span.set_attribute(
                     "files_skipped", stats.download_skipped_files
                 )
+        self._edge_downloaded()
         primary = bodies[0]
         stderr = primary.get("stderr", "")
         exit_code = int(primary.get("exit_code", -1))
@@ -3200,6 +3383,7 @@ class CodeExecutor:
             transfer.invalidate()
         stats.emit(self.metrics)
         phases = {**timer.as_dict(), **stats.as_phases()}
+        phases.update(self._stage_phases(primary, phases.get("exec", 0.0)))
         phases.update(self._compile_cache_phases(sandbox, bodies))
         # Device-memory accounting: the hosts' wire blocks folded into
         # phases (peak_hbm_bytes / live_buffer_bytes_delta — non-latency
@@ -3264,6 +3448,36 @@ class CodeExecutor:
             ),
         )
         return result, continuable
+
+    @classmethod
+    def _stage_phases(cls, body, exec_seconds: float) -> dict[str, float]:
+        """STAGE_PHASES from host 0's `trace` block (all 0.0 where it sent
+        none): where inside `exec` the time went, by the sandbox handler's
+        and the warm runner's own clocks. The parts never sum past `exec`:
+        what is left over is the reply line's way back through the pipe."""
+        phases = dict.fromkeys(STAGE_PHASES, 0.0)
+        stages = {
+            name: (offset, max(0.0, duration))
+            for name, offset, duration, _ in cls._trace_block_entries(body)
+        }
+        if not stages:
+            return phases
+        total = body["trace"].get("total_s")
+        if isinstance(total, (int, float)) and total >= 0:
+            phases["exec_wire"] = max(0.0, exec_seconds - float(total))
+            if "runner_wait" in stages:
+                sent, waited = stages["runner_wait"]
+                phases["sandbox_before_run"] = max(0.0, sent)
+                phases["sandbox_after_run"] = max(0.0, float(total) - sent - waited)
+
+        def seconds(*names: str) -> float:
+            return sum(stages[n][1] for n in names if n in stages)
+
+        phases["runner_pickup"] = seconds("runner.pickup")
+        phases["runner_before_user"] = seconds(*_RUNNER_BEFORE_USER)
+        phases["runner_user_code"] = seconds("runner.user_code")
+        phases["runner_after_user"] = seconds(*_RUNNER_AFTER_USER)
+        return {key: round(value, 6) for key, value in phases.items()}
 
     @staticmethod
     def _reported_device_op(bodies: list, fallback: float = 0.0) -> float:
@@ -4539,13 +4753,7 @@ class CodeExecutor:
         """Headers propagating the current span's context to a sandbox (the
         executor server echoes the value and stamps its phase timings into a
         `trace` block). None when there is nothing to propagate."""
-        span = tracing.current_span()
-        if span is None:
-            return None
-        traceparent = span.traceparent()
-        if traceparent is None:
-            return None
-        return {"traceparent": traceparent}
+        return tracing.trace_headers()
 
     def _graft_sandbox_trace(self, span, base: str, body) -> None:
         """Fold a sandbox's reported per-phase timings (install/exec/collect,
@@ -4554,9 +4762,27 @@ class CodeExecutor:
         to the sandbox's own request start and are applied to THIS span's
         start time, so cross-process clock skew never enters the math (the
         child spans are guaranteed to nest inside the HTTP call window)."""
-        if not span.recording or not isinstance(body, dict):
+        if not span.recording:
             return
-        block = body.get("trace")
+        # An entry may name its `parent`, an earlier entry of the same
+        # block (the handler's stages inside install/exec/collect, the warm
+        # runner's inside exec): it then hangs under that span.
+        grafted: dict[str, str | None] = {}
+        for name, offset, duration, parent in self._trace_block_entries(body):
+            grafted[name] = self.tracer.record_span(
+                f"sandbox.{name}"[:64],
+                trace_id=span.trace_id,
+                parent_id=grafted.get(parent) or span.span_id,
+                start_unix=span.start_unix + max(0.0, offset),
+                duration_s=duration,
+                attributes={"host": base},
+            )
+
+    @staticmethod
+    def _trace_block_entries(body):
+        """The well-formed entries of a sandbox reply's `trace` block, as
+        (name, start_offset_s, duration_s, parent or None)."""
+        block = body.get("trace") if isinstance(body, dict) else None
         entries = block.get("spans") if isinstance(block, dict) else None
         if not isinstance(entries, list):
             return
@@ -4567,20 +4793,12 @@ class CodeExecutor:
             offset = entry.get("start_offset_s")
             duration = entry.get("duration_s")
             if (
-                not isinstance(name, str)
-                or not name
-                or not isinstance(offset, (int, float))
-                or not isinstance(duration, (int, float))
+                isinstance(name, str)
+                and name
+                and isinstance(offset, (int, float))
+                and isinstance(duration, (int, float))
             ):
-                continue
-            self.tracer.record_span(
-                f"sandbox.{name}"[:64],
-                trace_id=span.trace_id,
-                parent_id=span.span_id,
-                start_unix=span.start_unix + max(0.0, float(offset)),
-                duration_s=float(duration),
-                attributes={"host": base},
-            )
+                yield name, float(offset), float(duration), entry.get("parent")
 
     async def _post_execute_stream(
         self,
@@ -5152,7 +5370,32 @@ class CodeExecutor:
         """Sandbox turnover (runs off the hot path): recycle the warm device
         process back into the pool when safe — the TPU lease survives and
         the next request pops a hot sandbox in milliseconds — else dispose
-        it and refill the lane (VERDICT r2 #1)."""
+        it and refill the lane (VERDICT r2 #1).
+
+        Off the request's path, but ON the chip holder's cycle: a queued
+        turn waits for it. So it is a trace of its own, `pool.turnover`,
+        sampled like a request, and its length rides on the recycled
+        sandbox (`meta["turnover_s"]`, beside `pooled_at`) into the next
+        turn's `phases.turnover_before`."""
+        started = self.tracer.clock()
+        with self.tracer.start_trace(
+            "pool.turnover", attributes={"sandbox": sandbox.id, "lane": lane}
+        ) as span:
+            recycled = await self._turnover_traced(
+                sandbox, lane, recyclable, extra_free, started
+            )
+            span.set_attribute(
+                "outcome", "recycled" if recycled else "disposed"
+            )
+
+    async def _turnover_traced(
+        self,
+        sandbox: Sandbox,
+        lane: int,
+        recyclable: bool,
+        extra_free: int,
+        started: float,
+    ) -> bool:
         recycled: Sandbox | None = None
         # Harvest BEFORE reset/dispose: kernels this generation compiled
         # must reach the fleet store even when the sandbox itself is about
@@ -5177,10 +5420,27 @@ class CodeExecutor:
                 # occupy the deque.
                 and self._pool_supply(lane) < self._lane_target(lane, extra_free=extra_free)
             ):
-                try:
-                    recycled = await self.backend.reset(sandbox)
-                except Exception:  # noqa: BLE001 — recycle is best-effort
-                    logger.exception("sandbox %s reset failed", sandbox.id)
+                with self.tracer.span("sandbox.reset") as reset_span:
+                    try:
+                        recycled = await self.backend.reset(sandbox)
+                    except Exception:  # noqa: BLE001 — recycle is best-effort
+                        logger.exception("sandbox %s reset failed", sandbox.id)
+                    # The executor's own stages of /reset (runner_reset,
+                    # wipe), one block per host, under the backend call.
+                    client_s = sandbox.meta.pop("reset_client_s", None)
+                    if reset_span.recording and client_s is not None:
+                        self.tracer.record_span(
+                            "sandbox.reset_client",
+                            trace_id=reset_span.trace_id,
+                            parent_id=reset_span.span_id,
+                            start_unix=reset_span.start_unix,
+                            duration_s=client_s,
+                        )
+                    blocks = sandbox.meta.pop("reset_trace", None) or ()
+                    for base, block in zip(sandbox.host_urls, blocks):
+                        self._graft_sandbox_trace(
+                            reset_span, base, {"trace": block}
+                        )
                 if recycled is not None:
                     # /reset wiped every host's workspace: the manifest
                     # cache restarts empty-known for the next generation
@@ -5197,15 +5457,20 @@ class CodeExecutor:
                 ):
                     recycled = None
             if recycled is not None:
+                appending = self.tracer.clock()
                 recycled.meta["pooled_at"] = self.scheduler.now()
                 self._pool(lane).append(recycled)
                 self.metrics.recycles.inc()
                 self._notify_lane(lane)
+                ended = self.tracer.clock()
+                recycled.meta["turnover_s"] = ended - started
+                self._record_timed("pool.append", appending, ended)
             else:
                 await self._dispose(sandbox)
         finally:
             if recycled is None:
                 self.fill_pool_soon(lane)
+        return recycled is not None
 
     async def _dispose(self, sandbox: Sandbox) -> None:
         self._live_sandboxes.pop(sandbox.id, None)

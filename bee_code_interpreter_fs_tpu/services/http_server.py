@@ -1129,7 +1129,10 @@ def create_http_app(
 
     def result_body(result, req: ExecuteRequest) -> dict:
         """Execute response body, identical for both surfaces (the stream's
-        final event must never diverge from the non-streaming body)."""
+        final event must never diverge from the non-streaming body). The
+        last point before the body is serialised: the request's trailing
+        edge ends here (`phases.edge_after`, `edge.after_download`)."""
+        code_executor.close_edge(result)
         body = {
             "stdout": result.stdout,
             "stderr": result.stderr,

@@ -381,6 +381,13 @@ def summarize_profile(data: bytes, *, top_n: int = 10) -> dict:
     — no xprof/TensorBoard dependency; artifacts without a parseable trace
     (or on an old jaxlib layout) degrade to a member listing, never a 500.
 
+    A capture that holds no device process (a CPU run; a turn that ran no
+    device program) says ``device_plane: false`` and gives no busy share, no
+    device ops and no idle gaps: host events are not the device's. Where the
+    capture holds the warm runner's ``runner.*`` stage annotations (every
+    profiled turn's does), each idle gap is named by the stage that covers
+    its start (``during``), and the stages' own lengths are listed.
+
     Durations in the trace-event format are microseconds; everything here
     reports milliseconds."""
     import gzip
@@ -430,6 +437,8 @@ def summarize_profile(data: bytes, *, top_n: int = 10) -> dict:
     }
     ops: dict[str, list[float]] = {}
     device_spans: list[tuple[float, float]] = []
+    # The warm runner's stage annotations (host plane): (start, end, name).
+    stages: list[tuple[float, float, str]] = []
     t_min = math.inf
     t_max = -math.inf
     for e in events:
@@ -443,18 +452,49 @@ def summarize_profile(data: bytes, *, top_n: int = 10) -> dict:
             continue
         t_min = min(t_min, float(ts))
         t_max = max(t_max, float(ts) + float(dur))
-        on_device = not device_pids or e.get("pid") in device_pids
-        if on_device:
+        name = str(e.get("name", "?"))
+        if e.get("pid") in device_pids:
             device_spans.append((float(ts), float(ts) + float(dur)))
-            bucket = ops.setdefault(str(e.get("name", "?")), [0.0, 0.0])
+            bucket = ops.setdefault(name, [0.0, 0.0])
             bucket[0] += float(dur)
             bucket[1] += 1.0
-    if not device_spans or not math.isfinite(t_min):
+        elif name.startswith("runner."):
+            stages.append((float(ts), float(ts) + float(dur), name))
+    if not math.isfinite(t_min):
         return {
             "verdict": "no complete events in trace",
             "member": parsed_member,
             "members": members[:50],
         }
+    stages.sort()
+    runner_stages = [
+        {
+            "name": name,
+            "offset_ms": round((start - t_min) / 1e3, 3),
+            "duration_ms": round((end - start) / 1e3, 3),
+        }
+        for start, end, name in stages
+    ]
+    if not device_spans:
+        summary = {
+            "verdict": (
+                "no device plane in the "
+                f"{max(t_max - t_min, 0.0) / 1e3:.1f}ms capture: nothing ran "
+                "on a device, or the capture is a CPU's"
+            ),
+            "member": parsed_member,
+            "device_plane": False,
+            "span_ms": round(max(t_max - t_min, 0.0) / 1e3, 3),
+        }
+        if runner_stages:
+            summary["runner_stages"] = runner_stages
+        return summary
+
+    def stage_at(moment: float) -> str | None:
+        """The innermost runner stage that covers `moment`."""
+        covering = [s for s in stages if s[0] <= moment < s[1]]
+        return max(covering, key=lambda s: s[0])[2] if covering else None
+
     # Busy wall = the union of device spans (ops overlap across cores);
     # idle gaps are the holes in that union over the capture window.
     device_spans.sort()
@@ -485,9 +525,20 @@ def summarize_profile(data: bytes, *, top_n: int = 10) -> dict:
         )
         + (f"; top op: {top_ops[0][0]}" if top_ops else "")
     )
-    return {
+    idle_gaps = []
+    for start, length in gaps[:5]:
+        gap = {
+            "offset_ms": round((start - t_min) / 1e3, 3),
+            "duration_ms": round(length / 1e3, 3),
+        }
+        during = stage_at(start)
+        if during is not None:
+            gap["during"] = during
+        idle_gaps.append(gap)
+    summary = {
         "verdict": verdict,
         "member": parsed_member,
+        "device_plane": True,
         "span_ms": round(span_us / 1e3, 3),
         "device_busy_ms": round(busy_us / 1e3, 3),
         "device_op_wall_share": round(busy_share, 4),
@@ -500,14 +551,11 @@ def summarize_profile(data: bytes, *, top_n: int = 10) -> dict:
             }
             for name, (total, count) in top_ops
         ],
-        "idle_gaps": [
-            {
-                "offset_ms": round((start - t_min) / 1e3, 3),
-                "duration_ms": round(length / 1e3, 3),
-            }
-            for start, length in gaps[:5]
-        ],
+        "idle_gaps": idle_gaps,
     }
+    if runner_stages:
+        summary["runner_stages"] = runner_stages
+    return summary
 
 
 @dataclass

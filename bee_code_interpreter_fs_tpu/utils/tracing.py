@@ -257,10 +257,14 @@ class TraceRing:
             self._spans.clear()
 
     def trace(self, trace_id: str) -> list[dict]:
-        """Every retained span of one trace, in start order."""
+        """Every retained span of one trace, in start order; of spans that
+        start together the longer first (a parent before its first child)."""
         with self._lock:
             spans = [s for s in self._spans if s.get("trace_id") == trace_id]
-        return sorted(spans, key=lambda s: s.get("start_unix", 0.0))
+        return sorted(
+            spans,
+            key=lambda s: (s.get("start_unix", 0.0), -s.get("duration_s", 0.0)),
+        )
 
     def recent(self, limit: int = 20, offset: int = 0) -> list[dict]:
         """Newest distinct traces (summary rows for the debug endpoint);
@@ -482,12 +486,13 @@ class Tracer:
         attributes: dict | None = None,
         events: Iterable[dict] = (),
         status: str = "ok",
-    ) -> None:
+    ) -> str | None:
         """Export an already-timed span directly — how remotely measured
-        work (the sandbox executor's install/exec/collect phases) is grafted
-        into a trace as child spans after the fact."""
+        work (the sandbox executor's stages) and work timed around a call
+        stack (the edges of a request) is grafted into a trace as child
+        spans after the fact. Returns the new span's id, for its children."""
         if not self.enabled:
-            return
+            return None
         span = {
             "name": name,
             "trace_id": trace_id,
@@ -503,6 +508,7 @@ class Tracer:
         if events:
             span["events"] = events
         self._export(span)
+        return span["span_id"]
 
     # --------------------------------------------------------------- plumbing
 
@@ -559,6 +565,15 @@ class Tracer:
 
 def current_span() -> Span | NullSpan | None:
     return current_span_var.get()
+
+
+def trace_headers() -> dict | None:
+    """Headers that carry the current span's context over a wire hop to a
+    sandbox (its executor stamps its own stage timings into a `trace` block
+    of the reply then). None when there is nothing to propagate."""
+    span = current_span()
+    traceparent = span.traceparent() if span is not None else None
+    return {"traceparent": traceparent} if traceparent else None
 
 
 def current_trace_id() -> str | None:

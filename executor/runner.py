@@ -10,8 +10,9 @@ sandbox is still sitting in the warm pool — so by the time an Execute arrives,
 `import jax` and device init are already done and user code sees a hot TPU.
 
 Protocol: newline-delimited JSON. fd 3 = requests in, fd 4 = responses out.
-Request:  {"source_path": ..., "stdout_path": ..., "stderr_path": ..., "env": {...}}
-Response: {"exit_code": int}
+Request:  {"source_path": ..., "stdout_path": ..., "stderr_path": ..., "env": {...},
+           "sent_mono": <the server's CLOCK_MONOTONIC at the pipe write>}
+Response: {"exit_code": int, "stages": [[name, start_offset_s, duration_s], ...]}
 Ready line (sent once at boot):
   {"ready": true, "backend": ..., "device_count": n, "device_kind": ...}
 
@@ -91,6 +92,84 @@ def _register_cache_listener() -> None:
 def _cache_counts() -> tuple[int, int]:
     """(hits, misses) so far."""
     return _CACHE_EVENTS["hits"], _CACHE_EVENTS["misses"]
+
+
+# ---------------------------------------------------------------------------
+# Stage clock of a serial turn. The server puts its CLOCK_MONOTONIC reading at
+# the pipe write (`sent_mono`) into the request line; time.monotonic() is the
+# same kernel clock on the same host, so the reply's `stages` list
+# ([name, start_offset_s, duration_s], offsets from that reading) tiles the
+# turn from the write to the reply: each stage ends where the next begins.
+# The server forwards them as `runner.<name>` in its `trace` block. Names are
+# a fixed set (the server's allow-list; they label a histogram upstream).
+
+_STAGES: list = []  # [(name, started)] of the request in hand
+_STAGE_ANNOTATION: list = []  # the open TraceAnnotation, while a capture runs
+_ANNOTATING = False  # True only between the profiler's start and its stop
+# [(started, seconds)] of the full collection after the last reset's ack,
+# reported once, on the next reply that carries stages.
+_GC_AFTER_RESET: list = []
+
+
+def _trace_annotation(name: str):
+    """The stage as a host-plane event of the running JAX capture, joined
+    to GET /traces/{trace_id} by the request's trace id."""
+    import jax
+
+    trace_id = getattr(_TRACE_LOCAL, "trace_id", None) or ""
+    return jax.profiler.TraceAnnotation(f"runner.{name}", trace_id=trace_id)
+
+
+def _annotate(on: bool) -> None:
+    """Close the open annotation; with `on`, wrap the stage in hand (and
+    every later one, until `_annotate(False)`) in one of its own. Only a
+    turn that runs under the profiler ever gets here with `on`."""
+    global _ANNOTATING
+    while _STAGE_ANNOTATION:
+        try:
+            _STAGE_ANNOTATION.pop().__exit__(None, None, None)
+        except Exception:  # noqa: BLE001 — an annotation never fails a turn
+            pass
+    _ANNOTATING = on
+    if on and _STAGES:
+        try:
+            annotation = _trace_annotation(_STAGES[-1][0])
+            annotation.__enter__()
+            _STAGE_ANNOTATION.append(annotation)
+        except Exception:  # noqa: BLE001
+            _ANNOTATING = False
+
+
+def _stage(name: str, started: float | None = None) -> None:
+    """`name` begins now (or began at `started`); the stage before it ends."""
+    _STAGES.append((name, time.monotonic() if started is None else started))
+    if _ANNOTATING:
+        _annotate(True)
+
+
+def _begin_stages(name: str, started: float) -> None:
+    del _STAGES[:]
+    _annotate(False)
+    _stage(name, started)
+
+
+def _take_stages(sent_mono) -> list | None:
+    """The request's stages for its reply, `pickup` (pipe write until the
+    line was read) first; None where the server sent no clock reading."""
+    ended = time.monotonic()
+    _annotate(False)
+    marks = list(_STAGES)
+    del _STAGES[:]
+    if not isinstance(sent_mono, (int, float)) or isinstance(sent_mono, bool):
+        return None
+    out = []
+    while _GC_AFTER_RESET:
+        started, seconds = _GC_AFTER_RESET.pop()
+        out.append(["gc_after_reset", round(started - sent_mono, 6), round(seconds, 6)])
+    marks.insert(0, ("pickup", float(sent_mono)))
+    for (name, started), (_next, until) in zip(marks, marks[1:] + [("", ended)]):
+        out.append([name, round(started - sent_mono, 6), round(max(0.0, until - started), 6)])
+    return out
 
 
 def _send(obj: dict) -> None:
@@ -500,7 +579,13 @@ def _run_one(req: dict) -> tuple[int, str | None]:
     # is the oom violation caught cleanly; without one it is ordinary user
     # code raising (or exhausting the host for real — the watchdog's case).
     mem_limited = _request_limit(limits, "memory_bytes", _resolve_mem_budget()) > 0
-    trace_dir = _start_profile() if _profile_requested(env) else None
+    trace_dir = None
+    if _profile_requested(env):
+        _stage("profile_start")
+        trace_dir = _start_profile()
+        if trace_dir is not None:
+            _annotate(True)
+    _stage("limits_arm")
     restore_rlimits = _apply_user_rlimits(limits)
     # User code may rebind/ignore SIGINT; restore it afterwards or a single
     # tenant could permanently disable the server's cooperative timeout
@@ -510,7 +595,11 @@ def _run_one(req: dict) -> tuple[int, str | None]:
     saved_sigint = _signal.getsignal(_signal.SIGINT)
     try:
         sys.argv = [source_path]  # argv[0] stays the user's path
-        runpy.run_path(run_path, run_name="__main__")
+        _stage("user_code")
+        try:
+            runpy.run_path(run_path, run_name="__main__")
+        finally:
+            _stage("limits_restore")
     except SystemExit as e:
         exit_code = _exit_code_of(e)
     except _CpuTimeExceeded:
@@ -540,8 +629,11 @@ def _run_one(req: dict) -> tuple[int, str | None]:
             pass
         sys.argv = saved_argv
         if trace_dir is not None:
+            _annotate(False)
+            _stage("profile_stop")
             # Inside the redirect so profiler chatter lands in the capture.
             _finish_profile(trace_dir)
+        _stage("finish")
         try:
             sys.stdout.flush()
             sys.stderr.flush()
@@ -1238,6 +1330,7 @@ def main() -> None:
             line, buf = buf.split(b"\n", 1)
             if not line.strip():
                 continue
+            line_read = time.monotonic()
             req = None
             replied = False
 
@@ -1258,15 +1351,26 @@ def main() -> None:
             try:
                 req = json.loads(line)
                 if req.get("op") == "reset":
+                    _begin_stages("scrub", line_read)
                     ok = _reset(snapshot)
-                    _reply({"ok": ok})
+                    reply = {"ok": ok}
+                    stages = _take_stages(req.get("sent_mono"))
+                    if stages is not None:
+                        reply["stages"] = stages
+                    _reply(reply)
                     if ok:
                         import gc
 
                         # Post-ack: drop the previous generation's host and
                         # device buffers while the server wipes the
-                        # workspace — off the next request's critical path.
+                        # workspace — off the next request's critical path
+                        # unless that request is already waiting, which its
+                        # `pickup` and this `gc_after_reset` then show.
+                        gc_started = time.monotonic()
                         gc.collect()
+                        _GC_AFTER_RESET[:] = [
+                            (gc_started, time.monotonic() - gc_started)
+                        ]
                 elif req.get("op") == "snapshot":
                     _reply(_snapshot_state(snapshot, req))
                 elif req.get("op") == "restore":
@@ -1282,6 +1386,7 @@ def main() -> None:
                     _set_trace_id(None)
                     _reply(reply)
                 else:
+                    _begin_stages("prepare", line_read)
                     _set_trace_id(req.get("trace_id"))
                     hits_before, misses_before = _cache_counts()
                     # Device-memory bracket around the run, only when the
@@ -1302,6 +1407,9 @@ def main() -> None:
                         hits_after, misses_after = _cache_counts()
                         reply["cache_hits"] = hits_after - hits_before
                         reply["cache_misses"] = misses_after - misses_before
+                    stages = _take_stages(req.get("sent_mono"))
+                    if stages is not None:
+                        reply["stages"] = stages
                     _set_trace_id(None)
                     _reply(reply)
             except KeyboardInterrupt:

@@ -599,6 +599,16 @@ long long now_ms() {
   return ts.tv_sec * 1000LL + ts.tv_nsec / 1000000LL;
 }
 
+// CLOCK_MONOTONIC as seconds: the one clock of every stage timing in the
+// `trace` blocks. The warm runner's time.monotonic() reads the same kernel
+// clock on the same host, so the server's reading at the pipe write is an
+// origin both processes share.
+double mono_s() {
+  struct timespec ts;
+  clock_gettime(CLOCK_MONOTONIC, &ts);
+  return ts.tv_sec + ts.tv_nsec / 1e9;
+}
+
 std::atomic<long long> g_boot_ms{0};
 // Warm-up (jax import + device attach) window: nonzero while one is running.
 std::atomic<long long> g_attach_start_ms{0};
@@ -768,9 +778,14 @@ class WarmRunner {
   // process (stray children, workspace modules, env/cwd) while keeping the
   // device lease. False ⇒ the runner is unscrubbable (killed) and the whole
   // process must be disposed.
-  bool reset(double timeout_s) {
-    minijson::Value resp;
-    if (execute("{\"op\":\"reset\"}", timeout_s, resp) != ExecResult::kOk)
+  // `sent_mono` is the caller's mono_s() at the call; the runner's reply
+  // (its `stages` among it) is handed back for the `/reset` trace block.
+  bool reset(double timeout_s, double sent_mono, minijson::Value& resp) {
+    minijson::Object reqo;
+    reqo["op"] = minijson::Value(std::string("reset"));
+    reqo["sent_mono"] = minijson::Value(sent_mono);
+    if (execute(minijson::Value(reqo).dump(), timeout_s, resp) !=
+        ExecResult::kOk)
       return false;
     if (!resp.get_bool("ok", false)) {
       kill_runner();
@@ -1448,7 +1463,55 @@ struct RunOutcome {
   // request asked for it AND the runner could measure (warm path; the cold
   // subprocess has no instrumented interpreter to sample).
   minijson::Value device_memory;
+  // Stage clock of a warm run (mono_s(); 0 = no warm run): the request
+  // line going into the runner's pipe, its reply line parsed, the guards
+  // (watchdog thread, cgroup event read) down again. `runner_stages` is the
+  // runner's own `stages` list as it sent it, offsets from `sent_mono`.
+  double sent_mono = 0;
+  double replied_mono = 0;
+  double guards_down_mono = 0;
+  minijson::Value runner_stages;
 };
+
+// One entry of a `trace` block: a stage named `name`, nested in time (and,
+// for a control plane that reads `parent`, in the tree) inside the entry
+// called `parent`; offsets are seconds since the request's arrival.
+void add_trace_span(minijson::Array& spans, const std::string& name,
+                    double start_offset, double dur,
+                    const char* parent = nullptr) {
+  minijson::Object s;
+  s["name"] = minijson::Value(name);
+  s["start_offset_s"] = minijson::Value(start_offset);
+  s["duration_s"] = minijson::Value(dur < 0 ? 0.0 : dur);
+  if (parent) s["parent"] = minijson::Value(std::string(parent));
+  spans.push_back(minijson::Value(s));
+}
+
+// The warm runner's `stages` ([name, start_offset_s, duration_s], offsets
+// from the pipe write) forwarded as `runner.<name>` children of `parent`.
+// Names are a fixed set: they end up as labels of the control plane's
+// span histogram, and nothing the user's code could say may mint one.
+void add_runner_stages(minijson::Array& spans, const minijson::Value& stages,
+                       double sent_offset, const char* parent) {
+  static const char* const kKnown[] = {
+      "gc_after_reset", "pickup",        "prepare",      "profile_start",
+      "limits_arm",     "user_code",     "limits_restore", "profile_stop",
+      "finish",         "scrub"};
+  if (!stages.is_array()) return;
+  for (const auto& entry : stages.as_array()) {
+    if (!entry.is_array()) continue;
+    const auto& e = entry.as_array();
+    if (e.size() != 3 || !e[0].is_string() || !e[1].is_number() ||
+        !e[2].is_number())
+      continue;
+    for (const char* known : kKnown) {
+      if (e[0].as_string() != known) continue;
+      add_trace_span(spans, "runner." + e[0].as_string(),
+                     sent_offset + e[1].as_number(), e[2].as_number(), parent);
+      break;
+    }
+  }
+}
 
 // The execution core shared by /execute and /execute/stream: run the script
 // through the warm runner when available, else a cold subprocess; stdout/
@@ -1538,9 +1601,12 @@ RunOutcome run_user_code(const std::string& script_path,
         // a memory.max OOM kill / pids.max fork refusal DURING this run
         // reclassifies a generic runner death below.
         g_runner_scope.refresh_baseline();
+        out.sent_mono = mono_s();
+        reqo["sent_mono"] = minijson::Value(out.sent_mono);
         WarmRunner::ExecResult r = g_state.runner->execute(
             minijson::Value(reqo).dump(), timeout_s > 0 ? timeout_s + 0.5 : 0,
             resp, /*allow_interrupt=*/true);
+        out.replied_mono = mono_s();
         wd.stop();
         out.ran_warm = true;
         switch (r) {
@@ -1552,6 +1618,7 @@ RunOutcome run_user_code(const std::string& script_path,
             out.cache_misses =
                 static_cast<long long>(resp.get_number("cache_misses", -1));
             out.device_memory = resp.get("device_memory");
+            out.runner_stages = resp.get("stages");
             break;
           case WarmRunner::ExecResult::kTimeout:
             out.timed_out = true;
@@ -1585,6 +1652,7 @@ RunOutcome run_user_code(const std::string& script_path,
           const char* cg_kind = g_runner_scope.violation();
           if (cg_kind) out.violation = cg_kind;
         }
+        out.guards_down_mono = mono_s();
       } else {
         // Runner found already dead at request time (e.g. OOM-killed
         // between requests): without flagging a restart here, the sandbox
@@ -1798,24 +1866,21 @@ std::string pure_result_sha256(const std::string& out_s,
 
 void handle_execute_impl(const minihttp::Request& req, minihttp::Conn& conn,
                          bool streaming) {
+  // The request's arrival: the origin of every offset in the `trace` block.
+  const double t_req = mono_s();
+  auto since_req = [t_req]() { return mono_s() - t_req; };
   // Lease fencing FIRST: a stale claim must be refused before the body is
   // even read, and above all before exec_mutex — a wedged op may be
   // holding that lock for minutes, and a stale dispatch queueing behind it
   // is exactly the re-wedge this check exists to prevent.
   if (reject_stale_lease(req, conn)) return;
-  // W3C trace context from the control plane: when present, per-phase
-  // timings (install/exec/collect) are stamped into a `trace` block on the
+  // W3C trace context from the control plane: when present, the handler's
+  // stages (parse, install, exec, collect and what each is made of, the
+  // warm runner's own among them) are stamped into a `trace` block on the
   // response so the orchestrator can graft them into the request's trace
   // as child spans. Offsets are relative to this request's own start — the
   // two processes' clocks never have to agree.
   std::string traceparent = req.header("traceparent");
-  struct timespec t_req;
-  clock_gettime(CLOCK_MONOTONIC, &t_req);
-  auto since_req = [&t_req]() {
-    struct timespec now;
-    clock_gettime(CLOCK_MONOTONIC, &now);
-    return (now.tv_sec - t_req.tv_sec) + (now.tv_nsec - t_req.tv_nsec) / 1e9;
-  };
 
   std::string body = conn.read_body();
   minijson::Value parsed;
@@ -1922,12 +1987,15 @@ void handle_execute_impl(const minihttp::Request& req, minihttp::Conn& conn,
     }
   }
 
-  // Phase timings for the trace block: install (dependency auto-install +
-  // pre-exec workspace snapshot), exec (user code), collect (post-exec
-  // snapshot + output read + manifest reconcile).
+  // Phase timings for the trace block: parse (everything above: lease
+  // check, body, exec_mutex, scratch dir, script), install (dependency
+  // auto-install + pre-exec snapshots, the latter its child scan_before),
+  // exec (user code, between the guards going up and coming down), collect
+  // (post-exec snapshot, output read, manifest reconcile, cache diff).
   double install_start = since_req();
   maybe_install_deps(script_path);
 
+  double scan_before_start = since_req();
   std::map<std::string, FileSig> before;
   scan_dir(g_state.workspace, "", before);
   // Compile-cache observability: diff the cache dir across the run — new
@@ -1937,14 +2005,11 @@ void handle_execute_impl(const minihttp::Request& req, minihttp::Conn& conn,
   std::map<std::string, FileSig> cc_before;
   if (g_state.compile_cache_enabled)
     scan_dir(g_state.compile_cache_dir, "", cc_before);
-  double install_s = since_req() - install_start;
 
   std::string stdout_path = scratch + "/cap.stdout";
   std::string stderr_path = scratch + "/cap.stderr";
 
   double exec_start = since_req();
-  struct timespec t0, t1;
-  clock_gettime(CLOCK_MONOTONIC, &t0);
 
   RunOutcome run;
   if (!streaming) {
@@ -2035,11 +2100,9 @@ void handle_execute_impl(const minihttp::Request& req, minihttp::Conn& conn,
   bool ran_warm = run.ran_warm;
   bool restart_runner = run.restarted;
 
-  clock_gettime(CLOCK_MONOTONIC, &t1);
-  double duration =
-      (t1.tv_sec - t0.tv_sec) + (t1.tv_nsec - t0.tv_nsec) / 1e9;
-
   double collect_start = since_req();
+  double duration = collect_start - exec_start;
+
   std::map<std::string, FileSig> after;
   scan_dir(g_state.workspace, "", after);
 
@@ -2051,6 +2114,7 @@ void handle_execute_impl(const minihttp::Request& req, minihttp::Conn& conn,
       limits::dir_usage_bytes(g_state.workspace) > eff_limits.disk_bytes) {
     run.violation = limits::kDiskQuota;
   }
+  double outputs_start = since_req();
 
   bool out_trunc = false, err_trunc = false;
   std::string out_s = read_file_capped(stdout_path, output_cap, &out_trunc);
@@ -2126,6 +2190,7 @@ void handle_execute_impl(const minihttp::Request& req, minihttp::Conn& conn,
   if (!run.violation.empty()) resp["violation"] = minijson::Value(run.violation);
   resp["files"] = minijson::Value(files);
   if (g_state.manifest_enabled) resp["deleted"] = minijson::Value(deleted);
+  double cache_scan_start = since_req();
   if (g_state.compile_cache_enabled) {
     std::map<std::string, FileSig> cc_after;
     scan_dir(g_state.compile_cache_dir, "", cc_after);
@@ -2159,29 +2224,6 @@ void handle_execute_impl(const minihttp::Request& req, minihttp::Conn& conn,
   // run, plus the runner's RSS — the per-request HBM attribution feed.
   if (run.device_memory.is_object())
     resp["device_memory"] = run.device_memory;
-  if (!traceparent.empty()) {
-    // The control plane sent trace context: report per-phase timings so it
-    // can graft them into the request's trace as child spans. Offsets are
-    // seconds since THIS request started on this host (the grafter anchors
-    // them to its own span start — no cross-process clock agreement).
-    double collect_s = since_req() - collect_start;
-    minijson::Object trace;
-    trace["traceparent"] = minijson::Value(traceparent);
-    minijson::Array trace_spans;
-    auto add_span = [&trace_spans](const char* name, double start_offset,
-                                   double dur) {
-      minijson::Object s;
-      s["name"] = minijson::Value(std::string(name));
-      s["start_offset_s"] = minijson::Value(start_offset);
-      s["duration_s"] = minijson::Value(dur);
-      trace_spans.push_back(minijson::Value(s));
-    };
-    add_span("install", install_start, install_s);
-    add_span("exec", exec_start, duration);
-    add_span("collect", collect_start, collect_s);
-    trace["spans"] = minijson::Value(trace_spans);
-    resp["trace"] = minijson::Value(trace);
-  }
   resp["warm"] = minijson::Value(ran_warm);
   // True when the warm runner was killed (timeout) or died during this
   // request: its in-process state is gone and a rewarm is in flight. The
@@ -2192,6 +2234,45 @@ void handle_execute_impl(const minihttp::Request& req, minihttp::Conn& conn,
     resp["pure"] = minijson::Value(true);
     resp["result_sha256"] = minijson::Value(
         pure_result_sha256(out_s, err_s, exit_code, changed_file_shas));
+  }
+  if (!traceparent.empty()) {
+    // The control plane sent trace context: report per-stage timings so it
+    // can graft them into the request's trace as child spans. Offsets are
+    // seconds since THIS request started on this host (the grafter anchors
+    // them to its own span start — no cross-process clock agreement). The
+    // block is the last thing written into the reply, so `total_s` holds
+    // everything the handler did but serialise and send it.
+    double block_at = since_req();
+    minijson::Object trace;
+    trace["traceparent"] = minijson::Value(traceparent);
+    minijson::Array trace_spans;
+    add_trace_span(trace_spans, "parse", 0.0, install_start);
+    add_trace_span(trace_spans, "install", install_start,
+                   exec_start - install_start);
+    add_trace_span(trace_spans, "scan_before", scan_before_start,
+                   exec_start - scan_before_start, "install");
+    add_trace_span(trace_spans, "exec", exec_start, duration);
+    if (run.sent_mono > 0) {
+      double sent = run.sent_mono - t_req;
+      double replied = run.replied_mono - t_req;
+      add_trace_span(trace_spans, "guard_start", exec_start, sent - exec_start,
+                     "exec");
+      add_trace_span(trace_spans, "runner_wait", sent, replied - sent, "exec");
+      add_runner_stages(trace_spans, run.runner_stages, sent, "exec");
+      add_trace_span(trace_spans, "guard_stop", replied,
+                     run.guards_down_mono - run.replied_mono, "exec");
+    }
+    add_trace_span(trace_spans, "collect", collect_start,
+                   block_at - collect_start);
+    add_trace_span(trace_spans, "scan_after", collect_start,
+                   outputs_start - collect_start, "collect");
+    add_trace_span(trace_spans, "outputs", outputs_start,
+                   cache_scan_start - outputs_start, "collect");
+    add_trace_span(trace_spans, "cache_scan", cache_scan_start,
+                   block_at - cache_scan_start, "collect");
+    trace["spans"] = minijson::Value(trace_spans);
+    trace["total_s"] = minijson::Value(block_at);
+    resp["trace"] = minijson::Value(trace);
   }
   if (!streaming) {
     conn.send_response(200, "application/json", minijson::Value(resp).dump());
@@ -2784,7 +2865,9 @@ void handle_warmup(const minihttp::Request&, minihttp::Conn& conn) {
 void handle_reset(const minihttp::Request& req, minihttp::Conn& conn) {
   // A /reset from a fenced predecessor's control path (a retry racing a
   // dispose) must not wipe the successor's workspace mid-request.
+  const double t_req = mono_s();
   if (reject_stale_lease(req, conn)) return;
+  std::string traceparent = req.header("traceparent");
   conn.drain_body();
   std::lock_guard<std::mutex> lock(g_state.exec_mutex);
   auto refuse = [&conn](const char* reason) {
@@ -2793,13 +2876,17 @@ void handle_reset(const minihttp::Request& req, minihttp::Conn& conn) {
     resp["reason"] = minijson::Value(std::string(reason));
     conn.send_response(409, "application/json", minijson::Value(resp).dump());
   };
+  double runner_sent = 0;
+  minijson::Value runner_reply;
   if (g_state.warm_enabled && g_state.runner) {
     if (g_warm_state.load() != kWarmReady) {
       refuse("runner not warm");
       return;
     }
     std::lock_guard<std::mutex> rlock(g_state.runner_mutex);
-    if (!g_state.runner->alive() || !g_state.runner->reset(8.0)) {
+    runner_sent = mono_s();
+    if (!g_state.runner->alive() ||
+        !g_state.runner->reset(8.0, runner_sent, runner_reply)) {
       {
         std::lock_guard<std::mutex> l(g_warm_transition_mutex);
         g_warm_state = kWarmFailed;
@@ -2809,6 +2896,7 @@ void handle_reset(const minihttp::Request& req, minihttp::Conn& conn) {
       return;
     }
   }
+  const double wipe_start = mono_s();
   // Runner scrubbed first (strays that could still write files are dead),
   // then the filesystem: workspace AND runtime-packages — a package the
   // previous user planted must never be importable by the next one. The
@@ -2848,6 +2936,25 @@ void handle_reset(const minihttp::Request& req, minihttp::Conn& conn) {
   }
   minijson::Value status = warm_status_body();
   status.as_object()["ok"] = minijson::Value(true);
+  if (!traceparent.empty()) {
+    // Same kind of block as /execute's: the turnover's two stages, the
+    // runner's scrub inside the first, offsets from this request's arrival.
+    double block_at = mono_s();
+    minijson::Array trace_spans;
+    if (runner_sent > 0) {
+      add_trace_span(trace_spans, "runner_reset", runner_sent - t_req,
+                     wipe_start - runner_sent);
+      add_runner_stages(trace_spans, runner_reply.get("stages"),
+                        runner_sent - t_req, "runner_reset");
+    }
+    add_trace_span(trace_spans, "wipe", wipe_start - t_req,
+                   block_at - wipe_start);
+    minijson::Object trace;
+    trace["traceparent"] = minijson::Value(traceparent);
+    trace["spans"] = minijson::Value(trace_spans);
+    trace["total_s"] = minijson::Value(block_at - t_req);
+    status.as_object()["trace"] = minijson::Value(trace);
+  }
   conn.send_response(200, "application/json", status.dump());
 }
 

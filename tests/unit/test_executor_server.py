@@ -765,15 +765,145 @@ def test_execute_trace_block_with_traceparent(executor):
     trace = result["trace"]
     assert trace["traceparent"] == TRACEPARENT
     spans = {s["name"]: s for s in trace["spans"]}
-    assert set(spans) == {"install", "exec", "collect"}
-    for span in spans.values():
-        assert span["start_offset_s"] >= 0
-        assert span["duration_s"] >= 0
+    # The three phases every control plane reads; their stages ride along
+    # (test_execute_trace_block_stages_tile_the_handler).
+    assert set(spans) >= {"install", "exec", "collect"}
+    for name in ("install", "exec", "collect"):
+        assert "parent" not in spans[name]
+        assert spans[name]["start_offset_s"] >= 0
+        assert spans[name]["duration_s"] >= 0
     # Phases run in order: install, then exec, then collect.
     assert spans["install"]["start_offset_s"] <= spans["exec"]["start_offset_s"]
     assert spans["exec"]["start_offset_s"] <= spans["collect"]["start_offset_s"]
     # The exec span is the duration_s the response already reported.
     assert spans["exec"]["duration_s"] == result["duration_s"]
+
+
+SERVER_STAGES = {
+    # name -> the entry it nests in (None: a top-level phase of the handler)
+    "parse": None,
+    "install": None,
+    "scan_before": "install",
+    "exec": None,
+    "guard_start": "exec",
+    "runner_wait": "exec",
+    "guard_stop": "exec",
+    "collect": None,
+    "scan_after": "collect",
+    "outputs": "collect",
+    "cache_scan": "collect",
+}
+RUNNER_STAGES = [
+    "runner.pickup",
+    "runner.prepare",
+    "runner.limits_arm",
+    "runner.user_code",
+    "runner.limits_restore",
+    "runner.finish",
+]
+EPS = 1e-6  # the runner rounds its offsets to the microsecond
+
+
+def _ends(span):
+    return span["start_offset_s"] + span["duration_s"]
+
+
+def test_execute_trace_block_stages_tile_the_handler(executor):
+    """Every stage between the request's arrival and the reply is named:
+    present, ordered, non-negative, inside `total_s`; children inside the
+    phase they name as `parent`; the warm runner's own stages inside
+    `runner_wait`, each ending where the next begins. Nothing here times
+    anything: only order and nesting are asserted."""
+    client, ws = executor
+    result = client.post(
+        "/execute",
+        json={"source_code": "print('staged')"},
+        headers={"traceparent": TRACEPARENT},
+    ).json()
+    assert result["exit_code"] == 0 and result["warm"] is True
+    trace = result["trace"]
+    total = trace["total_s"]
+    entries = trace["spans"]
+    names = [s["name"] for s in entries]
+    assert len(names) == len(set(names))
+    spans = {s["name"]: s for s in entries}
+    assert set(spans) >= set(SERVER_STAGES) | set(RUNNER_STAGES)
+    for span in entries:
+        if span["name"] == "runner.gc_after_reset":
+            continue  # ran before this request: the one entry that may not nest
+        assert span["start_offset_s"] >= 0 and span["duration_s"] >= 0, span
+        assert _ends(span) <= total + EPS, span
+        parent = span.get("parent")
+        if parent is not None:
+            # a parent is an EARLIER entry of the same block
+            assert names.index(parent) < names.index(span["name"])
+            assert spans[parent]["start_offset_s"] <= span["start_offset_s"] + EPS
+            assert _ends(span) <= _ends(spans[parent]) + EPS
+    for name, parent in SERVER_STAGES.items():
+        assert spans[name].get("parent") == parent, name
+    # The four phases tile the handler in order, from 0 to total_s.
+    assert spans["parse"]["start_offset_s"] == 0
+    order = ["parse", "install", "exec", "collect"]
+    for earlier, later in zip(order, order[1:]):
+        assert abs(_ends(spans[earlier]) - spans[later]["start_offset_s"]) < EPS
+    assert abs(_ends(spans["collect"]) - total) < EPS
+    # ... and exec's own three, and collect's.
+    for parent, children in (
+        ("exec", ["guard_start", "runner_wait", "guard_stop"]),
+        ("collect", ["scan_after", "outputs", "cache_scan"]),
+    ):
+        assert abs(spans[children[0]]["start_offset_s"] - spans[parent]["start_offset_s"]) < EPS
+        for earlier, later in zip(children, children[1:]):
+            assert abs(_ends(spans[earlier]) - spans[later]["start_offset_s"]) < EPS
+    # The runner's stages: children of exec in the tree, inside runner_wait
+    # in time, tiling it from the pipe write on.
+    wait = spans["runner_wait"]
+    assert abs(spans["runner.pickup"]["start_offset_s"] - wait["start_offset_s"]) < EPS
+    for earlier, later in zip(RUNNER_STAGES, RUNNER_STAGES[1:]):
+        assert abs(_ends(spans[earlier]) - spans[later]["start_offset_s"]) < 2 * EPS
+    for name in RUNNER_STAGES:
+        assert spans[name]["parent"] == "exec"
+        assert spans[name]["start_offset_s"] >= wait["start_offset_s"] - EPS
+        assert _ends(spans[name]) <= _ends(wait) + EPS
+    # An unprofiled turn has no profiler stage.
+    assert "runner.profile_start" not in spans and "runner.profile_stop" not in spans
+
+
+def test_reset_trace_block_and_gc_after_reset(executor):
+    """`POST /reset` carries the same kind of block (runner_reset with the
+    runner's scrub inside it, then wipe); the runner's full collection
+    after the reset's ack is reported by the NEXT request, once."""
+    client, ws = executor
+    reply = client.post("/reset", headers={"traceparent": TRACEPARENT})
+    assert reply.status_code == 200, reply.text
+    body = reply.json()
+    assert body["ok"] is True
+    trace = body["trace"]
+    assert trace["traceparent"] == TRACEPARENT
+    spans = {s["name"]: s for s in trace["spans"]}
+    assert set(spans) == {"runner_reset", "runner.pickup", "runner.scrub", "wipe"}
+    for span in spans.values():
+        assert span["start_offset_s"] >= 0 and span["duration_s"] >= 0
+        assert _ends(span) <= trace["total_s"] + EPS
+    assert spans["runner.scrub"]["parent"] == "runner_reset"
+    assert _ends(spans["runner.scrub"]) <= _ends(spans["runner_reset"]) + EPS
+    assert abs(_ends(spans["runner_reset"]) - spans["wipe"]["start_offset_s"]) < EPS
+    # No trace context, no block: the wire is unchanged for an old control plane.
+    assert "trace" not in client.post("/reset").json()
+
+    def stages_of_next():
+        result = client.post(
+            "/execute",
+            json={"source_code": "print('next')"},
+            headers={"traceparent": TRACEPARENT},
+        ).json()
+        return {s["name"]: s for s in result["trace"]["spans"]}
+
+    after_reset = stages_of_next()
+    assert "runner.gc_after_reset" in after_reset
+    assert after_reset["runner.gc_after_reset"]["duration_s"] >= 0
+    assert after_reset["runner.gc_after_reset"]["parent"] == "exec"
+    assert "runner.gc_after_reset" not in stages_of_next()
 
 
 def test_execute_no_trace_block_without_traceparent(executor):
@@ -798,7 +928,7 @@ def test_execute_stream_trace_block(executor):
     final = lines[-1]
     assert final["exit_code"] == 0
     assert final["trace"]["traceparent"] == TRACEPARENT
-    assert {s["name"] for s in final["trace"]["spans"]} == {
+    assert {s["name"] for s in final["trace"]["spans"]} >= {
         "install",
         "exec",
         "collect",

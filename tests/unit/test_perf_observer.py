@@ -652,6 +652,66 @@ def test_summarize_profile_verdict_top_ops_share_and_gaps():
     assert "fusion.3" in summary["verdict"]
 
 
+def test_summarize_profile_without_a_device_process_claims_no_device_time():
+    """A capture with no device process (a CPU run, or a turn that ran no
+    device program): host events are not counted as the device's."""
+    from bee_code_interpreter_fs_tpu.services.perf_observer import (
+        summarize_profile,
+    )
+
+    events = [
+        {"ph": "M", "name": "process_name", "pid": 7,
+         "args": {"name": "/host:CPU"}},
+        {"ph": "X", "pid": 7, "name": "python busywork", "ts": 100,
+         "dur": 5000},
+        {"ph": "X", "pid": 7, "name": "runner.user_code", "ts": 600,
+         "dur": 4000},
+    ]
+    summary = summarize_profile(_trace_zip(events))
+    assert summary["device_plane"] is False
+    assert summary["span_ms"] == 5.0
+    assert "no device plane" in summary["verdict"]
+    for key in ("device_busy_ms", "device_op_wall_share", "top_ops", "idle_gaps"):
+        assert key not in summary
+    assert summary["runner_stages"] == [
+        {"name": "runner.user_code", "offset_ms": 0.5, "duration_ms": 4.0}
+    ]
+
+
+def test_summarize_profile_names_idle_gaps_by_the_runner_stage():
+    """Where the capture holds the warm runner's `runner.*` annotations,
+    each idle gap says which stage covered its start."""
+    from bee_code_interpreter_fs_tpu.services.perf_observer import (
+        summarize_profile,
+    )
+
+    events = [
+        {"ph": "M", "name": "process_name", "pid": 1,
+         "args": {"name": "/device:TPU:0"}},
+        {"ph": "M", "name": "process_name", "pid": 2,
+         "args": {"name": "/host:CPU"}},
+        {"ph": "X", "pid": 2, "name": "runner.limits_arm", "ts": 0, "dur": 1000},
+        {"ph": "X", "pid": 2, "name": "runner.user_code", "ts": 1000,
+         "dur": 8000},
+        {"ph": "X", "pid": 2, "name": "runner.limits_restore", "ts": 9000,
+         "dur": 1500},
+        {"ph": "X", "pid": 1, "name": "fusion.8", "ts": 500, "dur": 1500},
+        {"ph": "X", "pid": 1, "name": "fusion.1", "ts": 5000, "dur": 3000},
+        {"ph": "X", "pid": 1, "name": "copy.2", "ts": 10000, "dur": 500},
+    ]
+    summary = summarize_profile(_trace_zip(events))
+    assert summary["device_plane"] is True
+    assert summary["device_busy_ms"] == 5.0
+    assert summary["idle_gaps"] == [
+        {"offset_ms": 2.0, "duration_ms": 3.0, "during": "runner.user_code"},
+        {"offset_ms": 8.0, "duration_ms": 2.0, "during": "runner.user_code"},
+    ]
+    assert [s["name"] for s in summary["runner_stages"]] == [
+        "runner.limits_arm", "runner.user_code", "runner.limits_restore",
+    ]
+    assert "runner.user_code" not in [op["name"] for op in summary["top_ops"]]
+
+
 def test_summarize_profile_degrades_without_a_trace_member():
     import io
     import zipfile
