@@ -27,13 +27,47 @@ class SandboxSpawnError(RuntimeError):
     pass
 
 
+class ResetClient:
+    """The HTTP client a backend POSTs /reset with, kept with its keep-alive
+    connections for the backend's life: built on first use (inside the
+    running loop, so never at import or in a constructor) and closed by the
+    backend's `close()`, after which the backend has no sandbox left to
+    reset. Building one is synchronous work on the event loop (its TLS
+    context: 26 ms on the chip's host, PERF.md), once per turnover before.
+
+    A plain transport, never the executor's `_http_client()`: that one may
+    carry a fault-injecting transport whose seeded draws /reset must not
+    consume. No connection cap: a slice's hosts are POSTed to at once, and
+    the live hosts bound the connections. A pooled connection the sandbox
+    has closed is dropped by httpcore before reuse, not handed a request."""
+
+    def __init__(self) -> None:
+        self._client: httpx.AsyncClient | None = None
+
+    def get(self) -> httpx.AsyncClient:
+        if self._client is None:
+            self._client = httpx.AsyncClient(
+                limits=httpx.Limits(
+                    max_connections=None,
+                    max_keepalive_connections=64,
+                    keepalive_expiry=30.0,
+                ),
+            )
+        return self._client
+
+    async def aclose(self) -> None:
+        if self._client is not None:
+            await self._client.aclose()
+
+
 async def reset_sandbox_over_http(
-    sandbox: "Sandbox", *, timeout: float = 15.0
+    sandbox: "Sandbox", kept: ResetClient, *, timeout: float
 ) -> "Sandbox | None":
     """Shared generation-turnover fan-out: POST /reset to every host of the
-    sandbox; all must answer 200 + ok. Returns the sandbox with its
-    generation bumped, or None (caller must dispose). Backend-specific
-    prechecks (process liveness, pod registry) stay in the backends."""
+    sandbox over the backend's kept client; all must answer 200 + ok within
+    `timeout` seconds each. Returns the sandbox with its generation bumped,
+    or None (caller must dispose). Backend-specific prechecks (process
+    liveness, pod registry) stay in the backends."""
     # The turnover's trace context, where it has one: the executor then
     # stamps its own stages (runner_reset, wipe) into the reply, and they are
     # left on `meta["reset_trace"]`, one block per host, for the caller to
@@ -41,18 +75,17 @@ async def reset_sandbox_over_http(
     headers = tracing.trace_headers()
     started = time.perf_counter()
     try:
-        async with httpx.AsyncClient(timeout=httpx.Timeout(timeout)) as client:
-            # Building the client (its TLS context among it) is synchronous
-            # work on the event loop, once per turnover: the caller gives it
-            # a span of its own.
-            sandbox.meta["reset_client_s"] = time.perf_counter() - started
-            resps = await asyncio.gather(
-                *(
-                    client.post(f"{url}/reset", headers=headers)
-                    for url in sandbox.host_urls
-                ),
-                return_exceptions=True,
-            )
+        client = kept.get()
+        # Obtaining the client has a span of its own in the caller's trace
+        # (`sandbox.reset_client`): a backend's first turnover builds it.
+        sandbox.meta["reset_client_s"] = time.perf_counter() - started
+        resps = await asyncio.gather(
+            *(
+                client.post(f"{url}/reset", headers=headers, timeout=timeout)
+                for url in sandbox.host_urls
+            ),
+            return_exceptions=True,
+        )
     except Exception:  # noqa: BLE001 — reuse is best-effort
         return None
     blocks = []

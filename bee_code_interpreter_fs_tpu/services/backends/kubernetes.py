@@ -31,6 +31,7 @@ from ...config import Config
 from ..kubectl import Kubectl, KubectlError
 from ..limits import sandbox_limit_env
 from .base import (
+    ResetClient,
     Sandbox,
     SandboxBackend,
     SandboxSpawnError,
@@ -81,6 +82,7 @@ class KubernetesSandboxBackend(SandboxBackend):
         self._owner_ref: dict | None | bool = None  # None = not looked up yet
         self._owner_lock = asyncio.Lock()
         self._live: dict[str, Sandbox] = {}
+        self._reset_client = ResetClient()
         self._cleanup_tasks: set[asyncio.Task] = set()
         self._breakers = None  # BreakerBoard, bound by the executor
 
@@ -711,7 +713,9 @@ class KubernetesSandboxBackend(SandboxBackend):
             return None
         if sandbox.id not in self._live:
             return None  # already deleted / unknown
-        return await reset_sandbox_over_http(sandbox, timeout=15.0)
+        return await reset_sandbox_over_http(
+            sandbox, self._reset_client, timeout=15.0
+        )
 
     async def delete_by_name(self, name: str) -> None:
         self._live.pop(name, None)
@@ -730,6 +734,7 @@ class KubernetesSandboxBackend(SandboxBackend):
             await self.delete_by_name(sandbox.id)
 
     async def close(self) -> None:
+        await self._reset_client.aclose()
         pending = list(self._cleanup_tasks)
         if pending:
             await asyncio.gather(*pending, return_exceptions=True)

@@ -31,6 +31,7 @@ import httpx
 from ...config import REPO_ROOT, Config
 from ..limits import sandbox_limit_env
 from .base import (
+    ResetClient,
     Sandbox,
     SandboxBackend,
     SandboxSpawnError,
@@ -113,6 +114,7 @@ class LocalSandboxBackend(SandboxBackend):
             else warm_import_jax
         )
         self._procs: dict[str, tuple[asyncio.subprocess.Process, str]] = {}
+        self._reset_client = ResetClient()
         # libtpu is exclusive-access: only `local_tpu_slots` warm-JAX
         # sandboxes may hold the local TPU at once. Spawns acquire a slot
         # BEFORE triggering the runner's jax import (POST /warmup) and
@@ -562,7 +564,9 @@ class LocalSandboxBackend(SandboxBackend):
             entry = self._procs.get(host_id)
             if entry is None or entry[0].returncode is not None:
                 return None  # process gone or already dying
-        return await reset_sandbox_over_http(sandbox, timeout=10.0)
+        return await reset_sandbox_over_http(
+            sandbox, self._reset_client, timeout=10.0
+        )
 
     async def delete(self, sandbox: Sandbox) -> None:
         # Concurrent per-host teardown: the TERM grace + reap timeout would
@@ -578,6 +582,7 @@ class LocalSandboxBackend(SandboxBackend):
         logger.info("deleted local sandbox %s", sandbox.id)
 
     async def close(self) -> None:
+        await self._reset_client.aclose()
         await asyncio.gather(
             *(self._kill_host(host_id) for host_id in list(self._procs))
         )
