@@ -8,6 +8,10 @@ PEAKS = {
     "TPU v5 lite": {
         "source": "cloud.google.com/tpu/docs/v5e (System architecture, TPU v5e)",
         "bytes_per_s": 819e9,
+        # The same page: 197 TFLOP/s in bfloat16, its only published
+        # floating-point peak (a float32 `highest` matmul is several bfloat16
+        # passes and has none of its own). For a `floor` stated in flops.
+        "bf16_flops_per_s": 197e12,
     },
 }
 

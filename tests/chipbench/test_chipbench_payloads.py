@@ -1,15 +1,13 @@
 """Every payload's `test` block under stock python, through the plain
-reference that the stateless configuration uses."""
+reference of the configuration whose cell deals the payload."""
 
 import json
 
 import pytest
 
-from chipbench_helpers import BENCH, PAYLOADS, SESSIONS_JSON
+from chipbench_helpers import ALL_CELLS, BENCH, DOC, PAYLOADS, ROOT, SESSIONS_JSON, found_cell
 from lib import compare
-from lib.traffic import Plan, seeded_bytes
-
-REFERENCE = BENCH / "configs" / "toolcalls-1chip.reference.py"
+from lib.traffic import Plan, evaluate, seeded_bytes
 
 
 # the yardstick's payloads, and the one of the tests' own sessions cell
@@ -22,6 +20,17 @@ def spec_of(name: str) -> dict:
     spec = json.loads((WHERE[name] / f"{name}.json").read_text())
     spec["name"], spec["text"] = name, (WHERE[name] / f"{name}.py").read_text()
     return spec
+
+
+def reference_of(name: str):
+    """The plain reference of the first cell whose traffic names the payload;
+    of the first configuration where no cell deals it yet."""
+    for found in filter(None, map(found_cell, ALL_CELLS)):
+        traffic = found["traffic"]
+        if name in traffic.get("mix", {}) or name == traffic.get("session", {}).get("payload"):
+            return found["reference"]
+    config = ROOT / DOC["configs"][0]["file"]
+    return config.with_name(config.stem + ".reference.py")
 
 
 def one_turn(spec: dict, params: dict, control: bool = False) -> dict:
@@ -37,7 +46,7 @@ def test_payload_test_block_under_stock_python(name, tmp_path):
     spec = spec_of(name)
     block = spec["test"]
     turn = one_turn(spec, block["params"])
-    got = compare.load_reference(REFERENCE).run(
+    got = compare.load_reference(reference_of(name)).run(
         [{"source": turn["reference_source"], "files": turn["inputs"]}], tmp_path)[0]
     assert got["exit_code"] == block["exit_code"], got["stderr_tail"]
     if "stdout" in block:
@@ -52,7 +61,7 @@ def test_the_control_of_an_array_payload_reads_over_its_limit(name, tmp_path):
     """Under stock numpy too, bfloat16 products and sums land far outside
     the payload's limit, and the sound source inside it against itself."""
     spec = spec_of(name)
-    run = compare.load_reference(REFERENCE).run
+    run = compare.load_reference(reference_of(name)).run
     # a chain of products shows the rounding only at some size: its own block
     params = spec["test"].get("control_params", spec["test"]["params"])
     sound, control = one_turn(spec, params), one_turn(spec, params, control=True)
@@ -65,9 +74,21 @@ def test_the_control_of_an_array_payload_reads_over_its_limit(name, tmp_path):
     assert compare.compare_text(want["stdout"], want["stdout"]) == (None, 0.0)
 
 
-def test_floors_are_expressions_of_the_parameters():
-    from lib.traffic import evaluate
+@pytest.mark.parametrize("name", [n for n in ALL_PAYLOADS if "floor" in spec_of(n)])
+def test_a_payload_that_states_a_floor_states_it_at_every_size_and_has_a_control(name):
+    """A floor is in bytes or flops or both, each an expression of the
+    parameters that is above 0 at the measured, the rehearsed and the test
+    block's sizes; and a payload that runs device programs has the control
+    that has to read not correct."""
+    spec = spec_of(name)
+    assert spec["floor"] and set(spec["floor"]) <= {"bytes", "flops"}
+    for sizes in (spec["params"], dict(spec["params"], **spec.get("rehearse", {})),
+                  dict(spec["params"], **spec["test"]["params"])):
+        assert all(evaluate(expr, sizes) > 0 for expr in spec["floor"].values())
+    assert spec["control"] and spec["rel_limit"] > 0
 
+
+def test_floors_are_expressions_of_the_parameters():
     spec = spec_of("sumsq")
     assert evaluate(spec["floor"]["bytes"], spec["params"]) == 9155 * 131072 * 4
     assert 0.25 * 2**34 < 9155 * 131072 * 4 < 0.5 * 2**34  # the array: 28 % of the chip's 16 GiB

@@ -1,19 +1,19 @@
 """The deck deals exact proportions for any seed; the seed changes order and
-drawn values only."""
+drawn values only. A case for each cell whose traffic file has the `deck`
+order, whatever the cell is called."""
 
 import collections
 
 import pytest
 
-from chipbench_helpers import CELLS, SESSIONS_CELL, SESSIONS_JSON
-from lib.manifest import Manifest
+from chipbench_helpers import DECK_CELLS, SESSIONS_CELL, manifest_of
 from lib.traffic import Plan, deal
 
 SEEDS = [0, 1, 7, 2**31 + 5, 2147500606]
 
 
 def plan_of(cell: str, seed: int, **kwargs) -> Plan:
-    manifest = Manifest(SESSIONS_JSON if cell == SESSIONS_CELL else None)
+    manifest = manifest_of(cell)
     traffic = manifest.cell(cell)["traffic"]
     return Plan(traffic, manifest.payloads_of(traffic), seed, rehearse=True, **kwargs)
 
@@ -26,7 +26,7 @@ def test_deal_is_exact_and_even():
 
 
 @pytest.mark.parametrize("seed", SEEDS)
-@pytest.mark.parametrize("cell", [c for c in CELLS if c.startswith("toolcalls")])
+@pytest.mark.parametrize("cell", DECK_CELLS)
 def test_any_seed_deals_the_mix_in_exact_proportion(cell, seed):
     plan = plan_of(cell, seed)
     mix = plan.traffic["mix"]
@@ -35,7 +35,7 @@ def test_any_seed_deals_the_mix_in_exact_proportion(cell, seed):
     assert {k: v * sum(mix.values()) for k, v in dealt.items()} == {k: w * 3 * size for k, w in mix.items()}
 
 
-@pytest.mark.parametrize("cell", [c for c in CELLS if c.startswith("toolcalls")])
+@pytest.mark.parametrize("cell", DECK_CELLS)
 def test_the_seed_changes_order_and_drawn_values_never_the_work(cell):
     a, b = plan_of(cell, 11), plan_of(cell, 12)
     assert a.deck == b.deck
@@ -65,8 +65,8 @@ def test_sessions_hold_each_variant_once_per_block(seed):
     assert turns[0]["inputs"] and not turns[1]["inputs"]  # only turn 1 uploads
 
 
-def test_control_changes_what_is_sent_not_what_the_reference_runs():
-    cell = next(c for c in CELLS if c.startswith("toolcalls"))
+@pytest.mark.parametrize("cell", DECK_CELLS)
+def test_control_changes_what_is_sent_not_what_the_reference_runs(cell):
     sound, control = plan_of(cell, 3), plan_of(cell, 3, control=True)
     for i in range(len(sound.deck)):
         s, c = sound.stateless(i), control.stateless(i)
@@ -75,13 +75,14 @@ def test_control_changes_what_is_sent_not_what_the_reference_runs():
         assert (c["source"] != s["source"]) is has_control
 
 
-def test_traced_runs_profile_only_payloads_that_state_a_floor():
-    cell = next(c for c in CELLS if c.startswith("toolcalls"))
+@pytest.mark.parametrize("cell", DECK_CELLS)
+def test_traced_runs_profile_only_payloads_that_state_a_floor(cell):
     plan = plan_of(cell, 3, trace=True)
-    turns = [plan.stateless(i) for i in range(4 * len(plan.deck))]
-    profiled = [t for t in turns if t["profile"]]
-    assert profiled and all("floor" in plan.payloads[t["payload"]] for t in profiled)
     every = plan.traffic["trace"]["profile_every"]
+    turns = [plan.stateless(i) for i in range(max(4, every) * len(plan.deck))]
+    profiled = [t for t in turns if t["profile"]]
+    # every cell drives the device path: some payload of its mix states a floor
+    assert profiled and all("floor" in plan.payloads[t["payload"]] for t in profiled)
     for name in {t["payload"] for t in profiled}:
         flags = [t["profile"] for t in turns if t["payload"] == name]
         assert flags == [i % every == 0 for i in range(len(flags))]
