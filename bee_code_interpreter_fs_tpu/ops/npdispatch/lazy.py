@@ -5,19 +5,40 @@ dispatch/round-trip cost and materializes its output in HBM. This module makes
 TpuArray operations build an expression DAG instead; when a value is actually
 needed (float(), print, np.asarray, control flow), the whole graph is compiled
 ONCE by jax.jit into a single fused XLA computation and executed. Graphs with
-identical structure (same ops, statics, and leaf shapes/dtypes) share one
-compiled executable via a structure-keyed cache, and jit executables persist
-across sandbox processes through the JAX compilation cache.
+identical structure (same ops, statics, leaf shapes/dtypes, outputs and
+donated leaves) share one compiled executable via a structure-keyed cache, and
+jit executables persist across sandbox processes through the JAX compilation
+cache.
 
 Effect: `a = np.random.rand(N); s = (a*a).sum(); float(s)` is one XLA
 execution instead of three, and re-running the same program shape skips
-tracing entirely.
+tracing entirely: the compiled runner by the graph's structure, and each
+node's shape and dtype by its op, its operands' shapes and its statics
+(`_aval_memo`), so that building the graph asks `jax.eval_shape` nothing.
+
+A time loop (`for t in range(T): a[1:-1] = f(b); b[1:-1] = f(a)`) grows one
+graph until it holds MAX_GRAPH_NODES nodes; the op that crosses the cap
+flushes: its lazy operands are computed by ONE program (counted in
+`counters.flushes`), every array the user still holds is written back, and the
+loop goes on from concrete leaves. A node that a program computed keeps its
+value and lets go of its operands, so nothing is computed twice and the
+history below it is freed at once. A concrete leaf whose only holder is the
+graph that overwrites it (`a[idx] = v`, `a += v`, `a = a + 1`) is DONATED to
+the program that consumes it: the output takes its buffer, and a loop over
+arrays that fill a quarter of the chip holds them once, not twice. What may be
+donated is read from what can be observed, reference counts and owners; an
+array the user holds under another name or through a lazy view is never
+donated, so numpy's call-time value semantics hold.
+
+`counters` counts what the shim did since it was last taken (the warm runner
+takes it at the end of each turn and stamps it into the reply).
 """
 
 from __future__ import annotations
 
 import logging
-from functools import partial
+import sys
+import time
 from typing import Any, Callable
 
 import jax
@@ -26,8 +47,13 @@ import numpy as real_np
 
 logger = logging.getLogger(__name__)
 
-# Cap on nodes in a single graph: beyond this, inputs are forced concrete so
-# unbounded program loops degrade to chunked fused executions, not OOM.
+# Cap on nodes in a single graph. The op that would cross it flushes: its lazy
+# operands are computed (one program, one `flushes` count) and it is built on
+# concrete leaves, so an unbounded program loop runs as a chain of fused
+# programs of at most this many nodes, never as one graph that grows until
+# tracing it is the bottleneck. A loop's flush falls at the same op in every
+# turn that runs the same source, so its programs are the same from turn to
+# turn and hit the runner cache.
 MAX_GRAPH_NODES = 200
 
 _REF_NODE = 0
@@ -35,11 +61,70 @@ _REF_LEAF = 1
 _REF_STATIC = 2
 
 
+class Counters:
+    """What the shim did since it was last taken. The warm runner takes it at
+    the end of a turn's user code and zeroes it on /reset; the control plane
+    stamps it into Result.phases as `shim_<name>` (`host_s` as `shim_host`).
+
+    programs           executions of a compiled runner
+    exec_cache_misses  runners built, that is traced (a clear-all of the runner
+                       cache at its limit shows here as the misses that follow)
+    nodes              nodes those programs executed
+    flushes            materializations forced by MAX_GRAPH_NODES
+    h2d_bytes          host arrays shipped to the device by `jnp.asarray` in
+                       `build_node` and `materialize`
+    donated_bytes      leaves donated to the program that consumed them
+    fallbacks          calls the shim routed to the device that ran under
+                       stock numpy after all (`np.fromfunction` of a function
+                       a TpuArray cannot serve, a jnp function that refused
+                       its arguments): correct, and seconds of host numpy
+    host_s             seconds inside `build_node` and `materialize`, the call
+                       of the compiled runner left out
+    """
+
+    FIELDS = ("programs", "exec_cache_misses", "nodes", "flushes", "h2d_bytes",
+              "donated_bytes", "fallbacks", "host_s")
+
+    def __init__(self) -> None:
+        self.reset()
+
+    def reset(self) -> None:
+        self.programs = self.exec_cache_misses = self.nodes = self.flushes = 0
+        self.h2d_bytes = self.donated_bytes = self.fallbacks = 0
+        self.host_s = 0.0
+        self._depth = 0  # build_node -> flush -> materialize nest: count once
+        self._entered = self._outside = 0.0
+
+    def take(self) -> dict:
+        taken = {name: getattr(self, name) for name in self.FIELDS}
+        taken["host_s"] = round(taken["host_s"], 6)
+        self.reset()
+        return taken
+
+    def leave_out(self, seconds: float) -> None:
+        """Time that passed inside the clock and is not the shim's own."""
+        self._outside += seconds
+
+    def __enter__(self) -> None:
+        self._depth += 1
+        if self._depth == 1:
+            self._entered = time.perf_counter()
+
+    def __exit__(self, *exc) -> None:
+        self._depth -= 1
+        if self._depth == 0:
+            self.host_s += time.perf_counter() - self._entered - self._outside
+            self._outside = 0.0
+
+
+counters = Counters()
+
+
 class Node:
     """One operation in the lazy DAG."""
 
     __slots__ = ("op_name", "fn", "arg_refs", "kwargs", "aval", "n_nodes",
-                 "owners")
+                 "owners", "value")
 
     def __init__(self, op_name, fn, arg_refs, kwargs, aval, n_nodes):
         self.op_name = op_name
@@ -55,6 +140,11 @@ class Node:
         # user-held arrays become concrete instead of being recomputed by the
         # next expression that uses them.
         self.owners: list = []
+        # What a program computed for this node, once one has: the node is a
+        # leaf from then on and holds no operand (`arg_refs` is emptied), so a
+        # lazy graph that still points at it neither runs its history again
+        # nor keeps that history's buffers alive.
+        self.value = None
 
     def live_owners(self):
         return [o for ref in self.owners if (o := ref()) is not None
@@ -91,12 +181,57 @@ def _static_key(value) -> str:
     return f"{type(value).__name__}:{value!r}"
 
 
+def _below(nodes, skip=()):
+    """Each node under `nodes` once, `skip` (by id) left out: shared
+    subexpressions (diamonds, x+x chains) count once, matching what actually
+    gets compiled. A node that holds its value has no operands left: the walk
+    ends at it. (A generator of its own, so that no local of `_build_node`
+    still points at a node when a flush asks who does.)"""
+    seen = set(skip)
+    stack = list(nodes)
+    while stack:
+        node = stack.pop()
+        if id(node) in seen:
+            continue
+        seen.add(id(node))
+        yield node
+        stack.extend(v for kind, v in node.arg_refs if kind == _REF_NODE)
+
+
+def _operands(arg_refs) -> list:
+    return [v for kind, v in arg_refs if kind == _REF_NODE]
+
+
+# What `jax.eval_shape` answered for an op, by the op, its operands' shapes and
+# dtypes and its statics: a turn that runs the same source builds the same few
+# hundred nodes as the turn before, and an abstract evaluation is most of the
+# host time of building one (0.7 ms; ten times that under the profiler's python
+# tracer). Only answers are kept, never a failure. Cleared whole at its limit,
+# like the runner cache.
+_aval_memo: dict[tuple, Any] = {}
+_AVAL_MEMO_LIMIT = 4096
+
+
+def _aval_key(fn, abstract_args, kwargs) -> tuple:
+    parts = tuple(
+        (tuple(a.shape), str(a.dtype), bool(getattr(a, "weak_type", False)))
+        if isinstance(a, jax.ShapeDtypeStruct) else _static_key(a)
+        for a in abstract_args
+    )
+    return (fn, parts, _static_key(sorted(kwargs.items())), bool(jax.config.jax_enable_x64))
+
+
 def build_node(op_name: str, fn: Callable, args, kwargs) -> Node | None:
     """Try to create a lazy node; None means 'do it eagerly instead'.
 
     `args` may contain TpuArray (lazy or concrete), jax/np arrays, and
     statics. kwargs must be static.
     """
+    with counters:
+        return _build_node(op_name, fn, args, kwargs)
+
+
+def _build_node(op_name: str, fn: Callable, args, kwargs) -> Node | None:
     from .shim import TpuArray
 
     for v in kwargs.values():
@@ -124,41 +259,48 @@ def build_node(op_name: str, fn: Callable, args, kwargs) -> Node | None:
         else:
             return None
 
-    # Unique-node count: shared subexpressions (diamonds, x+x chains) count
-    # once, matching what actually gets compiled — per-reference summing
-    # would inflate exponentially and force early materializations.
-    seen: set[int] = set()
-    stack = [v for kind, v in arg_refs if kind == _REF_NODE]
-    while stack:
-        nd = stack.pop()
-        if id(nd) in seen:
-            continue
-        seen.add(id(nd))
-        stack.extend(v for kind, v in nd.arg_refs if kind == _REF_NODE)
-    n_nodes = 1 + len(seen)
+    # Unique-node count: per-reference summing would inflate exponentially
+    # and force early materializations.
+    n_nodes = 1 + sum(1 for _ in _below(_operands(arg_refs)))
 
     if n_nodes > MAX_GRAPH_NODES:
-        # Force child graphs concrete; retry with flat leaves.
-        new_args = []
-        for a in args:
-            if isinstance(a, TpuArray) and a._node is not None:
-                a._force()
-            new_args.append(a)
-        return build_node(op_name, fn, new_args, kwargs)
-
-    def abstract_call(*arrays):
-        it = iter(arrays)
-        call_args = [
-            next(it) if kind != _REF_STATIC else value
-            for kind, value in arg_refs
+        # Flush, in ONE program (an operand forced alone would leave the
+        # others pointing at a history to run again): the arrays the user
+        # holds below the operands, so that an expression cut in two by the
+        # cap writes out no temporary; or, where that is not what made the
+        # graph big, the operands themselves. Every owner is written back,
+        # then the op is built again on what is concrete now.
+        counters.flushes += 1
+        operands = [
+            a._node for a in args
+            if isinstance(a, TpuArray) and a._node is not None
         ]
-        return fn(*call_args, **kwargs)
+        materialize_all(_held_below(operands) or operands)
+        return _build_node(op_name, fn, args, kwargs)
 
-    arrays_only = [a for a in abstract_args if isinstance(a, jax.ShapeDtypeStruct)]
+    memo_key = _aval_key(fn, abstract_args, kwargs)
     try:
-        aval = jax.eval_shape(abstract_call, *arrays_only)
-    except Exception:  # noqa: BLE001 — anything weird: run it eagerly
-        return None
+        aval = _aval_memo.get(memo_key)
+    except TypeError:  # a callable object that does not hash: asked every time
+        memo_key = aval = None
+    if aval is None:
+        def abstract_call(*arrays):
+            it = iter(arrays)
+            call_args = [
+                next(it) if kind != _REF_STATIC else value
+                for kind, value in arg_refs
+            ]
+            return fn(*call_args, **kwargs)
+
+        arrays_only = [a for a in abstract_args if isinstance(a, jax.ShapeDtypeStruct)]
+        try:
+            aval = jax.eval_shape(abstract_call, *arrays_only)
+        except Exception:  # noqa: BLE001 — anything weird: run it eagerly
+            return None
+        if memo_key is not None:
+            if len(_aval_memo) >= _AVAL_MEMO_LIMIT:
+                _aval_memo.clear()
+            _aval_memo[memo_key] = aval
     if not isinstance(aval, jax.ShapeDtypeStruct):
         return None  # multi-output ops stay eager
 
@@ -177,6 +319,7 @@ def build_node(op_name: str, fn: Callable, args, kwargs) -> Node | None:
                     snapshot = host_memo[id(value)] = jnp.asarray(value)
                 except (TypeError, ValueError):
                     return None  # e.g. object dtype: run eagerly instead
+                counters.h2d_bytes += value.nbytes
             arg_refs[i] = (_REF_LEAF, snapshot)
     return Node(op_name, fn, arg_refs, kwargs, aval, n_nodes)
 
@@ -203,60 +346,175 @@ _exec_cache: dict[tuple, Callable] = {}
 _CACHE_LIMIT = 512
 
 
-def _linearize(root: Node):
-    """Topo-order the DAG; returns (spec, leaves, nodes, key).
+def _held_below(operands: list[Node]) -> list[Node]:
+    """The nodes under `operands`, themselves left out, that a live TpuArray
+    points at: what the user holds of an expression's history."""
+    below = [v for node in operands for v in _operands(node.arg_refs)]
+    return [
+        node for node in _below(below, skip={id(node) for node in operands})
+        if node.value is None and node.live_owners()
+    ]
+
+
+class _Linear:
+    """A DAG in topological order (operands before the node that reads them).
 
     spec: per node, (fn, [(kind, index_or_static)], kwargs)
-    leaves: deduped concrete arrays in first-seen order
+    leaves: deduped concrete arrays in first-seen order; a node below the
+      roots that holds its value is the leaf it holds
     nodes: the Node object at each spec index
-    key: structural tuple — equal keys guarantee the same spec shape.
+    key: structural tuple — equal keys guarantee the same spec shape
+    and, for `_outputs` and `_donatable`, who points at what INSIDE this
+    graph: references to each node from the nodes above it, references to
+    each leaf from `arg_refs`, and the value-holding nodes with the
+    references to them.
     """
-    node_index: dict[int, int] = {}
-    leaf_index: dict[int, int] = {}
-    leaves: list[Any] = []
-    nodes: list[Node] = []
-    spec: list[tuple] = []
-    key_parts: list[tuple] = []
 
-    def visit(node: Node) -> int:
-        idx = node_index.get(id(node))
-        if idx is not None:
-            return idx
-        refs = []
-        ref_keys = []
-        for kind, value in node.arg_refs:
-            if kind == _REF_NODE:
-                child = visit(value)
-                refs.append((_REF_NODE, child))
-                ref_keys.append(("n", child))
-            elif kind == _REF_LEAF:
-                li = leaf_index.get(id(value))
-                if li is None:
-                    li = len(leaves)
-                    leaf_index[id(value)] = li
-                    leaves.append(value)
-                refs.append((_REF_LEAF, li))
-                ref_keys.append(
-                    ("l", li, tuple(value.shape), str(value.dtype))
+    __slots__ = ("spec", "leaves", "nodes", "key", "node_refs", "leaf_refs",
+                 "holders", "holder_leaf", "holder_refs")
+
+    def __init__(self, roots: list[Node]) -> None:
+        self.spec: list[tuple] = []
+        self.leaves: list[Any] = []
+        self.nodes: list[Node] = []
+        self.node_refs: list[int] = []
+        self.leaf_refs: list[int] = []
+        self.holders: dict[int, Node] = {}  # by id: the value-holding nodes,
+        self.holder_leaf: dict[int, int] = {}  # the leaf each holds,
+        self.holder_refs: dict[int, int] = {}  # and the references to it
+        node_index: dict[int, int] = {}
+        leaf_index: dict[int, int] = {}
+        key_parts: list[tuple] = []
+
+        def leaf_of(value) -> int:
+            li = leaf_index.get(id(value))
+            if li is None:
+                li = leaf_index[id(value)] = len(self.leaves)
+                self.leaves.append(value)
+                self.leaf_refs.append(0)
+            return li
+
+        # Post-order without recursion: a loop's graph is a chain as deep as
+        # the cap, and a closure that calls itself would be a reference cycle
+        # that keeps `leaves` alive until the cyclic collector runs.
+        stack = [(root, False) for root in reversed(roots)]
+        while stack:
+            node, expanded = stack.pop()
+            if id(node) in node_index:
+                continue
+            if not expanded:
+                stack.append((node, True))
+                stack.extend(
+                    (v, False) for kind, v in reversed(node.arg_refs)
+                    if kind == _REF_NODE and v.value is None and id(v) not in node_index
                 )
-            else:
-                refs.append((_REF_STATIC, value))
-                ref_keys.append(("s", _static_key(value)))
-        idx = len(spec)
-        node_index[id(node)] = idx
-        nodes.append(node)
-        spec.append((node.fn, refs, node.kwargs))
-        key_parts.append(
-            (node.op_name, tuple(ref_keys), _static_key(sorted(node.kwargs.items())))
-        )
-        return idx
+                continue
+            idx = len(self.spec)
+            refs, ref_keys = [], []
+            for kind, value in node.arg_refs:
+                if kind == _REF_NODE and value.value is None:
+                    child = node_index[id(value)]
+                    self.node_refs[child] += 1
+                    refs.append((_REF_NODE, child))
+                    ref_keys.append(("n", child))
+                elif kind == _REF_STATIC:
+                    refs.append((_REF_STATIC, value))
+                    ref_keys.append(("s", _static_key(value)))
+                else:
+                    if kind == _REF_NODE:  # it holds its value: a leaf
+                        holder, value = value, value.value
+                        li = leaf_of(value)
+                        self.holders[id(holder)] = holder
+                        self.holder_leaf[id(holder)] = li
+                        self.holder_refs[id(holder)] = self.holder_refs.get(id(holder), 0) + 1
+                    else:
+                        li = leaf_of(value)
+                        self.leaf_refs[li] += 1
+                    refs.append((_REF_LEAF, li))
+                    ref_keys.append(("l", li, tuple(value.shape), str(value.dtype)))
+            node_index[id(node)] = idx
+            self.nodes.append(node)
+            self.node_refs.append(0)
+            self.spec.append((node.fn, refs, node.kwargs))
+            key_parts.append(
+                (node.op_name, tuple(ref_keys), _static_key(sorted(node.kwargs.items())))
+            )
+        self.key = tuple(key_parts)
 
-    visit(root)
-    return spec, leaves, nodes, tuple(key_parts)
+
+def _refs_beyond(container, key) -> int:
+    """How many references `container[key]` has besides the container's own.
+    The one place that reads sys.getrefcount: the object is never bound to a
+    name here, so neither a caller's locals nor a tracer's frame can add to
+    the count, and what the call itself adds is measured at import, below,
+    not counted by hand."""
+    return sys.getrefcount(container[key]) - _REFS_OF_THE_CALL
+
+
+_REFS_OF_THE_CALL = 0
+_REFS_OF_THE_CALL = _refs_beyond([object()], 0)
+
+
+def _outputs(lin: _Linear, roots: list[Node]) -> list[int]:
+    """Spec indices of the nodes whose values the program returns: the roots;
+    any node some live TpuArray still points at, so that its owner gets the
+    computed value written back and user-held intermediates become concrete
+    instead of being recomputed by the next expression that uses them; and
+    any node that something outside this graph points at (`_refs_beyond`
+    against the references `_Linear` counted inside it), which is a lazy
+    expression still pending: it reads the value when its turn comes and
+    does not run this graph's history again. Every way into the graph from
+    outside then ends at a node that holds its value."""
+    root_ids = {id(root) for root in roots}
+    return [
+        i for i in range(len(lin.nodes))
+        if _refs_beyond(lin.nodes, i) > lin.node_refs[i]
+        or id(lin.nodes[i]) in root_ids or lin.nodes[i].live_owners()
+    ]
+
+
+def _donatable(lin: _Linear, out_indices: list[int]) -> list[int]:
+    """Indices of the leaves that may be donated to this graph's program:
+    device arrays that nothing outside the graph can reach, each paired with
+    an output of its shape and dtype (jax aliases no other, and warns).
+
+    Decided from what can be observed. A leaf is out of reach when every
+    reference to it is one this graph holds (`_refs_beyond` against the
+    count `_Linear` took): a TpuArray that shares it, a lazy view of it, a
+    `device_array` the user kept are each one reference more. The graph
+    itself never reads it again: `_outputs` leaves no way in from outside
+    but through a node that holds its value. A value-holding node below the
+    roots counts as one reference to the leaf it holds, if nothing outside
+    points at that node either. (One thread: a second one that holds an
+    array in a frame is one reference more, and the array is not donated.)
+    """
+    held = [0] * len(lin.leaves)  # holders of each leaf; -1: one of them is reachable
+    for key, li in lin.holder_leaf.items():
+        if _refs_beyond(lin.holders, key) > lin.holder_refs[key]:
+            held[li] = -1
+        elif held[li] >= 0:
+            held[li] += 1
+    free_outputs: dict[tuple, int] = {}
+    for i in out_indices:
+        aval = lin.nodes[i].aval
+        slot = (tuple(aval.shape), str(aval.dtype))
+        free_outputs[slot] = free_outputs.get(slot, 0) + 1
+    donated = []
+    for li in range(len(lin.leaves)):
+        if held[li] < 0 or _refs_beyond(lin.leaves, li) > lin.leaf_refs[li] + held[li]:
+            continue
+        leaf = lin.leaves[li]  # (bound only now: a name is a reference)
+        if not isinstance(leaf, jax.Array):
+            continue
+        slot = (tuple(leaf.shape), str(leaf.dtype))
+        if free_outputs.get(slot, 0) > 0:
+            free_outputs[slot] -= 1
+            donated.append(li)
+    return donated
 
 
 def _make_runner(spec, out_indices):
-    def run(leaves):
+    def run(*leaves):
         vals = []
         for fn, refs, kwargs in spec:
             args = [
@@ -272,39 +530,50 @@ def _make_runner(spec, out_indices):
 
 
 def materialize(root: Node) -> jax.Array:
-    spec, leaves, nodes, struct_key = _linearize(root)
-    root_idx = len(spec) - 1
-    # Besides the root, also emit any interior node some live TpuArray still
-    # points at: its owner gets the computed value written back, so user-held
-    # intermediates become concrete instead of being recomputed by the next
-    # expression that uses them. The writeback set shapes the compiled
-    # output tuple, so it is part of the cache key.
-    writebacks = []
-    for i, node in enumerate(nodes):
-        if i == root_idx:
-            continue
-        owners = node.live_owners()
-        if owners:
-            writebacks.append((i, owners))
-    out_indices = [root_idx] + [i for i, _ in writebacks]
-    key = (struct_key, tuple(out_indices))
+    return materialize_all([root])[0]
+
+
+def materialize_all(roots: list[Node]) -> list[jax.Array]:
+    """Compute `roots` in one program and return their values, in order."""
+    with counters:
+        if any(root.value is None for root in roots):
+            _run([root for root in roots if root.value is None])
+        return [root.value for root in roots]
+
+
+def _run(roots: list[Node]) -> None:
+    lin = _Linear(roots)
+    # Which values come back shapes the compiled output tuple, and which
+    # leaves are donated its aliases, so both are part of the cache key.
+    out_indices = _outputs(lin, roots)
+    donated = _donatable(lin, out_indices)
+    key = (lin.key, tuple(out_indices), tuple(donated))
     runner = _exec_cache.get(key)
     if runner is None:
         if len(_exec_cache) >= _CACHE_LIMIT:
             _exec_cache.clear()
-        runner = jax.jit(_make_runner(spec, out_indices))
+        counters.exec_cache_misses += 1
+        runner = jax.jit(_make_runner(lin.spec, out_indices), donate_argnums=tuple(donated))
         _exec_cache[key] = runner
-    device_leaves = [
-        leaf if isinstance(leaf, jax.Array) else jnp.asarray(leaf)
-        for leaf in leaves
-    ]
-    with precision_scope():
-        outs = runner(device_leaves)
-    for (_, owners), value in zip(writebacks, outs[1:]):
-        for owner in owners:
+    leaves = []
+    for leaf in lin.leaves:
+        if not isinstance(leaf, jax.Array):
+            counters.h2d_bytes += leaf.nbytes
+            leaf = jnp.asarray(leaf)
+        leaves.append(leaf)
+    counters.programs += 1
+    counters.nodes += len(lin.spec)
+    counters.donated_bytes += sum(leaves[li].nbytes for li in donated)
+    called = time.perf_counter()
+    with jax.profiler.TraceAnnotation("shim.materialize"), precision_scope():
+        outs = runner(*leaves)
+    counters.leave_out(time.perf_counter() - called)
+    for i, value in zip(out_indices, outs):
+        node = lin.nodes[i]
+        for owner in node.live_owners():
             owner._concrete = value
             owner._node = None
-    return outs[0]
+        node.value, node.arg_refs, node.n_nodes = value, [], 1
 
 
 # --------------------------------------------------------------------------
@@ -328,6 +597,14 @@ def astype_op(arr, dtype):
 
 def reshape_op(arr, shape):
     return jnp.reshape(arr, shape)
+
+
+def iota_op(shape, dtype, axis):
+    return jax.lax.broadcasted_iota(dtype, shape, axis)
+
+
+def indices_op(dimensions, dtype):
+    return jnp.indices(dimensions, dtype=dtype)
 
 
 def random_uniform_op(key, shape):

@@ -14,6 +14,7 @@ running anything.
 
 from __future__ import annotations
 
+import functools
 import types
 from typing import Any, Callable
 
@@ -284,6 +285,9 @@ class TpuArray:
 
     Holds either a concrete ``jax.Array`` or a lazy expression node; in-place
     mutation (``a[i] = v``, ``a += b``) rebinds to a functional update node.
+    A HOST ndarray updated in place with a TpuArray (``x += y @ A``, x a
+    vector under the dispatch threshold) stays the caller's array and takes
+    the result's values, as under stock numpy (``__array_ufunc__``).
 
     Known divergence from numpy: slicing returns a COPY, not a view. Writes
     through a slice (``b = a[:10]; b[0] = 5``) do not propagate to the parent
@@ -323,8 +327,8 @@ class TpuArray:
 
     def _force(self) -> jax.Array:
         if self._concrete is None:
-            self._concrete = lazy.materialize(self._node)
-            self._node = None
+            # (materialize writes every live owner back, this one among them)
+            self._concrete, self._node = lazy.materialize(self._node), None
         return self._concrete
 
     @property
@@ -350,6 +354,39 @@ class TpuArray:
 
     def __jax_array__(self):
         return self._arr
+
+    def __array_ufunc__(self, ufunc, method, *inputs, out=None, **kwargs):
+        """Where one of stock numpy's own ufuncs meets a TpuArray: from an
+        ndarray's operator (`host + t` is `np.add(host, t)`; numpy asks for
+        this hook before `__array_priority__`), from its IN-PLACE operator
+        (`host += t` is `np.add(host, t, out=(host,))`, which numpy never
+        defers), or called by a library that holds the real module.
+
+        An operator goes where the reflected operator goes (the device, or
+        the host for a 64-bit integer operand). With `out`, a host ndarray,
+        its result is then copied into that array under numpy's own casting
+        rule, so that a function that updates its argument in place
+        (`x += y @ A`) is seen by its caller. Anything else is computed by
+        numpy on host copies, as it was before this hook existed."""
+        if out is not None and _contains_tpu_array(out):
+            return NotImplemented  # numpy's TypeError: no ufunc writes into a TpuArray
+        result = NotImplemented
+        operators = _UFUNC_OPERATORS.get(ufunc)
+        if method == "__call__" and operators is not None and len(inputs) == 2 and not kwargs:
+            left, right = inputs
+            if isinstance(left, TpuArray):
+                result = getattr(left, operators[0])(right)
+            else:
+                result = getattr(right, operators[1])(left)
+        if result is NotImplemented:
+            if out is not None:
+                kwargs["out"] = out
+            return getattr(ufunc, method)(*_unwrap_np(list(inputs)), **kwargs)
+        if out is not None:
+            (target,) = out  # an operator has one result
+            real_np.copyto(target, real_np.asarray(result), casting="same_kind")
+            return target
+        return result
 
     def block_until_ready(self):
         self._force().block_until_ready()
@@ -401,6 +438,8 @@ class TpuArray:
 
     # -- indexing ------------------------------------------------------------
     def __getitem__(self, idx):
+        if isinstance(idx, list):
+            idx = (idx,)  # numpy: a list indexes the first axis; jax refuses a bare one
         # index as static argument when possible: keeps slicing lazy
         if lazy._static_ok(idx):
             node = lazy.build_node("getitem", lazy.getitem_op, (self, idx), {})
@@ -629,6 +668,15 @@ for _name, _fn in _BINOPS.items():
     if _name not in ("__lt__", "__le__", "__gt__", "__ge__", "__eq__", "__ne__"):
         setattr(TpuArray, reflected, _binop(reflected, _fn, swap=True))
 
+# numpy's ufunc -> (the TpuArray operator for `t <op> other`, the one for
+# `other <op> t`): where `TpuArray.__array_ufunc__` sends an ndarray's operator.
+_SWAPPED_COMPARISON = {"__lt__": "__gt__", "__le__": "__ge__", "__gt__": "__lt__",
+                       "__ge__": "__le__", "__eq__": "__eq__", "__ne__": "__ne__"}
+_UFUNC_OPERATORS = {
+    getattr(real_np, _fn.__name__): (_name, _SWAPPED_COMPARISON.get(_name, "__r" + _name[2:]))
+    for _name, _fn in _BINOPS.items()
+}
+
 for _name, _jnp_name in (
     ("__neg__", "negative"),
     ("__pos__", "positive"),
@@ -676,6 +724,8 @@ CREATION_FNS = (
     "zeros", "ones", "empty", "full", "arange", "linspace", "logspace",
     "eye", "identity",
 )
+# Creation from index grids, built on the device from iota (`_grid_overrides`).
+GRID_FNS = ("fromfunction", "indices")
 CONVERT_FNS = ("array", "asarray", "ascontiguousarray")
 LIKE_FNS = ("zeros_like", "ones_like", "empty_like", "full_like")
 COMPUTE_FNS = (
@@ -715,6 +765,14 @@ COMPUTE_FNS = (
 # (lazy would immediately force anyway, with extra tracing overhead).
 _EAGER_ONLY = {"allclose", "array_equal", "histogram", "meshgrid", "unique",
                "split", "array_split"}
+
+
+def _operand_size(value) -> int:
+    if isinstance(value, real_np.ndarray):
+        return int(value.size)
+    if isinstance(value, (tuple, list)):
+        return len(value)
+    return 1
 
 
 def _shape_size(shape) -> int:
@@ -790,6 +848,10 @@ class _Dispatcher:
             return False
         if _contains_tpu_array(values):
             return True
+        if self.name == "outer" and len(args) >= 2:
+            # small operands, big result: np.outer of two vectors under the
+            # threshold is a matrix over it, and belongs where it is used
+            return _operand_size(args[0]) * _operand_size(args[1]) >= self.threshold
         return _has_big_ndarray(values, self.threshold)
 
     def __call__(self, *args, **kwargs):
@@ -804,12 +866,76 @@ class _Dispatcher:
             if result is not NotImplemented:
                 return result
             # e.g. object dtype, unsupported kwarg — use host numpy
+            lazy.counters.fallbacks += 1
         return self.np_fn(
             *_unwrap_np(list(args)), **{k: _unwrap_np(v) for k, v in kwargs.items()}
         )
 
     def __repr__(self):
         return f"<tpu-dispatched numpy.{self.name}>"
+
+
+def _grid_dtype(dimensions, dtype, threshold: int):
+    """(dimensions as a tuple of ints, the dtype the index grids of
+    `np.indices` / `np.fromfunction` take on the device), or None where they
+    stay on the host: below the threshold, and where the device could not
+    hold numpy's values exactly. numpy's own default, `int`, is the platform
+    int64, which the integer policy above keeps on the host; a float dtype
+    gives float grids, as stock numpy's does, 64 bits computed in 32 under
+    the float policy, and exact while no index passes the mantissa."""
+    try:
+        dims = tuple(int(d) for d in dimensions)
+        wanted = real_np.dtype(dtype)
+    except TypeError:
+        return None
+    if not dims or min(dims) < 0 or _shape_size(dims) < threshold:
+        return None
+    if wanted.kind not in "iuf":
+        return None
+    if wanted.name in _WIDE_INT_NAMES and not _x64_enabled():
+        _announce_policy_once()
+        return None
+    on_device = real_np.dtype(canonical_dtype(wanted))
+    if on_device.kind == "f" and max(dims) > 2 ** (real_np.finfo(on_device).nmant + 1):
+        return None
+    return dims, on_device
+
+
+def _grid_overrides(threshold: int) -> dict[str, Callable]:
+    """`np.indices` and `np.fromfunction` above the dispatch threshold: the
+    grids are iota nodes of the lazy graph, so a closed-form initializer
+    (`np.fromfunction(lambda i, j: i * (j + 2) / N, (N, N))`) is part of the
+    program that first needs it and no grid crosses the host."""
+    @functools.wraps(real_np.indices)
+    def indices(dimensions, dtype=int, sparse=False):
+        on_device = None if sparse else _grid_dtype(dimensions, dtype, threshold)
+        if on_device is not None:
+            result = try_lazy("indices", lazy.indices_op, on_device, {})
+            if result is not None:
+                return result
+        return real_np.indices(dimensions, dtype=dtype, sparse=sparse)
+
+    @functools.wraps(real_np.fromfunction)
+    def fromfunction(function, shape, *, dtype=float, like=None, **kwargs):
+        on_device = _grid_dtype(shape, dtype, threshold) if like is None else None
+        if on_device is not None:
+            dims, grid_dtype = on_device
+            grids = [
+                try_lazy("indices.axis", lazy.iota_op, (dims, grid_dtype, axis), {})
+                for axis in range(len(dims))
+            ]
+            try:
+                return function(*grids, **kwargs)
+            except _FALLBACK_ERRORS:
+                # The function asked of a TpuArray what only an ndarray does
+                # (a buffer, an object dtype): stock numpy, and counted. An
+                # error of the function's own is the caller's to see, once.
+                lazy.counters.fallbacks += 1
+        if like is not None:
+            kwargs["like"] = like
+        return real_np.fromfunction(function, shape, dtype=dtype, **kwargs)
+
+    return {"indices": indices, "fromfunction": fromfunction}
 
 
 class _SubmoduleShim(types.ModuleType):
@@ -868,6 +994,7 @@ class _NumpyShim(types.ModuleType):
             self._overrides[name] = _Dispatcher(
                 name, np_fn, getattr(jnp, name, None), threshold, kind="compute"
             )
+        self._overrides.update(_grid_overrides(threshold))
         from .random import RandomShim
 
         self._overrides["random"] = RandomShim(threshold)

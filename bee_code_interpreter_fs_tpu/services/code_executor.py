@@ -135,6 +135,21 @@ STAGE_PHASES = (
     "runner_user_code",
     "runner_after_user",
 )
+# What the numpy shim counted during the turn's user code (npdispatch's
+# `lazy.Counters`, taken by the warm runner), stamped into Result.phases on
+# every served turn of a runner that has the shim installed, 0 where it did
+# nothing. Counts and bytes, and one duration (`shim_host`, seconds): none is
+# in LATENCY_PHASES, so the histogram sees none of them.
+SHIM_PHASES = {
+    "programs": "shim_programs",
+    "exec_cache_misses": "shim_exec_cache_misses",
+    "nodes": "shim_nodes",
+    "flushes": "shim_flushes",
+    "h2d_bytes": "shim_h2d_bytes",
+    "donated_bytes": "shim_donated_bytes",
+    "fallbacks": "shim_fallbacks",
+    "host_s": "shim_host",
+}
 _RUNNER_BEFORE_USER = ("runner.prepare", "runner.profile_start", "runner.limits_arm")
 _RUNNER_AFTER_USER = ("runner.limits_restore", "runner.profile_stop", "runner.finish")
 
@@ -3385,6 +3400,7 @@ class CodeExecutor:
         phases = {**timer.as_dict(), **stats.as_phases()}
         phases.update(self._stage_phases(primary, phases.get("exec", 0.0)))
         phases.update(self._compile_cache_phases(sandbox, bodies))
+        phases.update(self._shim_phases(primary))
         # Device-memory accounting: the hosts' wire blocks folded into
         # phases (peak_hbm_bytes / live_buffer_bytes_delta — non-latency
         # keys, excluded from the histogram by the allowlist) and, below,
@@ -3478,6 +3494,23 @@ class CodeExecutor:
         phases["runner_user_code"] = seconds("runner.user_code")
         phases["runner_after_user"] = seconds(*_RUNNER_AFTER_USER)
         return {key: round(value, 6) for key, value in phases.items()}
+
+    @staticmethod
+    def _shim_phases(body) -> dict[str, float]:
+        """SHIM_PHASES from host 0's `shim` block; nothing where the runner
+        sent none (no shim installed, a cold run, an older binary). Only the
+        known names, and only numbers: the block comes from the process that
+        ran the user's code."""
+        block = body.get("shim")
+        if not isinstance(block, dict):
+            return {}
+        phases = {}
+        for name, key in SHIM_PHASES.items():
+            value = block.get(name, 0)
+            if isinstance(value, bool) or not isinstance(value, (int, float)):
+                value = 0
+            phases[key] = round(max(0.0, float(value)), 6)
+        return phases
 
     @staticmethod
     def _reported_device_op(bodies: list, fallback: float = 0.0) -> float:

@@ -12,7 +12,8 @@ sandbox is still sitting in the warm pool — so by the time an Execute arrives,
 Protocol: newline-delimited JSON. fd 3 = requests in, fd 4 = responses out.
 Request:  {"source_path": ..., "stdout_path": ..., "stderr_path": ..., "env": {...},
            "sent_mono": <the server's CLOCK_MONOTONIC at the pipe write>}
-Response: {"exit_code": int, "stages": [[name, start_offset_s, duration_s], ...]}
+Response: {"exit_code": int, "stages": [[name, start_offset_s, duration_s], ...],
+           "shim": {<counter>: number, ...} where the numpy shim is installed}
 Ready line (sent once at boot):
   {"ready": true, "backend": ..., "device_count": n, "device_kind": ...}
 
@@ -170,6 +171,20 @@ def _take_stages(sent_mono) -> list | None:
     for (name, started), (_next, until) in zip(marks, marks[1:] + [("", ended)]):
         out.append([name, round(started - sent_mono, 6), round(max(0.0, until - started), 6)])
     return out
+
+
+def _take_shim() -> dict | None:
+    """The numpy shim's counters of the request in hand (programs run, runner
+    cache misses, nodes, flushes, bytes shipped and donated, host seconds
+    inside the shim), taken and zeroed the way `_take_stages` takes the stage
+    clocks; None in a runner whose interpreter started without the shim."""
+    shim = sys.modules.get("bee_code_interpreter_fs_tpu.ops.npdispatch")
+    if shim is None:
+        return None
+    try:
+        return shim.take_counters()
+    except Exception:  # noqa: BLE001 — a counter never fails a turn
+        return None
 
 
 def _send(obj: dict) -> None:
@@ -1353,6 +1368,7 @@ def main() -> None:
                 if req.get("op") == "reset":
                     _begin_stages("scrub", line_read)
                     ok = _reset(snapshot)
+                    _take_shim()  # the next tenant's counters start at zero
                     reply = {"ok": ok}
                     stages = _take_stages(req.get("sent_mono"))
                     if stages is not None:
@@ -1399,6 +1415,9 @@ def main() -> None:
                     )
                     exit_code, violation = _run_one(req)
                     reply = {"exit_code": exit_code}
+                    shim = _take_shim()
+                    if shim is not None:
+                        reply["shim"] = shim
                     if mem_probe is not None:
                         reply["device_memory"] = mem_probe.finish()
                     if violation:

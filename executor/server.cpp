@@ -1471,6 +1471,10 @@ struct RunOutcome {
   double replied_mono = 0;
   double guards_down_mono = 0;
   minijson::Value runner_stages;
+  // The numpy shim's counters of this run as the warm runner sent them
+  // (programs, cache misses, nodes, flushes, bytes shipped and donated, host
+  // seconds); absent from a runner without the shim and from a cold run.
+  minijson::Value shim;
 };
 
 // One entry of a `trace` block: a stage named `name`, nested in time (and,
@@ -1619,6 +1623,7 @@ RunOutcome run_user_code(const std::string& script_path,
                 static_cast<long long>(resp.get_number("cache_misses", -1));
             out.device_memory = resp.get("device_memory");
             out.runner_stages = resp.get("stages");
+            out.shim = resp.get("shim");
             break;
           case WarmRunner::ExecResult::kTimeout:
             out.timed_out = true;
@@ -2224,6 +2229,9 @@ void handle_execute_impl(const minihttp::Request& req, minihttp::Conn& conn,
   // run, plus the runner's RSS — the per-request HBM attribution feed.
   if (run.device_memory.is_object())
     resp["device_memory"] = run.device_memory;
+  // The numpy shim's counters, forwarded as sent: the control plane reads
+  // the names it knows, as numbers, and nothing else.
+  if (run.shim.is_object()) resp["shim"] = run.shim;
   resp["warm"] = minijson::Value(ran_warm);
   // True when the warm runner was killed (timeout) or died during this
   // request: its in-process state is gone and a rewarm is in flight. The

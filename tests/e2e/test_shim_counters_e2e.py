@@ -1,0 +1,95 @@
+"""The numpy shim's counters end to end (ISSUE 31): a `/v1/execute` of array
+code through the HTTP API, the real C++ executor and a warm runner that has
+the shim installed comes back with the eight `shim_*` keys in
+`Result.phases`; the next turn, on the sandbox that `/reset` put back, reads
+0 where the shim did nothing; and no histogram observes any of them. A
+sandbox without the shim (the no-JAX plumbing mode) stamps none. Nothing
+here times anything."""
+
+import pytest
+
+pytest.importorskip("httpx", reason="optional e2e dependency not installed")
+pytest.importorskip("aiohttp", reason="optional e2e dependency not installed")
+
+from aiohttp.test_utils import TestClient, TestServer
+
+from bee_code_interpreter_fs_tpu.config import Config
+from bee_code_interpreter_fs_tpu.services.backends.local import LocalSandboxBackend
+from bee_code_interpreter_fs_tpu.services.code_executor import (
+    LATENCY_PHASES,
+    SHIM_PHASES,
+    CodeExecutor,
+)
+from bee_code_interpreter_fs_tpu.services.custom_tool_executor import CustomToolExecutor
+from bee_code_interpreter_fs_tpu.services.http_server import create_http_app
+from bee_code_interpreter_fs_tpu.services.storage import Storage
+
+from test_stage_spans_e2e import turnover_traces
+
+# over the shim's shipped dispatch threshold (2**17 elements)
+ARRAY_TURN = (
+    "import numpy as np\n"
+    "a = np.fromfunction(lambda i, j: i * (j + 2) / 512, (512, 512), dtype=np.float32)\n"
+    "a[1:-1, 1:-1] = 0.5 * (a[:-2, 1:-1] + a[2:, 1:-1])\n"
+    "print(type(a).__name__, float(a.sum(axis=1).sum()))\n"
+)
+
+
+async def make_client(tmp_path, warm_import_jax: bool):
+    config = Config(
+        file_storage_path=str(tmp_path / "storage"),
+        local_sandbox_root=str(tmp_path / "sandboxes"),
+        executor_pod_queue_target_length=1,
+        jax_compilation_cache_dir="",
+        default_execution_timeout=60.0,
+    )
+    backend = LocalSandboxBackend(config, warm_import_jax=warm_import_jax)
+    storage = Storage(config.file_storage_path)
+    executor = CodeExecutor(backend, storage, config)
+    app = create_http_app(executor, CustomToolExecutor(executor), storage)
+    client = TestClient(TestServer(app))
+    await client.start_server()
+    return client, executor
+
+
+async def test_shim_counters_of_a_served_array_turn_and_of_the_next(tmp_path):
+    client, executor = await make_client(tmp_path, warm_import_jax=True)
+    try:
+        bodies = []
+        for source in (ARRAY_TURN, "print(6 * 7)"):
+            resp = await client.post("/v1/execute", json={"source_code": source})
+            assert resp.status == 200
+            bodies.append(await resp.json())
+            await turnover_traces(executor, len(bodies))
+        array, plain = bodies
+        assert array["exit_code"] == 0 and array["stdout"].startswith("TpuArray "), array["stderr"]
+        assert array["warm"] and plain["warm"]
+        for body in bodies:
+            for key in SHIM_PHASES.values():
+                assert isinstance(body["phases"][key], float) and body["phases"][key] >= 0.0, key
+        phases = array["phases"]
+        # creation, the update and the sum: one program, nothing from the host
+        assert phases["shim_programs"] == 1.0 and phases["shim_flushes"] == 0.0
+        assert phases["shim_nodes"] >= 8 and phases["shim_h2d_bytes"] == 0.0
+        assert phases["shim_exec_cache_misses"] == 1.0 and phases["shim_host"] > 0.0
+        # the next turn ran on the sandbox /reset put back, in the same warm runner
+        assert plain["phases"]["turnover_before"] > 0.0
+        assert all(plain["phases"][key] == 0.0 for key in SHIM_PHASES.values())
+        # no histogram saw a count, a byte or the shim's seconds
+        observed = {labels["phase"] for labels, *_ in executor.metrics.phase_seconds.samples()}
+        assert observed <= set(LATENCY_PHASES) and not observed & set(SHIM_PHASES.values())
+    finally:
+        await client.close()
+        await executor.close()
+
+
+async def test_a_sandbox_without_the_shim_stamps_no_shim_phase(tmp_path):
+    client, executor = await make_client(tmp_path, warm_import_jax=False)
+    try:
+        resp = await client.post("/v1/execute", json={"source_code": "print(6 * 7)"})
+        body = await resp.json()
+        assert body["stdout"] == "42\n"
+        assert not set(SHIM_PHASES.values()) & set(body["phases"])
+    finally:
+        await client.close()
+        await executor.close()

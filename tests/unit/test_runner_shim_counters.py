@@ -1,0 +1,133 @@
+"""The warm runner takes the numpy shim's counters at the end of a turn, the
+way it takes its stage clocks, and `/reset` zeroes them (ISSUE 31); the
+control plane stamps them into `Result.phases` under fixed names, as numbers,
+and none of them is a latency. On the modules themselves: no server, no
+timing."""
+
+import importlib.util
+from pathlib import Path
+
+import pytest
+
+from bee_code_interpreter_fs_tpu.ops import npdispatch
+from bee_code_interpreter_fs_tpu.services.code_executor import (
+    LATENCY_PHASES,
+    SHIM_PHASES,
+    STAGE_PHASES,
+    CodeExecutor,
+)
+from bee_code_interpreter_fs_tpu.services.perf_observer import OBSERVED_PHASES
+
+RUNNER_PY = Path(__file__).resolve().parents[2] / "executor" / "runner.py"
+COUNTERS = ("programs", "exec_cache_misses", "nodes", "flushes", "h2d_bytes", "donated_bytes", "fallbacks",
+            "host_s")
+
+
+@pytest.fixture()
+def runner():
+    spec = importlib.util.spec_from_file_location("runner_under_test_shim", RUNNER_PY)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def run_script(runner, tmp_path, text: str) -> None:
+    script = tmp_path / "script.py"
+    script.write_text(text)
+    runner._begin_stages("prepare", runner.time.monotonic())
+    code, violation = runner._run_one({
+        "source_path": str(script), "stdout_path": str(tmp_path / "out"),
+        "stderr_path": str(tmp_path / "err"), "env": {},
+    })
+    assert (code, violation) == (0, None), (tmp_path / "err").read_text()
+
+
+ARRAY_TURN = "import numpy as np\na = np.ones(5000, dtype='float32')\na += 1.0\nprint(float(a.sum()))\n"
+
+
+def test_a_runner_without_the_shim_takes_nothing(runner, tmp_path):
+    run_script(runner, tmp_path, ARRAY_TURN)
+    assert runner._take_shim() is None
+
+
+def test_a_turns_counters_are_taken_once_and_a_turn_without_arrays_reads_zero(runner, tmp_path):
+    npdispatch.install(threshold=1000)
+    try:
+        runner._take_shim()
+        run_script(runner, tmp_path, ARRAY_TURN)
+        taken = runner._take_shim()
+        assert tuple(taken) == COUNTERS
+        assert taken["programs"] == 1 and taken["nodes"] == 3 and taken["flushes"] == 0
+        assert taken["h2d_bytes"] == 0 and taken["host_s"] > 0
+        assert runner._take_shim() == dict.fromkeys(COUNTERS, 0), "taken is zeroed"
+        run_script(runner, tmp_path, "print(6 * 7)\n")
+        assert runner._take_shim() == dict.fromkeys(COUNTERS, 0), "present, and 0 where the shim did nothing"
+    finally:
+        npdispatch.uninstall()
+
+
+def test_reset_zeroes_what_a_turn_left_untaken(runner, tmp_path):
+    """A turn that died before its reply (a timeout kill is a respawn, but a
+    batch or a snapshot op in between is not) leaves counts behind: the next
+    tenant's first turn must not be stamped with them."""
+    from bee_code_interpreter_fs_tpu.ops.npdispatch import lazy
+
+    npdispatch.install(threshold=1000)
+    try:
+        lazy.counters.reset()
+        run_script(runner, tmp_path, ARRAY_TURN)
+        assert lazy.counters.programs == 1
+        # the main loop's reset branch, as far as the counters go
+        source = RUNNER_PY.read_text()
+        branch = source[source.index('if req.get("op") == "reset":'):source.index('elif req.get("op") == "snapshot":')]
+        assert "_take_shim()" in branch
+        runner._take_shim()
+        assert lazy.counters.programs == 0
+    finally:
+        npdispatch.uninstall()
+
+
+def test_a_counter_that_fails_never_fails_the_turn(runner, monkeypatch):
+    class Broken:
+        @staticmethod
+        def take_counters():
+            raise RuntimeError("boom")
+
+    monkeypatch.setitem(runner.sys.modules, "bee_code_interpreter_fs_tpu.ops.npdispatch", Broken)
+    assert runner._take_shim() is None
+
+
+# -- the control plane's side
+
+
+def test_shim_phases_are_stamped_under_fixed_names_as_numbers():
+    body = {"shim": {"programs": 5, "exec_cache_misses": 0, "nodes": 306, "flushes": 1, "h2d_bytes": 1114112,
+                     "donated_bytes": 4831838208, "fallbacks": 2, "host_s": 0.123456789, "minted_by_user_code": 7}}
+    phases = CodeExecutor._shim_phases(body)
+    assert phases == {
+        "shim_programs": 5.0, "shim_exec_cache_misses": 0.0, "shim_nodes": 306.0, "shim_flushes": 1.0,
+        "shim_h2d_bytes": 1114112.0, "shim_donated_bytes": 4831838208.0, "shim_fallbacks": 2.0, "shim_host": 0.123457,
+    }
+    assert all(isinstance(v, float) for v in phases.values())
+
+
+@pytest.mark.parametrize("body", [{}, {"shim": None}, {"shim": "5"}, {"shim": [1, 2]}])
+def test_no_shim_block_no_shim_phase(body):
+    """No shim installed, a cold run, an older binary: the keys are absent,
+    and a per-turn metric that reads them finds nothing to read."""
+    assert CodeExecutor._shim_phases(body) == {}
+
+
+def test_a_block_from_the_users_process_is_read_as_numbers_only():
+    phases = CodeExecutor._shim_phases({"shim": {"programs": "many", "nodes": True, "flushes": -3, "host_s": None}})
+    assert phases == dict.fromkeys(SHIM_PHASES.values(), 0.0)
+
+
+def test_no_shim_phase_is_a_latency_phase():
+    """The histogram's allowlist and the perf observer's baselines see none
+    of the new keys (the PR 6 / PR 7 discipline)."""
+    keys = set(SHIM_PHASES.values())
+    assert len(keys) == 8 and tuple(SHIM_PHASES) == COUNTERS
+    assert not keys & LATENCY_PHASES
+    assert not keys & set(OBSERVED_PHASES)
+    assert not keys & set(STAGE_PHASES)
