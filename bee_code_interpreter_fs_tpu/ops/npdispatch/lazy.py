@@ -30,6 +30,24 @@ donated is read from what can be observed, reference counts and owners; an
 array the user holds under another name or through a lazy view is never
 donated, so numpy's call-time value semantics hold.
 
+A WINDOW store in such a loop (`b[1:-1, 1:-1] = 0.2 * (a[1:-1, 1:-1] + a[1:-1,
+:-2] + ...)`: a full-rank tuple of step-1 slices on both sides) is traced as
+ONE pass over the array's full, aligned shape: each window read is its array
+shifted by the read's origin less the store's (one `lax.pad` with negative
+edges, which XLA keeps inside the fusion that reads it), the operators run at
+the full shape on the same operands in the same order, and the store is a
+select against the window's mask, so the program's output aliases the donated
+target. XLA's own program for the slices and the scatter is two passes that
+the tiling does not align: the stencil into a window-shaped temporary, then a
+`dynamic-update-slice` of it one element in. `_full_shape_plan` decides, once
+per runner and from the index and the shapes alone; a store whose value is a
+scalar or broadcasts, whose index is strided, fancy or of reduced rank, whose
+window is under half the array, whose expression holds anything but windows of
+arrays of the target's shape, element-wise operators, scalars and 0-d arrays,
+or any node of which something else reads (a view the user holds) keeps
+`setitem_op` / `getitem_op` as they are. `counters.aligned_stores` counts the
+stores that ran in the full-shape form.
+
 `counters` counts what the shim did since it was last taken (the warm runner
 takes it at the end of each turn and stamps it into the reply).
 """
@@ -37,6 +55,7 @@ takes it at the end of each turn and stamps it into the reply).
 from __future__ import annotations
 
 import logging
+import math
 import sys
 import time
 from typing import Any, Callable
@@ -74,6 +93,9 @@ class Counters:
     h2d_bytes          host arrays shipped to the device by `jnp.asarray` in
                        `build_node` and `materialize`
     donated_bytes      leaves donated to the program that consumed them
+    aligned_stores     window stores (`a[1:-1, 1:-1] = f(b[...])`) that a
+                       program executed as one select over the array's full
+                       shape (`_full_shape_plan`), counted per execution
     fallbacks          calls the shim routed to the device that ran under
                        stock numpy after all (`np.fromfunction` of a function
                        a TpuArray cannot serve, a jnp function that refused
@@ -83,14 +105,14 @@ class Counters:
     """
 
     FIELDS = ("programs", "exec_cache_misses", "nodes", "flushes", "h2d_bytes",
-              "donated_bytes", "fallbacks", "host_s")
+              "donated_bytes", "aligned_stores", "fallbacks", "host_s")
 
     def __init__(self) -> None:
         self.reset()
 
     def reset(self) -> None:
         self.programs = self.exec_cache_misses = self.nodes = self.flushes = 0
-        self.h2d_bytes = self.donated_bytes = self.fallbacks = 0
+        self.h2d_bytes = self.donated_bytes = self.aligned_stores = self.fallbacks = 0
         self.host_s = 0.0
         self._depth = 0  # build_node -> flush -> materialize nest: count once
         self._entered = self._outside = 0.0
@@ -342,7 +364,8 @@ def precision_scope():
 
 # Materialization: linearize DAG -> structure key -> cached jitted runner.
 
-_exec_cache: dict[tuple, Callable] = {}
+# structure key -> (the jitted runner, the window stores it runs at full shape)
+_exec_cache: dict[tuple, tuple[Callable, int]] = {}
 _CACHE_LIMIT = 512
 
 
@@ -513,17 +536,162 @@ def _donatable(lin: _Linear, out_indices: list[int]) -> list[int]:
     return donated
 
 
-def _make_runner(spec, out_indices):
+# The element-wise operator functions: what `TpuArray`'s binary and unary
+# operators call. Filled by shim.py where its operator tables are defined.
+ELEMENTWISE_OPS: list = []
+
+_SCALARS = (int, float, bool, complex, real_np.generic)
+
+
+def _window(idx, shape):
+    """(starts, sizes) where `idx` takes a window of an array of `shape`: one
+    step-1 slice for every axis, none of them empty. None for anything else."""
+    if isinstance(idx, slice) and len(shape) == 1:
+        idx = (idx,)
+    if not isinstance(idx, tuple) or len(idx) != len(shape) or not shape:
+        return None
+    starts, sizes = [], []
+    for part, n in zip(idx, shape):
+        if not isinstance(part, slice):
+            return None
+        try:
+            start, stop, step = part.indices(n)
+        except TypeError:  # a bound that is no index
+            return None
+        if step != 1 or stop <= start:
+            return None
+        starts.append(start)
+        sizes.append(stop - start)
+    return tuple(starts), tuple(sizes)
+
+
+def _full_shape_plan(lin: _Linear, out_indices: list[int]):
+    """Which window stores of `lin` are computed over their array's full,
+    aligned shape, and which window reads are shifted for them:
+    ({index of a getitem: its origin less the store's}, {index of a setitem:
+    (the window's starts, its sizes)}).
+
+    XLA writes `a[1:-1, 1:-1] = f(b[...])` as a stencil into a window-shaped
+    temporary and a `dynamic-update-slice` of it at an offset the tiling does
+    not share: two misaligned passes. Over the full shape, with each read as
+    its array shifted and the store as a select against the window's mask, it
+    is ONE fusion whose output aliases the target (`_shifted`, `_select_window`).
+
+    A store qualifies by what can be read from its index and the shapes: a
+    window of at least half of its array, whose value has the target's dtype
+    and is built only from equal-shaped windows of arrays of the target's full
+    shape, the element-wise operators (ELEMENTWISE_OPS) over them, python
+    scalars and 0-d arrays; and nothing but the store reads any node of that
+    expression (a view the user holds, or another consumer, needs the window
+    itself). Every other store and read keeps `setitem_op` / `getitem_op`."""
+    spec = lin.spec
+    avals = [node.aval for node in lin.nodes]
+
+    def shape_of(ref):
+        kind, v = ref
+        if kind == _REF_STATIC:
+            return None
+        return tuple(avals[v].shape if kind == _REF_NODE else lin.leaves[v].shape)
+
+    def expression(root, full, origin, sizes):
+        """The nodes of a store's value, each with its shift (a read) or None
+        (an operator), and how often the expression reads each; None where
+        something in it does not qualify."""
+        tree: dict[int, tuple | None] = {}
+        read_here = {root: 1}
+        stack = [root]
+        while stack:
+            i = stack.pop()
+            if i in tree:
+                continue
+            fn, refs, _ = spec[i]
+            if fn is getitem_op:
+                read = _window(refs[1][1], full) if shape_of(refs[0]) == full else None
+                if read is None or read[1] != sizes:
+                    return None
+                tree[i] = tuple(r - o for r, o in zip(read[0], origin))
+            elif any(fn is op for op in ELEMENTWISE_OPS):
+                tree[i] = None
+                for kind, v in refs:
+                    if kind == _REF_STATIC:
+                        if not isinstance(v, _SCALARS):
+                            return None
+                    elif shape_of((kind, v)) != ():  # (a 0-d array is computed as ever)
+                        if kind != _REF_NODE:
+                            return None
+                        read_here[v] = read_here.get(v, 0) + 1
+                        stack.append(v)
+            else:
+                return None
+        return tree, read_here
+
+    readers = list(lin.node_refs)
+    for i in out_indices:
+        readers[i] += 1  # whoever takes the output reads the window itself
+
+    shifts: dict[int, tuple] = {}
+    stores: dict[int, tuple] = {}
+    for s, (fn, refs, _) in enumerate(spec):
+        if fn is not setitem_op or refs[1][0] != _REF_NODE:
+            continue
+        full, root = tuple(avals[s].shape), refs[1][1]
+        window = _window(refs[2][1], full)
+        if window is None:
+            continue
+        origin, sizes = window
+        if (2 * math.prod(sizes) < math.prod(full) or tuple(avals[root].shape) != sizes
+                or avals[root].dtype != avals[s].dtype):
+            continue
+        found = expression(root, full, origin, sizes)
+        if found is None:
+            continue
+        tree, read_here = found
+        if all(readers[i] == read_here[i] for i in tree):
+            shifts.update((i, shift) for i, shift in tree.items() if shift is not None)
+            stores[s] = window
+    return shifts, stores
+
+
+def _shifted(arr, shift):
+    """`out[i] = arr[i + shift]` at arr's own shape, zero where that leaves
+    it: one `lax.pad` with negative edges, which XLA keeps inside the fusion
+    that reads it (a slice and a pad, or a roll, it materializes first)."""
+    if not any(shift):
+        return arr
+    return jax.lax.pad(arr, jnp.zeros((), arr.dtype), [(-d, d, 0) for d in shift])
+
+
+def _select_window(arr, value, starts, sizes):
+    """`arr` with `value` inside the window, both at arr's shape: a select
+    against the window's mask, so that the output can alias `arr`."""
+    mask = None
+    for axis, (n, start, size) in enumerate(zip(arr.shape, starts, sizes)):
+        if size == n:
+            continue
+        at = jax.lax.iota(jnp.int32, n)
+        inside = ((at >= start) & (at < start + size)).reshape(
+            [n if k == axis else 1 for k in range(arr.ndim)])
+        mask = inside if mask is None else mask & inside
+    return value if mask is None else jnp.where(mask, value, arr)
+
+
+def _make_runner(spec, out_indices, shifts, stores):
     def run(*leaves):
         vals = []
-        for fn, refs, kwargs in spec:
+        for i, (fn, refs, kwargs) in enumerate(spec):
             args = [
                 vals[v] if kind == _REF_NODE
                 else leaves[v] if kind == _REF_LEAF
                 else v
                 for kind, v in refs
             ]
-            vals.append(fn(*args, **kwargs))
+            if i in shifts:
+                vals.append(_shifted(args[0], shifts[i]))
+            elif i in stores:
+                vals.append(_select_window(args[0], args[1], *stores[i]))
+            else:
+                # (an operator between shifted reads runs at the full shape)
+                vals.append(fn(*args, **kwargs))
         return tuple(vals[i] for i in out_indices)
 
     return run
@@ -548,13 +716,16 @@ def _run(roots: list[Node]) -> None:
     out_indices = _outputs(lin, roots)
     donated = _donatable(lin, out_indices)
     key = (lin.key, tuple(out_indices), tuple(donated))
-    runner = _exec_cache.get(key)
-    if runner is None:
+    cached = _exec_cache.get(key)
+    if cached is None:
         if len(_exec_cache) >= _CACHE_LIMIT:
             _exec_cache.clear()
         counters.exec_cache_misses += 1
-        runner = jax.jit(_make_runner(lin.spec, out_indices), donate_argnums=tuple(donated))
-        _exec_cache[key] = runner
+        shifts, stores = _full_shape_plan(lin, out_indices)
+        runner = jax.jit(_make_runner(lin.spec, out_indices, shifts, stores),
+                         donate_argnums=tuple(donated))
+        cached = _exec_cache[key] = (runner, len(stores))
+    runner, aligned_stores = cached
     leaves = []
     for leaf in lin.leaves:
         if not isinstance(leaf, jax.Array):
@@ -564,6 +735,7 @@ def _run(roots: list[Node]) -> None:
     counters.programs += 1
     counters.nodes += len(lin.spec)
     counters.donated_bytes += sum(leaves[li].nbytes for li in donated)
+    counters.aligned_stores += aligned_stores
     called = time.perf_counter()
     with jax.profiler.TraceAnnotation("shim.materialize"), precision_scope():
         outs = runner(*leaves)

@@ -677,14 +677,13 @@ _UFUNC_OPERATORS = {
     for _name, _fn in _BINOPS.items()
 }
 
-for _name, _jnp_name in (
-    ("__neg__", "negative"),
-    ("__pos__", "positive"),
-    ("__abs__", "abs"),
-    ("__invert__", "invert"),
-):
-    def _unop(jnp_name):
-        fn = getattr(jnp, jnp_name)
+_UNOPS = {
+    "__neg__": jnp.negative, "__pos__": jnp.positive, "__abs__": jnp.abs,
+    "__invert__": jnp.invert,
+}
+for _name, _fn in _UNOPS.items():
+    def _unop(fn):
+        jnp_name = fn.__name__
 
         def op(self):
             result = self._lazy_or_eager(jnp_name, fn, (self,), {})
@@ -692,7 +691,14 @@ for _name, _jnp_name in (
                 raise TypeError(f"{jnp_name} failed on TpuArray")
             return result
         return op
-    setattr(TpuArray, _name, _unop(_jnp_name))
+    setattr(TpuArray, _name, _unop(_fn))
+
+# What the operators call, but the one contraction: element for element, so the
+# lazy engine may run them at another shape than the window they were asked at
+# (`lazy._full_shape_plan`).
+lazy.ELEMENTWISE_OPS.extend(
+    fn for fn in (*_BINOPS.values(), *_UNOPS.values()) if fn is not jnp.matmul
+)
 
 for _name in (
     "__iadd__", "__isub__", "__imul__", "__itruediv__", "__ifloordiv__",

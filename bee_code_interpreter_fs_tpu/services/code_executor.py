@@ -147,6 +147,7 @@ SHIM_PHASES = {
     "flushes": "shim_flushes",
     "h2d_bytes": "shim_h2d_bytes",
     "donated_bytes": "shim_donated_bytes",
+    "aligned_stores": "shim_aligned_stores",
     "fallbacks": "shim_fallbacks",
     "host_s": "shim_host",
 }

@@ -175,6 +175,215 @@ def test_a_time_loop_is_flushed_once_and_runs_nothing_twice(name, np_shim):
     assert first["donated_bytes"] >= len(outputs) * 4 * 200 * 200
 
 
+# -- a window store over the array's full shape -----------------------------------
+
+SIDE = 384  # the benchmark's `rehearse` grid: over the shim's own threshold too
+
+
+def ints(np, shape, k=3, dtype="float32"):
+    """Small whole numbers, so that every case below is exact in its dtype
+    and equality with stock numpy is equality of bits."""
+    return np.fromfunction(lambda *at: sum((k + d) * i for d, i in enumerate(at)) % 7, shape, dtype=dtype)
+
+
+def _jacobi(np, shim):
+    A, B = ints(np, (SIDE, SIDE)), ints(np, (SIDE, SIDE), 4)
+    for _ in range(2):
+        B[1:-1, 1:-1] = 0.25 * (A[1:-1, 1:-1] + A[1:-1, :-2] + A[1:-1, 2:] + A[2:, 1:-1] + A[:-2, 1:-1])
+        A[1:-1, 1:-1] = 0.25 * (B[1:-1, 1:-1] + B[1:-1, :-2] + B[1:-1, 2:] + B[2:, 1:-1] + B[:-2, 1:-1])
+    return A, B
+
+
+def _fdtd(np, shim):
+    ex, ey, hz = (ints(np, (340, 442), k) for k in (3, 4, 5))
+    fict = np.fromfunction(lambda i: i, (2,), dtype="float32")
+    for t in range(2):
+        ey[0, :] = fict[t]
+        ey[1:, :] -= 0.5 * (hz[1:, :] - hz[:-1, :])
+        ex[:, 1:] -= 0.5 * (hz[:, 1:] - hz[:, :-1])
+        hz[:-1, :-1] -= 0.75 * (ex[:-1, 1:] - ex[:-1, :-1] + ey[1:, :-1] - ey[:-1, :-1])
+    return ex, ey, hz
+
+
+def _two_arrays(np, shim):
+    a, b, c = (ints(np, (SIDE, SIDE), k) for k in (3, 4, 5))
+    c[1:-1, 1:-1] = a[1:-1, 1:-1] * 0.5 + b[2:, :-2]
+    return (c,)
+
+
+def _rank1(np, shim):
+    a = ints(np, (SIDE * SIDE,))
+    a[1:-1] = 0.5 * (a[:-2] + a[2:])  # reads the values it overwrites: the old ones
+    return (a,)
+
+
+def _rank3(np, shim):
+    a, b = ints(np, (40, 48, 80)), ints(np, (40, 48, 80), 4)
+    b[1:-1, 1:-1, 1:-1] = 0.25 * (a[2:, 1:-1, 1:-1] + a[:-2, 1:-1, 1:-1] + a[1:-1, 2:, 1:-1] + a[1:-1, 1:-1, :-2])
+    return (b,)
+
+
+def _bfloat16(np, shim):
+    import ml_dtypes
+
+    A, B = (ints(np, (SIDE, SIDE), k).astype(ml_dtypes.bfloat16) for k in (3, 4))
+    B[1:-1, 1:-1] = 0.25 * (A[1:-1, 1:-1] + A[1:-1, :-2] + A[1:-1, 2:] + A[2:, 1:-1] + A[:-2, 1:-1])
+    return (B,)
+
+
+def _every_kind_of_operand(np, shim):
+    """Unary and comparison operators, a power, a numpy scalar, a 0-d array
+    (computed as ever: `a[2, 3]` is no window), a store into an integer grid,
+    and a window that is the whole array."""
+    a, b = ints(np, (SIDE, SIDE)), ints(np, (SIDE, SIDE), 4)
+    b[:-2, 2:] = -abs(a[1:-1, 1:-1] - 3.0) ** 2 * np.float32(0.5) + a[2, 3] * a[2:, :-2]
+    k, m = ints(np, (SIDE, SIDE), 5, "int32"), ints(np, (SIDE, SIDE), 6, "int32")
+    m[1:, 1:] = (k[1:, 1:] << 2) % 5 + ~k[:-1, :-1] // 3
+    m[:, :] = m[:, :] - k[:, :]
+    return b, m
+
+
+def _scalar(np, shim):
+    a = ints(np, (SIDE, SIDE))
+    a[1:-1, 1:-1] = 3.0
+    return (a,)
+
+
+def _row_broadcast(np, shim):
+    a, row = ints(np, (SIDE, SIDE)), ints(np, (SIDE * SIDE,), 4)
+    a[1:-1, 1:-1] = row[1:SIDE - 1]
+    a[0, :] = row[5]
+    return (a,)
+
+
+def _strided(np, shim):
+    a, b = ints(np, (SIDE, SIDE)), ints(np, (SIDE, SIDE), 4)
+    a[1:-1:2, 1:-1] = 0.5 * b[1:-1:2, 1:-1]
+    a[::-1, :] = 0.5 * b[::-1, :]
+    return (a,)
+
+
+def _reduced_rank(np, shim):
+    a, b = ints(np, (SIDE, SIDE)), ints(np, (SIDE, SIDE), 4)
+    a[1:-1] = 0.5 * b[1:-1]
+    a[3, 1:] = b[4, 1:] + b[5, :-1]
+    a[..., 1:] = 0.5 * b[..., 1:]
+    return (a,)
+
+
+def _under_half(np, shim):
+    a, b = ints(np, (SIDE, SIDE)), ints(np, (SIDE, SIDE), 4)
+    a[:SIDE // 2, :-1] = 0.5 * (b[:SIDE // 2, :-1] + b[:SIDE // 2, 1:])
+    return (a,)
+
+
+def _another_shape(np, shim):
+    a, b = ints(np, (SIDE, SIDE)), ints(np, (SIDE, SIDE), 4)
+    wider, patch = ints(np, (SIDE, SIDE + 2), 5), ints(np, (SIDE - 2, SIDE - 2), 6)
+    a[1:-1, 1:-1] = b[1:-1, 1:-1] + wider[1:-1, 1:SIDE - 1]  # a window of an array of another shape
+    b[1:-1, 1:-1] = b[1:-1, 1:-1] + patch  # a whole array of the window's shape
+    b[1:-1, 1:-1] = b[1:-1, 1:-1] * a[0, 1:-1]  # a row, which broadcasts
+    return a, b
+
+
+def _no_operator(np, shim):
+    a, b = ints(np, (SIDE, SIDE)), ints(np, (SIDE, SIDE), 4)
+    a[1:-1, 1:-1] = np.where(b[1:-1, 1:-1] > 2, b[2:, 1:-1], 0.0)
+    b[1:-1, 1:-1] = a[1:-1, 1:-1] - np.sum(a[1:-1, 1:-1], axis=0)
+    b[1:-1, 1:-1] = np.cumsum(b[1:-1, 1:-1], axis=1) % 7 + a[1:-1, 1:-1]
+    a[:, :] = (a[:, :] % 3) @ (b[:, :] % 3)  # an operator, and no element-wise one
+    return a, b
+
+
+def _a_view_still_held(np, shim):
+    a, b = ints(np, (SIDE, SIDE)), ints(np, (SIDE, SIDE), 4)
+    view = b[1:-1, 1:-1] + b[2:, 2:]
+    a[1:-1, 1:-1] = 0.5 * view
+    b[1:-1, 1:-1] = 0.5 * (a[1:-1, 1:-1] + a[2:, 2:])  # nobody holds these windows
+    return a, view * 1.0, b
+
+
+def _a_second_name(np, shim):
+    a, b = ints(np, (SIDE, SIDE)), ints(np, (SIDE, SIDE), 4)
+    assert float(a[0, 1]) == 4.0  # a is computed, and a buffer of its own
+    # What a second wrapper of the same buffer is to the shim (np.asarray of a
+    # TpuArray: numpy itself would hand back the one array; the shim's contract
+    # is a copy), a copy is to numpy.
+    other = np.asarray(a) if shim else a.copy()
+    a[1:-1, 1:-1] = 0.5 * (b[1:-1, 1:-1] + a[2:, 1:-1])
+    return a, other
+
+
+# name: (the statements, the window stores a program runs at the full shape)
+WINDOW_STORES = {
+    "jacobi": (_jacobi, 4), "fdtd": (_fdtd, 6), "two arrays": (_two_arrays, 1), "rank 1": (_rank1, 1),
+    "rank 3": (_rank3, 1), "bfloat16": (_bfloat16, 1), "every kind of operand": (_every_kind_of_operand, 3),
+    "a scalar": (_scalar, 0), "a row broadcast": (_row_broadcast, 0), "a strided window": (_strided, 0),
+    "a reduced-rank index": (_reduced_rank, 0), "under half the array": (_under_half, 0),
+    "an operand of another shape": (_another_shape, 0), "no element-wise operator": (_no_operator, 0),
+    "a view still held": (_a_view_still_held, 1), "a second name": (_a_second_name, 1),
+}
+
+
+@pytest.mark.parametrize("name", sorted(WINDOW_STORES))
+def test_a_window_store_equals_stock_numpys(name, np_shim):
+    """`a[1:-1, 1:-1] = f(b[...])` over the shapes `lazy._full_shape_plan`
+    tells apart: to the bit what stock numpy gives, whether it runs as one
+    select over the array's full shape or as the slices and the scatter it was
+    before; the counter says which."""
+    statements, aligned = WINDOW_STORES[name]
+    want = [real_np.asarray(x) for x in statements(real_np, False)]
+    lazy._exec_cache.clear()
+    lazy.counters.reset()
+    got = statements(np_shim, True)
+    assert all(isinstance(x, TpuArray) for x in got)
+    for g, w in zip(got, want, strict=True):
+        g = real_np.asarray(g)
+        assert g.dtype == w.dtype and real_np.array_equal(g, w)
+    taken = lazy.counters.take()
+    assert taken["aligned_stores"] == aligned and taken["fallbacks"] == 0
+    if name == "a second name":
+        assert taken["donated_bytes"] == 4 * SIDE * SIDE, "b's buffer, dead after the store; never a's"
+    # the same statements again: the same programs, and counted per execution
+    for g in statements(np_shim, True):
+        real_np.asarray(g)
+    again = lazy.counters.take()
+    assert again["aligned_stores"] == aligned and again["exec_cache_misses"] == 0
+
+
+def test_the_elementwise_operators_are_the_operator_tables_less_the_contraction():
+    """Collected where `TpuArray`'s operators are defined, so that a new
+    operator is one; `isinstance(fn, jnp.ufunc)` would miss three of them."""
+    import jax.numpy as jnp
+
+    from bee_code_interpreter_fs_tpu.ops.npdispatch.shim import _BINOPS, _UNOPS
+
+    ops = lazy.ELEMENTWISE_OPS
+    assert {id(fn) for fn in ops} == {id(fn) for fn in (*_BINOPS.values(), *_UNOPS.values())} - {id(jnp.matmul)}
+    assert len(_BINOPS) == 19 and len(_UNOPS) == 4 and len({id(fn) for fn in ops}) == 22
+    for fn in (jnp.true_divide, jnp.power, jnp.abs, jnp.subtract, jnp.invert):
+        assert any(fn is op for op in ops)
+
+
+def test_a_full_shape_store_leaves_no_scatter_and_no_window_in_the_program(np_shim):
+    """What the runner is traced to, as jax sees it: pads and a select, where
+    today's lowering has five slices and a scatter."""
+    from bee_code_interpreter_fs_tpu.ops.npdispatch.lazy import _full_shape_plan, _make_runner
+
+    A, B = ints(np_shim, (SIDE, SIDE)), ints(np_shim, (SIDE, SIDE), 4)
+    B[1:-1, 1:-1] = 0.25 * (A[1:-1, 1:-1] + A[1:-1, :-2] + A[1:-1, 2:] + A[2:, 1:-1] + A[:-2, 1:-1])
+    lin = lazy._Linear([B._node])
+    out = [len(lin.spec) - 1]
+    shifts, stores = _full_shape_plan(lin, out)
+    assert sorted(shifts.values()) == [(-1, 0), (0, -1), (0, 0), (0, 1), (1, 0)]
+    assert list(stores.values()) == [((1, 1), (SIDE - 2, SIDE - 2))]
+    new = str(jax.make_jaxpr(_make_runner(lin.spec, out, shifts, stores))(*lin.leaves))
+    old = str(jax.make_jaxpr(_make_runner(lin.spec, out, {}, {}))(*lin.leaves))
+    assert "scatter" in old and "slice" in old and f"{SIDE - 2},{SIDE - 2}" in old
+    assert "scatter" not in new and "slice" not in new and f"{SIDE - 2},{SIDE - 2}" not in new
+    assert new.count(" pad[") == 4 and "select_n" in new
+
+
 # -- creation from index grids -------------------------------------------------
 
 
@@ -555,12 +764,19 @@ def test_counters_of_a_hand_made_graph(np_shim):
     taken = lazy.counters.take()
     # (the shipped copy of `host` is dead after the add, and c has its shape)
     assert taken == {"programs": 1, "exec_cache_misses": 1, "nodes": 4, "flushes": 0,
-                     "h2d_bytes": host.nbytes, "donated_bytes": host.nbytes, "fallbacks": 0,
-                     "host_s": taken["host_s"]}
+                     "h2d_bytes": host.nbytes, "donated_bytes": host.nbytes, "aligned_stores": 0,
+                     "fallbacks": 0, "host_s": taken["host_s"]}
     assert 0.0 < taken["host_s"] < 60.0
     # a, b and c came back as outputs: each now reads in a program of one node
     assert float(b[1]) == 2.0 and float(c[2]) == 4.0
     assert lazy.counters.take()["nodes"] == 2
+    # a window store from two shifted windows: two reads, the sum, the product,
+    # the store, and the pick that forces them; once per execution, traced or not
+    for misses in (1, 0):
+        a[1:-1] = 0.5 * (c[:-2] + c[2:])
+        assert float(a[1]) == 3.0
+        taken = lazy.counters.take()
+        assert (taken["aligned_stores"], taken["nodes"], taken["exec_cache_misses"]) == (1, 6, misses)
     assert lazy.counters.take() == dict.fromkeys(lazy.Counters.FIELDS, 0), "taken is zeroed"
 
 

@@ -14,11 +14,14 @@ these tests in this one file (a second file may go to another xdist worker,
 where the fixture would skip in silence).
 """
 
+import json
+import re
 import runpy
 from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -80,9 +83,10 @@ class _AotJit:
     for the described chip at the arguments' shapes, keeps the executable,
     and returns zeros of the output shape — nothing executes anywhere."""
 
-    def __init__(self, sharding):
+    def __init__(self, sharding, zeros=jnp.zeros):
         self.real_jit = jax.jit
         self.sharding = sharding  # None: the program's own mesh names devices
+        self.zeros = zeros
         self.compiled = []
 
     def __call__(self, fn, **jit_kwargs):
@@ -90,14 +94,35 @@ class _AotJit:
             specs = jax.tree.map(
                 lambda a: _spec(a.shape, a.dtype, self.sharding), args
             )
+            placed = dict(jit_kwargs)
+            if not args and self.sharding is not None:
+                # no argument names the chip (a program that creates its
+                # arrays): its outputs do, or it compiles for the CPU
+                placed["out_shardings"] = self.sharding
             self.compiled.append(
-                self.real_jit(fn, **jit_kwargs).lower(*specs).compile()
+                self.real_jit(fn, **placed).lower(*specs).compile()
             )
             return jax.tree.map(
-                lambda o: jnp.zeros(o.shape, o.dtype), jax.eval_shape(fn, *args)
+                lambda o: self.zeros(o.shape, o.dtype), jax.eval_shape(fn, *args)
             )
 
         return call
+
+
+def _shim_jits_ahead_of_time(monkeypatch, aot: _AotJit) -> _AotJit:
+    """The shim's lazy engine with its `jax.jit` swapped for `aot`, and an
+    empty runner cache, until the test ends."""
+    from bee_code_interpreter_fs_tpu.ops.npdispatch import lazy
+
+    class JaxWithAotJit:
+        jit = staticmethod(aot)
+
+        def __getattr__(self, name):
+            return getattr(jax, name)
+
+    monkeypatch.setattr(lazy, "jax", JaxWithAotJit())
+    monkeypatch.setattr(lazy, "_exec_cache", {})
+    return aot
 
 
 @pytest.mark.parametrize(
@@ -174,18 +199,8 @@ def test_headline_payload_programs_fit_one_chip(one_chip, monkeypatch):
     draw) compiles for one v5e chip and fits its 16 GB beside the live
     arrays the payload holds (a, b and a temporary: 400 MB each in f32)."""
     from bee_code_interpreter_fs_tpu.ops import npdispatch
-    from bee_code_interpreter_fs_tpu.ops.npdispatch import lazy
 
-    aot = _AotJit(one_chip)
-
-    class JaxWithAotJit:
-        jit = staticmethod(aot)
-
-        def __getattr__(self, name):
-            return getattr(jax, name)
-
-    monkeypatch.setattr(lazy, "jax", JaxWithAotJit())
-    monkeypatch.setattr(lazy, "_exec_cache", {})
+    aot = _shim_jits_ahead_of_time(monkeypatch, _AotJit(one_chip))
     npdispatch.install()
     try:
         runpy.run_path(
@@ -197,6 +212,85 @@ def test_headline_payload_programs_fit_one_chip(one_chip, monkeypatch):
     live_arrays = 4 * 100_000_000 * 4
     for compiled in aot.compiled:
         assert _device_bytes(compiled) + live_arrays < V5E_HBM_BYTES
+
+
+def _untouched_zeros(shape, dtype):
+    """Zeros that cost no memory until they are read, which nothing here
+    does: numpy's untouched pages, handed to jax as they are. (2.4 GB a grid
+    otherwise, on a machine this sandbox shares.)"""
+    return jax.dlpack.from_dlpack(np.zeros(shape, dtype))
+
+
+def _entry_ops(compiled, min_elements: int) -> list[tuple[str, set[str], list[str]]]:
+    """(opcode, the arrays of at least `min_elements` in the result's type,
+    the operands' types) of every instruction of the entry computation whose
+    result holds such an array."""
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY "):]
+    instruction = r"^\s*(?:ROOT )?(%[\w.-]+) = (\(?[a-z0-9]+\[[^=]*?) ([a-z-]+)\(([^)]*)\)"
+    types = {name: result for name, result, _, _ in re.findall(instruction, entry, re.M)}
+    found = []
+    for _, result, opcode, operands in re.findall(instruction, entry, re.M):
+        big = {
+            f"{dtype}[{dims}]" for dtype, dims in re.findall(r"([a-z0-9]+)\[([0-9,]+)\]", result)
+            if np.prod([int(d) for d in dims.split(",")]) >= min_elements
+        }
+        if big:
+            found.append((opcode, big, [types.get(o.strip(), "") for o in operands.split(",")]))
+    return found
+
+
+@pytest.mark.parametrize("payload, steps, grids, stores_a_step", [
+    ("jacobi_2d", {"TSTEPS": 3}, 2, 2),
+    ("fdtd_2d", {"TMAX": 2}, 3, 3),
+])
+def test_npbench_stencil_programs_are_one_aligned_pass_per_window_store(
+    one_chip, monkeypatch, capsys, payload, steps, grids, stores_a_step
+):
+    """`benchmarks/chip/payloads/jacobi_2d.py` and `fdtd_2d.py` at the run's
+    grid sizes, two time steps, through the shim with its jit swapped for the
+    AOT one. A window store (`B[1:-1, 1:-1] = 0.2 * (A[...] + ...)`) is ONE
+    fusion over the full, aligned grid whose only big operands are grids:
+    no window-shaped temporary, no `dynamic-update-slice` of one, no slice of
+    a grid materialized first (`lazy._full_shape_plan`). Structure only."""
+    from bee_code_interpreter_fs_tpu.ops import npdispatch
+    from bee_code_interpreter_fs_tpu.ops.npdispatch import lazy
+
+    payloads = REPO_ROOT / "benchmarks" / "chip" / "payloads"
+    params = {**json.loads((payloads / f"{payload}.json").read_text())["params"], **steps}
+    aot = _shim_jits_ahead_of_time(monkeypatch, _AotJit(one_chip, zeros=_untouched_zeros))
+    npdispatch.install()
+    try:
+        lazy.counters.reset()
+        runpy.run_path(str(payloads / f"{payload}.py"), init_globals={"P": params}, run_name="__main__")
+        taken = lazy.counters.take()
+    finally:
+        npdispatch.uninstall()
+    assert capsys.readouterr().out.startswith(f"{payload} ")
+    n_steps = 2
+    assert taken["aligned_stores"] == stores_a_step * n_steps and taken["fallbacks"] == 0
+
+    side = [v for k, v in params.items() if k in ("N", "NX", "NY")]
+    grid_shape = f"f32[{side[0]},{side[-1]}]"
+    grid_bytes = 4 * side[0] * side[-1]
+    kernel = aot.compiled[0]  # creation and the time loop: the first value asked for needs them all
+    big = _entry_ops(kernel, side[0] * side[-1] // 2)
+    for opcode, arrays, operands in big:
+        # every big value is a whole grid, never a window of one, made by a fusion
+        assert arrays == {grid_shape}, (opcode, arrays)
+        assert opcode in ("fusion", "get-tuple-element", "tuple", "dynamic-update-slice"), (opcode, arrays)
+        if opcode == "dynamic-update-slice":  # fdtd's `ey[0, :] = _fict_[t]`, a row in place: today's lowering
+            assert grid_shape in operands[0] and grid_shape not in operands[1], operands
+    fusions = [arrays for opcode, arrays, _ in big if opcode == "fusion"]
+    # jacobi: one fusion a half-step; fdtd: one for ey and ex, one for hz; and
+    # at most one for each grid made that no store took into its own
+    passes = 2 * n_steps
+    assert passes <= len(fusions) <= passes + grids, fusions
+    # No window-shaped temporary: what is left is at most a buffer for each
+    # grid, which this program makes itself and so cannot take over in place.
+    assert kernel.memory_analysis().temp_size_in_bytes < (grids + 0.01) * grid_bytes
+    for compiled in aot.compiled:
+        assert _device_bytes(compiled) + grids * grid_bytes < V5E_HBM_BYTES
 
 
 def test_prewarm_kernel_set_compiles_for_v5e(topo, one_chip, monkeypatch):
