@@ -23,7 +23,7 @@ def job_sharding(mesh: Mesh, axis: str = "jobs") -> NamedSharding:
     job's block per device. This is the fused-dispatch half of the batched
     execution lanes — ``shard_map`` over a 1-axis job mesh runs every
     job's block on its own chip in ONE XLA program (see the
-    ``batched_dispatch`` pre-warm kernel and ``scripts/bench_batch.py``).
+    ``batched_dispatch`` pre-warm kernel in ``services/compile_cache.py``).
     """
     return NamedSharding(mesh, P(axis))
 

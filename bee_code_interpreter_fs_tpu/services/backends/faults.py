@@ -31,9 +31,9 @@ on in any deployment (``APP_EXECUTOR_FAULT_SPEC=spawn_fail:0.3,seed:7``):
                          /device-stats): from then on its stats report an
                          attach pending whose age grows in real time and a
                          stale runner heartbeat — a HANG, not an error,
-                         which is the real wedge semantics (BENCH_r03-r05:
-                         attaches block for tens of minutes; they do not
-                         fail). Drives the probe daemon's
+                         which is the real wedge semantics (rounds 3 to
+                         5 on the TPU rig: attaches block for tens of
+                         minutes; they do not fail). Drives the probe daemon's
                          healthy→suspect→wedged escalation deterministically.
     attach_hang_lane:<n> restrict attach_hang to hosts of ONE chip-count
                          lane (-1 = any lane, the default) — the chaos e2e
@@ -252,8 +252,10 @@ class AttachHangTransport(httpx.AsyncBaseTransport):
     ``attach_pending_s`` grows in REAL time from the moment the hang
     started, with a matching stale runner heartbeat. A hang, not an error —
     the executor's HTTP plane stays perfectly responsive while the device
-    plane silently stops, which is exactly the BENCH_r03-r05 wedge the
-    probe daemon must distinguish from ordinary busy/attaching states.
+    plane silently stops, which is exactly the wedge of rounds 3 to 5 (a
+    device op that never completes, 50-76 minutes of manual recovery by
+    host reboot) that the probe daemon must distinguish from ordinary
+    busy/attaching states.
     Everything except /device-stats passes through untouched (detection is
     this PR's scope; the data plane keeps serving)."""
 

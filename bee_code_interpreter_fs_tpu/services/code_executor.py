@@ -303,7 +303,7 @@ class CodeExecutor:
             resolve_replica_id(self.config) or self.config.replica_self or ""
         )
         if self._store_shared and not self.replica_id:
-            # A shared store handed in directly (tests, the bench) still
+            # A shared store handed in directly (the tests do) still
             # needs a distinct identity per executor instance.
             self.replica_id = f"replica-{id(self) & 0xFFFF:04x}"
         # Session→replica affinity router (services/replicas.py), attached

@@ -1,8 +1,8 @@
 """Fleet-wide persistent XLA compilation cache: the control plane's store
 and the seed/harvest protocol against sandbox executors.
 
-The bench trajectory (BENCH_r02-r05) shows the dominant cost of real array
-workloads is JAX/XLA first-compile and accelerator page-in, not execution.
+On the TPU rig of rounds 2 to 5 the dominant cost of real array workloads
+was JAX/XLA first-compile and accelerator page-in, not execution.
 Per-sandbox ``JAX_COMPILATION_CACHE_DIR`` plumbing has existed since the
 seed, but it was host-local at best and pod-local-and-dying on Kubernetes:
 a million users running the same N popular kernels recompiled them once per
@@ -775,9 +775,9 @@ print("prewarm batched_dispatch ok", n)
 """,
     ),
     (
-        # The batch bench's hot small-array shape (scripts/bench_batch.py:
-        # a chained 64x64 matmul — the coalesced small-job workload the
-        # batching lanes exist for), jitted so the whole chain compiles to
+        # A hot small-array shape: a chained 64x64 matmul (the coalesced
+        # small-job workload the batching lanes exist for; drawn from a CPU
+        # harness's traffic, not from a cell), jitted so the whole chain compiles to
         # ONE cached executable. Fleet coverage scales only with this set
         # (pre-warm is the store's sole admission source), and a cold
         # lane's first burst of small jobs is exactly when an XLA compile

@@ -1,7 +1,8 @@
 """Device-health probe daemon: the detection half of wedge recovery.
 
-The repo's own bench history (BENCH_r03–r05) records the production failure
-mode this module exists for: a TPU attach that never completes, with
+Rounds 3 to 5 on the TPU rig are the production failure mode this module
+exists for (50-76 minutes of manual recovery by host reboot each time): a
+TPU attach that never completes, with
 ``/healthz`` answering "ok" the whole time — nothing distinguished *busy*
 from *wedged*, and the recovery story was an operator in a shell. The
 ROADMAP's fencing item needs observation before it can get actuation; this
@@ -389,7 +390,8 @@ class DeviceHealthProbe:
 
         # Attach (warm-up: jax import + libtpu init + device enumeration)
         # in flight: legitimate for minutes, wedged when it outlives the
-        # budget — THE historical failure signature (BENCH_r03-r05).
+        # budget — THE historical failure signature (rounds 3 to 5: a
+        # device op that never completes).
         # warm_state "pending" alone counts too: an attach observed at age
         # zero is still an attach.
         attach_pending = age("attach_pending_s")

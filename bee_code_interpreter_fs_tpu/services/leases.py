@@ -2,8 +2,9 @@
 
 The device-health probe (PR 8) can SAY a host is wedged; nothing could
 safely ACT on that verdict, because disposal alone does not protect the
-replacement — the repo's own outage history (BENCH_r03-r05) is precisely a
-stale claim wedging a chip for the next holder: a zombie runner still
+replacement — the repo's own outage history (rounds 3 to 5: a device op
+that never completes, 50-76 minutes of manual recovery by host reboot) is
+precisely a stale claim wedging a chip for the next holder: a zombie runner still
 holding libtpu, a late-arriving dispatch, a retry racing a dispose. This
 module is the fencing primitive that makes dispose-and-replace safe:
 

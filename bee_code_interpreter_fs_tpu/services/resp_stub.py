@@ -9,10 +9,9 @@ Expiry is lazy (checked at read/lock time), which is exactly the part of
 ``SET NX PX`` the client's advisory locks rely on. NOT a Redis: no
 persistence, no replication, no pipelining guarantees beyond
 one-request-one-reply per connection — a protocol-faithful crash dummy
-the bench can SIGKILL and restart to stage a store outage.
+that can be SIGKILLed and restarted to stage a store outage.
 
-Run standalone (the bench spawns this as a subprocess and waits for the
-READY line):
+Run standalone (it prints a READY line once it listens):
 
     python -m bee_code_interpreter_fs_tpu.services.resp_stub --port 7379
 

@@ -14,7 +14,7 @@ implementations:
   path, so a single replica with ``APP_STATE_STORE`` unset runs today's
   behavior byte-for-byte. A single instance can also be handed to several
   in-process control planes (``shared=True``) — the deterministic harness
-  the replica e2e tests and the bench run on.
+  the replica e2e tests run on.
 - ``SQLiteStateStore`` — a file-backed store (stdlib ``sqlite3``, WAL mode)
   whose writes ride ``BEGIN IMMEDIATE`` transactions: advisory locking and
   compare-and-swap across PROCESSES with zero external service
@@ -265,7 +265,7 @@ class SQLiteStateStore(StateStore):
     ``BEGIN IMMEDIATE`` gives ``incr``/``mutate`` cross-process atomicity
     (SQLite's own file locking is the advisory lock — no lockfile
     protocol to get wrong). Connections are per-thread (sqlite3 objects
-    are not thread-safe; the bench drives replicas from worker threads).
+    are not thread-safe, and replicas may be driven from worker threads).
 
     Busy handling: a writer that finds the database locked retries inside
     sqlite's busy timeout (5s) — under control-plane write rates (tag
@@ -402,7 +402,7 @@ class RespStateStore(StateStore):
     critical section (a handful of single-RTT commands), so lapses are a
     pathology bound, not a working path.
 
-    Connections are per-thread (the bench drives replicas from worker
+    Connections are per-thread (replicas may be driven from worker
     threads); every transport failure closes the connection and raises
     ``StateStoreUnavailableError`` — the resilience wrapper's cue."""
 

@@ -23,8 +23,7 @@ Design:
   starts: an incoming ``traceparent`` is respected (flag 01 records, 00
   propagates ids but records nothing), otherwise ``sample_ratio`` decides.
   Unsampled and disabled paths go through no-op spans whose methods do no
-  allocation or locking — the 0%-sampling overhead gate in
-  ``scripts/bench_transfer.py`` holds the tracer to that.
+  allocation or locking.
 - **Pluggable exporters** — a bounded in-memory ring (the ``GET /traces``
   debug surface) and an append-only JSONL file. Every finished span also
   lands in the module-level :data:`GLOBAL_RING` flight recorder (bounded),

@@ -67,7 +67,7 @@ t_int8 = timed_best(
 )
 
 # bf16/int8 results go out BEFORE the int4 leg starts: a partial run
-# (int4 OOM / timeout under bench.py's deadline) must still carry the
+# (int4 OOM / timeout under the caller's deadline) must still carry the
 # measurements already made.
 bf16_bytes = quantized_nbytes(params)
 int8_bytes = quantized_nbytes(qparams)

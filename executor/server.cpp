@@ -582,8 +582,9 @@ ExecOutcome run_subprocess(const std::vector<std::string>& argv,
 }
 
 // ---------------------------------------------------------------------------
-// Device-health telemetry (GET /device-stats). The repo's own bench history
-// (BENCH_r03-r05) shows the worst failure mode is a wedged device op: the
+// Device-health telemetry (GET /device-stats). Rounds 3 to 5 on the TPU rig
+// showed the worst failure mode is a wedged device op (50-76 minutes of
+// manual recovery by host reboot each time): the
 // attach blocks for tens of minutes with /healthz still answering "ok",
 // because nothing distinguished "busy" from "wedged". These globals are the
 // raw signals a probe daemon needs to make that call: when the current
@@ -653,8 +654,8 @@ std::atomic<long long> g_run_scope_seq{0};
 // Every dispatch carries its token in `x-lease-token`; a mismatch is a
 // claim minted for a fenced predecessor on the same chips — rejected with
 // a typed 409 BEFORE any lock is taken, so a stale dispatch cannot even
-// queue behind the device plane it must never touch (the BENCH_r03-r05
-// re-wedge vector). Tiny mutex, never held across I/O.
+// queue behind the device plane it must never touch (how rounds 3 to 5
+// re-wedged a chip for its next holder). Tiny mutex, never held across I/O.
 std::mutex g_lease_mutex;
 std::string g_lease_token;
 
@@ -2772,7 +2773,7 @@ void handle_healthz(const minihttp::Request&, minihttp::Conn& conn) {
 // lock-free (atomics + one tiny string mutex never held across I/O): it
 // must answer while exec_mutex/runner_mutex are pinned by a wedged device
 // op — the exact situation where /healthz kept saying "ok" while attaches
-// never completed (BENCH_r03-r05). Ages are computed server-side on
+// never completed (rounds 3 to 5). Ages are computed server-side on
 // the server's own monotonic clock, so the probe never does cross-host
 // clock math.
 void handle_device_stats(const minihttp::Request&, minihttp::Conn& conn) {

@@ -28,7 +28,7 @@ from bee_code_interpreter_fs_tpu.services.storage import Storage
 CONCURRENCY = 16
 
 # Tiny Llama-class forward, self-shrunk for CI: the same model family and
-# code path as benchmarks/run_configs.py LLAMA_INFER, smaller shapes.
+# code path as BASELINE.json's llama inference configuration, smaller shapes.
 LLAMA_SNIPPET = """
 import jax, jax.numpy as jnp
 from bee_code_interpreter_fs_tpu.models.llama import LlamaConfig, init_params, forward

@@ -14,8 +14,6 @@ carries ``peak_hbm_bytes``; the ``APP_PERF_OBSERVER_ENABLED=0`` run shows
 zero perf surfaces and byte-identical serving behavior.
 """
 
-import asyncio
-
 import pytest
 
 pytest.importorskip("httpx", reason="optional e2e dependency not installed")
@@ -42,11 +40,18 @@ from bee_code_interpreter_fs_tpu.services.storage import Storage
 SLOW_LANE = 2
 HEALTHY_LANE = 0
 TENANT = "perf-acct"
-# The window must FIT a burst of sequential slow requests: at ~0.45s per
-# slowed round-trip, five of them take ~2.3s — a shorter window would
-# scatter them into sub-min_samples slivers the detector rightly ignores.
-WINDOW_S = 2.5
-SLOW_S = 0.4
+# What the test injects, and the detector's absolute band as a function of
+# it: a lane reads degraded only past baseline * factor + MIN_BAND_S, so the
+# healthy lane holds `normal` unless the host's own noise adds half the
+# injected delay to a print, and the slowed lane (every exec + SLOW_S) still
+# clears baseline * 3 + MIN_BAND_S while its baseline is under SLOW_S / 4.
+# (A band of 0.05 s was crossed by the healthy lane under six loaded workers.)
+SLOW_S = 1.0
+MIN_BAND_S = SLOW_S / 2
+# The window must FIT a burst of sequential slow requests (five round trips
+# of SLOW_S and a little): a shorter window would scatter them into
+# sub-min_samples slivers the detector rightly ignores.
+WINDOW_S = 6.0
 
 
 def _config(tmp_path, **overrides) -> Config:
@@ -68,11 +73,26 @@ def _config(tmp_path, **overrides) -> Config:
         ),
         perf_window_seconds=WINDOW_S,
         perf_min_window_samples=3,
-        perf_min_band_seconds=0.05,
+        perf_min_band_seconds=MIN_BAND_S,
         perf_profile_min_interval_seconds=0.0,
     )
     defaults.update(overrides)
     return Config(**defaults)
+
+
+class SkippingClock:
+    """The executor's own clock plus what the test has skipped: a drift
+    window is closed by stepping over it, not by sleeping it out."""
+
+    def __init__(self, clock):
+        self.clock = clock
+        self.skipped = 0.0
+
+    def __call__(self) -> float:
+        return self.clock() + self.skipped
+
+    def skip(self, seconds: float) -> None:
+        self.skipped += seconds
 
 
 async def _build_stack(config):
@@ -88,6 +108,7 @@ async def _build_stack(config):
     transport = backend.http_transport()
     transport.rate = 0.0
     executor._client = httpx.AsyncClient(transport=transport, timeout=90.0)
+    executor.perf.clock = SkippingClock(executor.perf.clock)
     app = create_http_app(executor, CustomToolExecutor(executor), storage)
     client = TestClient(TestServer(app))
     await client.start_server()
@@ -105,10 +126,10 @@ async def _execute(client, lane: int, tenant: str | None = None) -> dict:
     return body
 
 
-async def _window(client, lane: int, n: int = 5, tenant=None) -> list[dict]:
-    bodies = [await _execute(client, lane, tenant) for _ in range(n)]
-    await asyncio.sleep(WINDOW_S + 0.1)
-    return bodies
+async def _window(client, executor, lane: int, tenant=None) -> None:
+    for _ in range(5):
+        await _execute(client, lane, tenant)
+    executor.perf.clock.skip(WINDOW_S + 0.1)
 
 
 def _perf_state(executor, lane: int) -> str:
@@ -121,8 +142,8 @@ async def test_perf_anomaly_plane_end_to_end(tmp_path):
     try:
         # ---- baseline: both lanes healthy over two full windows.
         for _ in range(2):
-            await _window(client, HEALTHY_LANE)
-            await _window(client, SLOW_LANE, tenant=TENANT)
+            await _window(client, executor, HEALTHY_LANE)
+            await _window(client, executor, SLOW_LANE, tenant=TENANT)
         body = await _execute(client, HEALTHY_LANE)
         # Every request's phases carries the device-memory attribution.
         assert "peak_hbm_bytes" in body["phases"], body["phases"]
@@ -133,8 +154,8 @@ async def test_perf_anomaly_plane_end_to_end(tmp_path):
 
         # ---- the regression: the seeded fault lands on the slow lane.
         transport.rate = 1.0
-        await _window(client, SLOW_LANE, tenant=TENANT)
-        await _window(client, HEALTHY_LANE)
+        await _window(client, executor, SLOW_LANE, tenant=TENANT)
+        await _window(client, executor, HEALTHY_LANE)
         # The roll-triggering records: one per lane.
         await _execute(client, SLOW_LANE, tenant=TENANT)
         await _execute(client, HEALTHY_LANE)

@@ -73,8 +73,13 @@ def _requests_billed(executor, tenant="shared"):
     return row["requests"] if row else 0
 
 
-async def test_repeat_pure_run_zero_sandbox_http_zero_chip_seconds(stack):
-    """The BENCH_memo acceptance criterion, test flavor."""
+@pytest.mark.parametrize("repeats", [1, 3, 7])
+async def test_repeat_pure_run_zero_sandbox_http_zero_chip_seconds(
+    stack, repeats
+):
+    """The memo's acceptance criterion: a hit costs no sandbox HTTP and no
+    chip-seconds and is byte for byte the live run, however often it is
+    repeated."""
     executor = stack
     source = "print(sum(range(100)))\nopen('out.txt','w').write('made')"
 
@@ -90,24 +95,26 @@ async def test_repeat_pure_run_zero_sandbox_http_zero_chip_seconds(stack):
     requests_before = _requests_billed(executor)
     wire = _count_sandbox_http(executor)
 
-    cached = await executor.execute(source, pure=True)
-    # Zero sandbox HTTP...
-    assert wire["n"] == 0
-    # ...zero chip-seconds on the ledger (but the request IS counted)...
-    assert _chip_seconds(executor) == chip_before
-    assert _requests_billed(executor) == requests_before + 1
-    assert cached.phases["chip_seconds"] == 0.0
-    assert cached.phases["device_op_seconds"] == 0.0
-    # ...and byte-identical output, files included.
-    assert cached.phases["memo"]["state"] == "hit"
-    assert cached.stdout == live.stdout
-    assert cached.stderr == live.stderr
-    assert cached.exit_code == live.exit_code
-    assert cached.files == live.files
-    assert (
-        await executor.storage.read(cached.files["/workspace/out.txt"])
-        == b"made"
-    )
+    for n in range(1, repeats + 1):
+        cached = await executor.execute(source, pure=True)
+        # Zero sandbox HTTP...
+        assert wire["n"] == 0
+        # ...zero chip-seconds on the ledger (but the request IS counted)...
+        assert _chip_seconds(executor) == chip_before
+        assert _requests_billed(executor) == requests_before + n
+        assert cached.phases["chip_seconds"] == 0.0
+        assert cached.phases["device_op_seconds"] == 0.0
+        # ...and byte-identical output, files included.
+        assert cached.phases["memo"]["state"] == "hit"
+        assert cached.stdout == live.stdout
+        assert cached.stderr == live.stderr
+        assert cached.exit_code == live.exit_code
+        assert cached.files == live.files
+        assert (
+            await executor.storage.read(cached.files["/workspace/out.txt"])
+            == b"made"
+        )
+    assert executor.result_memo.entry_count() == 1
 
 
 async def test_stderr_and_nonzero_exit_memoize_too(stack):
@@ -161,14 +168,44 @@ async def test_input_files_key_the_record(stack):
 
 
 async def test_kill_switch_parity_e2e(tmp_path):
-    executor = _make_stack(tmp_path, result_memo_enabled=False)
+    source = "print('off')\nopen('out.bin','wb').write(bytes(range(16)))"
+
+    async def output_bytes(executor, result):
+        return (
+            result.stdout,
+            result.stderr,
+            result.exit_code,
+            {
+                path: await executor.storage.read(object_id)
+                for path, object_id in sorted(result.files.items())
+            },
+        )
+
+    executor = _make_stack(tmp_path / "disabled", result_memo_enabled=False)
     try:
         for _ in range(2):
-            result = await executor.execute("print('off')", pure=True)
+            result = await executor.execute(source, pure=True)
             assert result.exit_code == 0, result.stderr
             assert "memo" not in result.phases
+        disabled = await output_bytes(executor, result)
         assert executor.result_memo.entry_count() == 0
-        assert not (tmp_path / "storage" / ".result-memo").exists()
+        assert not (
+            tmp_path / "disabled" / "storage" / ".result-memo"
+        ).exists()
+    finally:
+        await executor.close()
+    # Byte for byte what the memo plane answers for the same request, live
+    # and from the record.
+    executor = _make_stack(tmp_path / "enabled")
+    try:
+        live = await executor.execute(source, pure=True)
+        hit = await executor.execute(source, pure=True)
+        assert hit.phases["memo"]["state"] == "hit"
+        assert (
+            disabled
+            == await output_bytes(executor, live)
+            == await output_bytes(executor, hit)
+        )
     finally:
         await executor.close()
 

@@ -3,8 +3,8 @@ engine built and driven INSIDE a sandbox via Execute — orchestrator →
 pool → C++ executor server → warm JAX runner → ServingEngine — with the
 outputs token-checked against the fused decoder in the same process.
 
-This is config 5g's correctness backbone (benchmarks/run_configs.py runs
-the throughput version on the chip); here the full feature surface rides
+This is the correctness backbone of BASELINE.json's config 5 (a resident
+engine served from a session); here the full feature surface rides
 one Execute: prefix caching, per-request sampling with a seed, logprobs,
 and a QLoRA adapter served beside base traffic.
 """

@@ -47,33 +47,51 @@ async def legacy_stack(tmp_path, monkeypatch):
     await executor.close()
 
 
-async def test_session_unchanged_files_move_no_bytes(stack):
+@pytest.mark.parametrize(
+    "num_files, file_bytes", [(1, 4096), (4, 8192), (16, 65536)]
+)
+async def test_session_unchanged_files_move_no_bytes(
+    stack, num_files, file_bytes
+):
     executor = stack
-    payload = b"A" * 4096
-    object_id = await executor.storage.write(payload)
-    files = {"/workspace/input.bin": object_id}
-
-    first = await executor.execute(
-        "print(len(open('input.bin','rb').read()))",
-        files=files,
-        executor_id="xfer-sess",
+    total = num_files * file_bytes
+    # Distinct content per file: the skip must come from the manifest
+    # protocol, not from accidentally identical payloads.
+    files = {
+        f"/workspace/input-{i:03d}.bin": await executor.storage.write(
+            bytes([i]) * file_bytes
+        )
+        for i in range(num_files)
+    }
+    source = (
+        "import glob\n"
+        "print(sum(len(open(p,'rb').read()) for p in glob.glob('input-*.bin')))"
     )
-    assert first.exit_code == 0, first.stderr
-    assert first.stdout.strip() == "4096"
+
+    async def turn(turn_files):
+        result = await executor.execute(
+            source, files=turn_files, executor_id="xfer-sess"
+        )
+        assert result.exit_code == 0, result.stderr
+        assert result.stdout.strip() == str(total)
+        return result.phases
+
     # Cold turn: everything moved, nothing skipped.
-    assert first.phases["upload_bytes"] == float(len(payload))
-    assert first.phases["upload_skipped_bytes"] == 0.0
-
-    second = await executor.execute(
-        "print(len(open('input.bin','rb').read()))",
-        files=files,
-        executor_id="xfer-sess",
-    )
-    assert second.exit_code == 0, second.stderr
-    assert second.stdout.strip() == "4096"
+    cold = await turn(files)
+    assert cold["upload_bytes"] == float(total)
+    assert cold["upload_skipped_bytes"] == 0.0
     # Unchanged turn: the manifest delta moved nothing.
-    assert second.phases["upload_bytes"] == 0.0
-    assert second.phases["upload_skipped_bytes"] == float(len(payload))
+    unchanged = await turn(files)
+    assert unchanged["upload_bytes"] == 0.0
+    assert unchanged["upload_skipped_bytes"] == float(total)
+    # One file changed: that file moves, the rest are skipped.
+    changed = dict(files)
+    changed["/workspace/input-000.bin"] = await executor.storage.write(
+        b"\xff" * file_bytes
+    )
+    one_changed = await turn(changed)
+    assert one_changed["upload_bytes"] == float(file_bytes)
+    assert one_changed["upload_skipped_bytes"] == float(total - file_bytes)
 
 
 async def test_download_negotiated_away_for_known_content(stack):

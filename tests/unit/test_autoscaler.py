@@ -394,39 +394,46 @@ async def test_spawn_burst_cap_zero_is_uncapped(tmp_path):
         await executor.close()
 
 
-async def test_kill_switch_executor_behavior_is_static(tmp_path):
+@pytest.mark.parametrize("static_target, jobs", [(2, 8), (1, 4), (1, 6)])
+async def test_kill_switch_executor_behavior_is_static(
+    tmp_path, static_target, jobs
+):
     """APP_POOL_AUTOSCALE_ENABLED=0 end to end: targets are the static
-    constant, bursts do not move them, the sweep is a no-op, and
+    constant, bursts do not move them, surplus sandboxes are disposed back
+    down to it, the autoscaler emits nothing, the sweep is a no-op, and
     start_autoscaler refuses to run."""
     backend = FakeBackend()
     executor = make_executor(
         backend,
         tmp_path,
-        executor_pod_queue_target_length=2,
+        executor_pod_queue_target_length=static_target,
         pool_autoscale_enabled=False,
     )
     try:
-        assert executor._lane_target(0) == 2
+        assert executor._lane_target(0) == static_target
         results = await asyncio.gather(
-            *(executor.execute("print('x')") for _ in range(8))
+            *(executor.execute("print('x')") for _ in range(jobs))
         )
         assert all(r.exit_code == 0 for r in results)
         await settle(executor)
-        assert executor._lane_target(0) == 2
-        assert len(executor._pool(0)) <= 2
+        assert executor.autoscaler.target(0) == static_target
+        assert executor._lane_target(0) == static_target
+        assert len(executor._pool(0)) <= static_target
+        assert executor.metrics.pool_scale_events.samples() == []
         assert await executor.autoscale_sweep() == 0
         assert executor.start_autoscaler() is None
         assert executor.statusz()["autoscaler"] == {
             "enabled": False,
             "min_target": 1,
             "max_target": 16,
-            "static_target": 2,
+            "static_target": static_target,
         }
     finally:
         await executor.close()
 
 
-async def test_burst_retains_recycles_up_to_dynamic_target(tmp_path):
+@pytest.mark.parametrize("jobs", [6, 4])
+async def test_burst_retains_recycles_up_to_dynamic_target(tmp_path, jobs):
     """The demand loop end to end: a concurrent burst raises the lane
     target, so released sandboxes recycle into the pool (ready for the
     next wave) instead of being disposed back down to the static 1."""
@@ -436,7 +443,7 @@ async def test_burst_retains_recycles_up_to_dynamic_target(tmp_path):
     )
     try:
         results = await asyncio.gather(
-            *(executor.execute("print('x')") for _ in range(6))
+            *(executor.execute("print('x')") for _ in range(jobs))
         )
         assert all(r.exit_code == 0 for r in results)
         await settle(executor)

@@ -98,32 +98,48 @@ async def drain(executor: CodeExecutor) -> None:
         await asyncio.gather(*pending, return_exceptions=True)
 
 
-async def test_full_batch_one_dispatch_demuxed_results(tmp_path):
-    executor, harness = make_executor(tmp_path)
+# (lane chips, jobs): the default four-chip host, and one job a chip of an
+# eight-chip single-host lane.
+FULL_BATCHES = [(LANE, 4), (8, 8)]
+
+
+def make_lane_executor(tmp_path, lane, jobs, **config_kwargs):
+    return make_executor(
+        tmp_path,
+        tpu_chips_per_host=lane,  # keeps the lane on one host
+        batch_max_jobs=jobs,
+        **config_kwargs,
+    )
+
+
+@pytest.mark.parametrize("lane, jobs", FULL_BATCHES)
+async def test_full_batch_one_dispatch_demuxed_results(tmp_path, lane, jobs):
+    executor, harness = make_lane_executor(tmp_path, lane, jobs)
     try:
         results = await asyncio.gather(
             *(
-                executor.execute(f"print({i})", chip_count=LANE)
-                for i in range(4)
+                executor.execute(f"print({i})", chip_count=lane)
+                for i in range(jobs)
             )
         )
-        # ONE fused round-trip served all four requests...
+        # ONE fused round-trip served every request, none fell back to the
+        # serial path...
         assert len(harness.batch_calls) == 1
         assert len(harness.serial_calls) == 0
         payload = harness.batch_calls[0]
         assert [j["source_code"] for j in payload["jobs"]] == [
-            f"print({i})" for i in range(4)
+            f"print({i})" for i in range(jobs)
         ]
         # ...with the device-axis placement hint per job...
-        assert [j["device_index"] for j in payload["jobs"]] == [0, 1, 2, 3]
+        assert [j["device_index"] for j in payload["jobs"]] == list(range(jobs))
         # ...and each caller got ITS job's demuxed result.
         for i, result in enumerate(results):
             assert result.stdout == f"job {i} ok\n"
             assert result.exit_code == 0
             assert result.phases["batch_index"] == float(i)
-            assert result.phases["batch_jobs"] == 4.0
+            assert result.phases["batch_jobs"] == float(jobs)
         # Occupancy fed the scheduler (full batch = 1.0).
-        assert executor.scheduler.batch_occupancies()[LANE] == 1.0
+        assert executor.scheduler.batch_occupancies()[lane] == 1.0
         # The batch demux coordinates ride in phases but are NOT latencies:
         # they must never pollute the phase_seconds histogram (found live —
         # batch_jobs=8.0 read as an 8-second sample).
@@ -230,19 +246,25 @@ async def test_tenants_never_share_a_dispatch(tmp_path):
         await executor.close()
 
 
-async def test_kill_switch_restores_serial_path(tmp_path):
-    executor, harness = make_executor(tmp_path, batching_enabled=False)
+@pytest.mark.parametrize("lane, jobs", FULL_BATCHES)
+async def test_kill_switch_restores_serial_path(tmp_path, lane, jobs):
+    executor, harness = make_lane_executor(
+        tmp_path, lane, jobs, batching_enabled=False
+    )
     try:
         results = await asyncio.gather(
             *(
-                executor.execute(f"print({i})", chip_count=LANE)
-                for i in range(4)
+                executor.execute(f"print({i})", chip_count=lane)
+                for i in range(jobs)
             )
         )
         assert executor.batcher is None
         assert len(harness.batch_calls) == 0
-        assert len(harness.serial_calls) == 4
+        assert len(harness.serial_calls) == jobs
         assert all(r.stdout == "serial ok\n" for r in results)
+        # The serial path never touched the batch plane: no demux
+        # coordinates in any result.
+        assert all("batch_jobs" not in r.phases for r in results)
     finally:
         await executor.close()
 

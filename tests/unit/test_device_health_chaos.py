@@ -6,7 +6,8 @@ CI pins the {7, 23, 1337} matrix; a red leg replays exactly with
 The injected fault is a HANG, not an error: the host's HTTP plane answers
 every probe, but its synthesized /device-stats reports an attach that has
 been pending since the hang began and keeps aging in (injected) real time —
-the BENCH_r03-r05 wedge semantics. The probe must walk that host
+the wedge of rounds 3 to 5 (a device op that never completes). The probe
+must walk that host
 healthy -> (busy/suspect) -> wedged while untouched hosts stay healthy.
 """
 

@@ -543,6 +543,7 @@ def test_loop_teardown_with_undrained_deletes_ends(fake_kubectl):
     cancelled mid-start used to wait forever."""
     import asyncio
     import threading
+    import time
 
     kubectl, state, calls = fake_kubectl
     (state / "fail_wait").touch()
@@ -556,11 +557,24 @@ def test_loop_teardown_with_undrained_deletes_ends(fake_kubectl):
         target=asyncio.run, args=(failed_group_spawn(),), daemon=True
     )
     runner.start()
-    runner.join(timeout=30.0)
+    # A hang is forever, so the bound only has to outlast a loaded host's
+    # eight kubectl starts.
+    runner.join(timeout=120.0)
     assert not runner.is_alive()
+
+    def deleted():
+        return {c["argv"][2] for c in calls() if c["argv"][0] == "delete"}
+
+    # The teardown cancels a delete between its Popen and the worker thread
+    # picking up `communicate`: the kubectl process is started and runs to
+    # its end, but nothing waits for it, so neither the thread pool's
+    # shutdown nor the loop's end says when it has logged its call. Wait for
+    # the calls themselves.
+    deadline = time.monotonic() + 120.0
+    while len(deleted()) < 3 and time.monotonic() < deadline:
+        time.sleep(0.05)
     kubectl._threads.shutdown(wait=True)
-    deleted = {c["argv"][2] for c in calls() if c["argv"][0] == "delete"}
-    assert len(deleted) == 3  # both pods and the group's headless service
+    assert len(deleted()) == 3  # both pods and the group's headless service
 
 
 async def test_single_host_watch_failure_leaves_strike_to_executor(fake_kubectl):

@@ -305,8 +305,8 @@ def test_headline_sum_of_squares_divergence_bounded(np_shim):
     real numpy's float64 pairwise summation. This is the tested bound behind
     the precision policy: XLA reduces in tiles, so f32 accumulation error
     grows ~eps*log(n), not eps*n — the bound is n-insensitive, so the test
-    uses 1e7 elements to stay CI-sized (the 1e8 headline run goes through
-    bench.py on the real machine)."""
+    uses 1e7 elements to stay CI-sized (the chip-sized run is the
+    benchmark's `sumsq` payload, `benchmarks/chip/payloads/`)."""
     import numpy as real_np
 
     rng = real_np.random.default_rng(42)

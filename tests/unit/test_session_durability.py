@@ -123,44 +123,59 @@ def counter(executor, name, **labels):
     return 0.0
 
 
-async def test_hibernate_releases_chip_then_restore_continues_seq(tmp_path):
+@pytest.mark.parametrize("sessions", [1, 3, 5])
+async def test_hibernate_releases_chip_then_restore_continues_seq(
+    tmp_path, sessions
+):
+    """One session after another on a one-slot backend: each hibernates,
+    gives the chip back, restores and closes; the counts add up."""
     backend = FakeBackend(capacity=1)
     executor, server, plane = make_executor(backend, tmp_path)
     try:
-        first = await executor.execute("x", executor_id="sess-d")
-        assert first.session_seq == 1
-        assert executor._session_held.get(0) == 1
+        for n in range(1, sessions + 1):
+            sid = f"sess-d{n}"
+            first = await executor.execute("x", executor_id=sid)
+            assert first.session_seq == 1
+            assert executor._session_held.get(0) == 1
 
-        # Idle past the hibernate threshold but NOT past the hard idle
-        # timeout: the durability leg must fire first.
-        age_session(
-            executor,
-            "sess-d",
-            executor.config.session_hibernate_idle_seconds + 1.0,
-        )
-        assert await executor.sweep_sessions() == 1
-        await settle(executor)
-        # The chip is back: session_held drained, the session is a record.
-        assert executor._session_held.get(0) == 0
-        assert plane.snapshots == 1
-        assert executor.session_store.entry_count() == 1
-        assert counter(executor, "session_hibernates", outcome="hibernate") == 1
-        snap = executor.statusz()["session_durability"]
-        assert snap["enabled"] is True and snap["hibernated"] == 1
+            # Idle past the hibernate threshold but NOT past the hard idle
+            # timeout: the durability leg must fire first.
+            age_session(
+                executor,
+                sid,
+                executor.config.session_hibernate_idle_seconds + 1.0,
+            )
+            assert await executor.sweep_sessions() == 1
+            await settle(executor)
+            # The chip is back: session_held drained, the session is a
+            # record.
+            assert executor._session_held.get(0) == 0
+            assert sid not in executor._sessions
+            assert plane.snapshots == n
+            assert executor.session_store.entry_count() == 1
+            assert (
+                counter(executor, "session_hibernates", outcome="hibernate")
+                == n
+            )
+            snap = executor.statusz()["session_durability"]
+            assert snap["enabled"] is True and snap["hibernated"] == 1
 
-        # Next turn restores lazily: interpreter state shipped back,
-        # session_seq CONTINUOUS (2, not a reset to 1), restore phase
-        # reported.
-        second = await executor.execute("x", executor_id="sess-d")
-        assert second.session_seq == 2
-        assert second.session_ended is False
-        assert plane.restored == [dict(plane.STATE)]
-        assert "restore" in second.phases
-        assert counter(executor, "session_restores", outcome="restored") == 1
-        # The record stays until close/expiry (it is superseded on the
-        # next hibernate via first-write-wins on a newer seq).
-        assert await executor.close_session("sess-d") is True
-        assert executor.session_store.entry_count() == 0
+            # Next turn restores lazily: interpreter state shipped back,
+            # session_seq CONTINUOUS (2, not a reset to 1), restore phase
+            # reported.
+            second = await executor.execute("x", executor_id=sid)
+            assert second.session_seq == 2
+            assert second.session_ended is False
+            assert plane.restored == [dict(plane.STATE)] * n
+            assert "restore" in second.phases
+            assert (
+                counter(executor, "session_restores", outcome="restored") == n
+            )
+            # The record stays until close/expiry (it is superseded on the
+            # next hibernate via first-write-wins on a newer seq).
+            assert await executor.close_session(sid) is True
+            await settle(executor)
+            assert executor.session_store.entry_count() == 0
     finally:
         await executor.close()
 
@@ -185,12 +200,19 @@ async def test_restore_in_flight_turn_gets_typed_refusal(tmp_path):
         turn_a = asyncio.ensure_future(
             executor.execute("x", executor_id="sess-r")
         )
-        for _ in range(200):
-            await asyncio.sleep(0)
+        # The restore parks at the gate, so the state below holds until the
+        # gate opens; getting there takes real time (the record is loaded and
+        # a sandbox acquired first), so wait for the state, not for a count
+        # of loop turns: 200 of them ran out under six loaded workers.
+        loop = asyncio.get_running_loop()
+        deadline = loop.time() + 60.0
+        session = None
+        while loop.time() < deadline:
             session = executor._sessions.get("sess-r")
             if session is not None and session.restoring:
                 break
-        assert executor._sessions["sess-r"].restoring is True
+            await asyncio.sleep(0.005)
+        assert session is not None and session.restoring is True
 
         with pytest.raises(SessionRestoringError) as exc_info:
             await executor.execute("x", executor_id="sess-r")
@@ -330,6 +352,12 @@ async def test_kill_switch_restores_pin_forever_semantics(tmp_path):
         assert not (
             tmp_path / "storage" / ".session-store"
         ).exists()
+        # The session is still live where it was: the next turn continues
+        # it with nothing to restore.
+        live = await executor.execute("x", executor_id="sess-k")
+        assert live.session_seq == 2
+        assert "restore" not in live.phases
+        assert "sess-k" in executor._sessions
         # A fence force-closes, exactly as before the plane existed.
         sandbox = executor._sessions["sess-k"].sandbox
         await executor.fence_host(sandbox.id, reason="wedged")

@@ -218,8 +218,8 @@ async def test_server_log_written_per_sandbox(tmp_path):
 async def test_missing_binary_triggers_auto_build(tmp_path, monkeypatch):
     """A fresh checkout has no executor binary (`executor/build/` is
     gitignored); the first spawn must attempt `make -C executor` instead of
-    failing outright — a re-imaged driver machine runs bench.py without a
-    manual build step."""
+    failing outright — a re-imaged machine serves its first request
+    without a manual build step."""
     from bee_code_interpreter_fs_tpu.services.backends import local as local_mod
 
     backend = LocalSandboxBackend(_config(tmp_path), warm_import_jax=False)

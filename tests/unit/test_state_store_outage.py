@@ -8,11 +8,14 @@ fleet windows failing open, session restore refusing typed.
 """
 
 import pytest
+from fakes import FakeBackend
 
+from bee_code_interpreter_fs_tpu.config import Config
 from bee_code_interpreter_fs_tpu.services.backends.faults import (
     FaultInjectingStateStore,
     StoreFaultSpec,
 )
+from bee_code_interpreter_fs_tpu.services.code_executor import CodeExecutor
 from bee_code_interpreter_fs_tpu.services.errors import StateStoreDegradedError
 from bee_code_interpreter_fs_tpu.services.leases import LeaseRegistry
 from bee_code_interpreter_fs_tpu.services.quotas import _FleetWindows
@@ -77,7 +80,8 @@ class Clock:
 
 
 def resilient(**kwargs):
-    inner = FlakyStore()
+    # `inner=` hands several wrappers (replicas) one store.
+    inner = kwargs.pop("inner", None) or FlakyStore()
     clock = kwargs.pop("clock", None) or Clock()
     kwargs.setdefault("failure_threshold", 2)
     kwargs.setdefault("cooldown", 5.0)
@@ -326,24 +330,28 @@ def test_stale_serves_cached_floor_during_outage():
     assert not registry_a.stale(lease_new)
 
 
-def test_zero_double_grants_across_replicas_through_outage():
-    """The bench invariant, unit-sized: generations minted by two replicas
-    around an outage never collide (fencing tokens stay unique)."""
-    store_a, inner, clock_a = resilient()
-    # Replica B shares the same inner store through its own wrapper.
-    clock_b = Clock()
-    store_b = ResilientStateStore(inner, failure_threshold=2, clock=clock_b)
-    a = LeaseRegistry(store=store_a)
-    b = LeaseRegistry(store=store_b)
-    minted = [a.mint("host-1"), b.mint("host-1")]
+@pytest.mark.parametrize("replicas", [2, 3])
+def test_zero_double_grants_across_replicas_through_outage(replicas):
+    """Generations minted by several replicas around an outage never
+    collide (fencing tokens stay unique), and every replica refuses to mint
+    while the store is down."""
+    inner = FlakyStore()
+    clocks = [Clock() for _ in range(replicas)]
+    # Every replica shares the same inner store through its own wrapper.
+    registries = [
+        LeaseRegistry(store=resilient(inner=inner, clock=clock)[0])
+        for clock in clocks
+    ]
+    minted = [registry.mint("host-1") for registry in registries]
     inner.down = True
-    for registry in (a, b):
+    for registry in registries:
         with pytest.raises(StateStoreDegradedError):
             registry.mint("host-1")
+    assert [r.degraded_mint_refusals for r in registries] == [1] * replicas
     inner.down = False
-    clock_a.now += 6.0
-    clock_b.now += 6.0
-    minted += [b.mint("host-1"), a.mint("host-1")]
+    for clock in clocks:
+        clock.now += 6.0
+    minted += [registry.mint("host-1") for registry in reversed(registries)]
     generations = [lease.generation for lease in minted]
     assert len(set(generations)) == len(generations)
     assert generations == sorted(generations)
@@ -352,27 +360,38 @@ def test_zero_double_grants_across_replicas_through_outage():
 # ------------------------------------------------------------ quota half
 
 
-def test_fleet_windows_fail_open_and_reconcile():
+@pytest.mark.parametrize("replicas, adds", [(1, 1), (3, 5)])
+def test_fleet_windows_fail_open_and_reconcile(replicas, adds):
     clock = Clock(now=1000.0)
-    store, inner, _ = resilient(clock=clock)
-    fleet = _FleetWindows(store, walltime=clock)
-    fleet.add("tenant-a", "chip", 10.0, window=80.0)
-    assert fleet.used("tenant-a", "chip", 80.0) == 10.0
+    inner = FlakyStore()
+    stores = [resilient(inner=inner, clock=clock)[0] for _ in range(replicas)]
+    fleets = [_FleetWindows(store, walltime=clock) for store in stores]
+    fleets[0].add("tenant-a", "chip", 10.0, window=80.0)
+    assert fleets[0].used("tenant-a", "chip", 80.0) == 10.0
     inner.down = True
     # Outage: accrual fails OPEN — publish keeps succeeding against the
     # wrapper (journal), the fleet view degrades to whatever the shadow
     # holds, and nothing raises on the admit path.
-    fleet.add("tenant-a", "chip", 5.0, window=80.0)
+    for fleet in fleets:
+        for _ in range(adds):
+            fleet.add("tenant-a", "chip", 1.0, window=80.0)
     clock.now += 1.0  # age past the items() read TTL
-    assert fleet.used("tenant-a", "chip", 80.0) == 5.0  # shadow-local view
-    assert fleet.publish_errors == 0  # wrapper absorbed it: no raw failure
-    # Reconnect: journaled deltas replay; within one window the fleet view
-    # reconverges to the full accrual.
+    # Each replica sees its own shadow, not its peers'.
+    assert [f.used("tenant-a", "chip", 80.0) for f in fleets] == [
+        float(adds)
+    ] * replicas
+    # The wrapper absorbed it: no raw failure.
+    assert [f.publish_errors for f in fleets] == [0] * replicas
+    # Reconnect: journaled deltas replay; within one window a FRESH handle
+    # (no replica-local state) reads the full accrual of every replica.
     inner.down = False
     clock.now += 6.0  # past the breaker cooldown
-    store.get("wfq", "poke")  # heal + replay
+    for store in stores:
+        store.get("wfq", "poke")  # heal + replay
+        assert store.health()["journal_depth"] == 0
     clock.now += 1.0
-    assert fleet.used("tenant-a", "chip", 80.0) == 15.0
+    fresh = _FleetWindows(inner, walltime=clock)
+    assert fresh.used("tenant-a", "chip", 80.0) == 10.0 + replicas * adds
 
 
 def test_fleet_windows_bare_store_outage_counts_publish_errors():
@@ -439,3 +458,84 @@ async def test_session_restore_fails_closed_observers_fail_open(tmp_path):
     record = await sessions.load("t1", "sess-a")
     assert record is not None and record["seq"] == 1
     assert inner.get(SESSION_NS, "t1/sess-a") is not None
+
+
+# ---------------------------------------------------------- executor level
+
+
+@pytest.mark.parametrize("replicas", [2, 3])
+async def test_replicas_keep_serving_through_store_outage(tmp_path, replicas):
+    """The store-loss drill at the executor: control-plane replicas over
+    ONE shared store, each behind its own resilience wrapper, with their
+    sandboxes warm when the store dies. Every turn sent during the outage is
+    served, a mint is refused by every replica (fail closed), and no (scope,
+    generation) pair is granted twice before, during or after it."""
+    inner = FlakyStore()
+    clocks = [Clock() for _ in range(replicas)]
+    executors = []
+    minted: list[tuple[str, int]] = []
+
+    def recording(registry):
+        mint = registry.mint
+
+        def recorded(scope, sandbox_id=""):
+            lease = mint(scope, sandbox_id)
+            minted.append((lease.scope, lease.generation))
+            return lease
+
+        registry.mint = recorded
+
+    async def post_execute(client, base, payload, timeout, sandbox):
+        return {
+            "stdout": "ok\n",
+            "stderr": "",
+            "exit_code": 0,
+            "files": [],
+            "warm": True,
+        }
+
+    for index in range(replicas):
+        config = Config(
+            file_storage_path=str(tmp_path / f"replica-{index}" / "storage"),
+            usage_journal_path=str(tmp_path / f"replica-{index}" / "usage"),
+            executor_pod_queue_target_length=1,
+            compile_cache_prewarm=False,
+            replica_self=f"replica-{index}",
+        )
+        executor = CodeExecutor(
+            FakeBackend(),
+            Storage(config.file_storage_path),
+            config,
+            state_store=resilient(inner=inner, clock=clocks[index])[0],
+        )
+        executor._post_execute = post_execute
+        recording(executor.leases)
+        executors.append(executor)
+    try:
+        # Store up: every replica serves (its sandbox minted on the fleet
+        # counter), and mints on one scope draw unique generations.
+        for executor in executors:
+            assert (await executor.execute("print(1)")).exit_code == 0
+            executor.leases.mint("shared-scope")
+
+        inner.down = True
+        for _ in range(8):
+            for executor in executors:
+                assert (await executor.execute("print(1)")).exit_code == 0
+        for executor in executors:
+            with pytest.raises(StateStoreDegradedError):
+                executor.leases.mint("shared-scope")
+            # The turns were served DEGRADED: the replica's own serving path
+            # found the store down, not only the mint above.
+            health = executor.state_store.health()
+            assert health["outages"] == 1 and health["degraded_ops"] > 1
+
+        inner.down = False
+        for executor, clock in zip(executors, clocks):
+            clock.now += 6.0  # past the breaker cooldown
+            executor.leases.mint("shared-scope")
+            assert (await executor.execute("print(1)")).exit_code == 0
+        assert len(minted) == len(set(minted))
+    finally:
+        for executor in executors:
+            await executor.close()
