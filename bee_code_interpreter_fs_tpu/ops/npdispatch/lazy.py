@@ -48,6 +48,24 @@ or any node of which something else reads (a view the user holds) keeps
 `setitem_op` / `getitem_op` as they are. `counters.aligned_stores` counts the
 stores that ran in the full-shape form.
 
+That one fusion still streams the read array from HBM once per shifted window
+and reads the target for the select: seven array-sized streams for a 5-point
+stencil. Where the program runs on a TPU (`_platform`: the device its leaves
+live on) and the store is one the kernel takes (`_kernel_plan`: two
+dimensions of whole registers, 32 bits an element throughout, every shift
+within 8 rows and 128 lanes, the target read at shift zero if at all, rows
+enough for four blocks), the whole store is ONE Pallas kernel instead
+(`stencil.window_store`): every source streamed once in row blocks with its
+halo rows in VMEM, the expression evaluated strip by strip by the SAME
+functions in the SAME order (`_Expression`), the target written in place and
+read only where the expression reads it or the window's border is wide. One
+plan, two lowerings, chosen by what can be observed; no option. A kernel the
+chip's compiler refuses never fails a turn: `_run` builds the program again
+with every store as a select. `counters.kernel_stores` counts the stores that
+ran as a kernel. So that an in-place kernel finds its target's buffer, a
+program returns its outputs in the order in which jax pairs them with the
+donated leaves they were stored into (`_paired`).
+
 `counters` counts what the shim did since it was last taken (the warm runner
 takes it at the end of each turn and stamps it into the reply).
 """
@@ -58,11 +76,13 @@ import logging
 import math
 import sys
 import time
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as real_np
+
+from . import stencil
 
 logger = logging.getLogger(__name__)
 
@@ -94,8 +114,12 @@ class Counters:
                        `build_node` and `materialize`
     donated_bytes      leaves donated to the program that consumed them
     aligned_stores     window stores (`a[1:-1, 1:-1] = f(b[...])`) that a
-                       program executed as one select over the array's full
-                       shape (`_full_shape_plan`), counted per execution
+                       program executed over the array's full shape
+                       (`_full_shape_plan`), as a select or as a kernel,
+                       counted per execution
+    kernel_stores      those of them that ran as one Pallas kernel, each
+                       grid streamed once (`stencil.window_store`); the rest
+                       ran as one select fusion
     fallbacks          calls the shim routed to the device that ran under
                        stock numpy after all (`np.fromfunction` of a function
                        a TpuArray cannot serve, a jnp function that refused
@@ -105,14 +129,15 @@ class Counters:
     """
 
     FIELDS = ("programs", "exec_cache_misses", "nodes", "flushes", "h2d_bytes",
-              "donated_bytes", "aligned_stores", "fallbacks", "host_s")
+              "donated_bytes", "aligned_stores", "kernel_stores", "fallbacks", "host_s")
 
     def __init__(self) -> None:
         self.reset()
 
     def reset(self) -> None:
         self.programs = self.exec_cache_misses = self.nodes = self.flushes = 0
-        self.h2d_bytes = self.donated_bytes = self.aligned_stores = self.fallbacks = 0
+        self.h2d_bytes = self.donated_bytes = self.aligned_stores = self.kernel_stores = 0
+        self.fallbacks = 0
         self.host_s = 0.0
         self._depth = 0  # build_node -> flush -> materialize nest: count once
         self._entered = self._outside = 0.0
@@ -364,8 +389,9 @@ def precision_scope():
 
 # Materialization: linearize DAG -> structure key -> cached jitted runner.
 
-# structure key -> (the jitted runner, the window stores it runs at full shape)
-_exec_cache: dict[tuple, tuple[Callable, int]] = {}
+# structure key -> (the jitted runner, the nodes whose values it returns, in its
+# order, the window stores it runs at full shape, those of them as a kernel)
+_exec_cache: dict[tuple, tuple[Callable, list, int, int]] = {}
 _CACHE_LIMIT = 512
 
 
@@ -536,6 +562,39 @@ def _donatable(lin: _Linear, out_indices: list[int]) -> list[int]:
     return donated
 
 
+def _paired(lin: _Linear, out_indices: list[int], donated: list[int]) -> list[int]:
+    """`out_indices` in the order the program returns them. jax gives the k-th
+    donated argument of a shape and dtype the buffer of the k-th output of that
+    shape and dtype, whatever made it; so the output that a chain of stores
+    makes of a donated leaf (`a[idx] = v`, again and again) is put where it is
+    paired with that leaf, and the store runs in place. Any other order is as
+    correct and costs a copy of the array on the way in and one on the way
+    out wherever an in-place kernel writes it."""
+    def slot(aval):
+        return tuple(aval.shape), str(aval.dtype)
+
+    made_of: dict[int, int] = {}  # a donated leaf: the position of the last output stored into it
+    for at, i in enumerate(out_indices):
+        ref = (_REF_NODE, i)
+        while ref[0] == _REF_NODE and lin.spec[ref[1]][0] is setitem_op:
+            ref = lin.spec[ref[1]][1][0]
+        if ref[0] == _REF_LEAF and ref[1] in donated:
+            made_of[ref[1]] = at
+    if not made_of:
+        return out_indices
+    order = list(range(len(out_indices)))
+    for key in {slot(lin.leaves[li]) for li in made_of}:
+        places = [at for at in order if slot(lin.nodes[out_indices[at]].aval) == key]
+        others = [at for at in places if at not in made_of.values()]
+        ranked = []
+        for li in donated:
+            if slot(lin.leaves[li]) == key and (li in made_of or others):
+                ranked.append(made_of[li] if li in made_of else others.pop(0))
+        for place, at in zip(places, ranked + others):
+            order[place] = at
+    return [out_indices[at] for at in order]
+
+
 # The element-wise operator functions: what `TpuArray`'s binary and unary
 # operators call. Filled by shim.py where its operator tables are defined.
 ELEMENTWISE_OPS: list = []
@@ -565,11 +624,121 @@ def _window(idx, shape):
     return tuple(starts), tuple(sizes)
 
 
-def _full_shape_plan(lin: _Linear, out_indices: list[int]):
+class _Expression(NamedTuple):
+    """A store's value as a kernel evaluates it on one strip: a step for each
+    node of the expression, operands first. A read is (None, the source's
+    slot or None for the target, the shift); an operator is (its function,
+    its operands, its keyword arguments as sorted items), an operand one of
+    ("tile", None, an earlier step), ("scalar", None, a 0-d array's slot) and
+    ("static", its type, the value): 2.0 and np.float64(2.0) are equal and
+    trace apart. Hashable by what it holds, so that the stores of one
+    structure (a time loop's, the same every step) are traced and lowered to
+    ONE kernel, called once for each."""
+
+    steps: tuple
+
+    @property
+    def reads_target(self) -> bool:
+        return any(fn is None and slot is None for fn, slot, _ in self.steps)
+
+    def __call__(self, read, scalars):
+        tiles = []
+        for fn, operands, kwargs in self.steps:
+            if fn is None:  # a read: the source's slot and the shift are in the operator's places
+                tiles.append(read(operands, kwargs))
+                continue
+            tiles.append(fn(*[
+                tiles[v] if kind == "tile" else scalars[v] if kind == "scalar" else v
+                for kind, _, v in operands
+            ], **dict(kwargs)))
+        return tiles[-1]
+
+
+class _Kernel(NamedTuple):
+    """A window store as one `stencil.window_store`: what `_kernel_plan` read
+    from the store's expression. References are `_Linear.spec`'s (kind, index)."""
+
+    nodes: tuple  # the expression's nodes: evaluated inside the kernel and nowhere else
+    target: tuple
+    sources: tuple  # the distinct arrays the expression reads, the target left out
+    reads: tuple  # for each of them, the shifts it is read at
+    scalars: tuple  # the 0-d arrays among the operators' operands
+    expression: _Expression
+    block_rows: int
+
+
+def _kernel_plan(lin: _Linear, s: int, tree: dict) -> _Kernel | None:
+    """Store `s`, whose expression `tree` (`_full_shape_plan`) qualified for
+    the full shape, as a kernel; None keeps the select. Read from the shapes,
+    the dtypes and the shifts: two dimensions of whole registers and enough
+    rows for a few blocks (`stencil.block_rows`); 32 bits an element in every
+    array, every 0-d operand (strongly typed) and every intermediate, booleans
+    allowed between; every shift inside the halo; and the target read at
+    shift zero only, since the kernel writes it block by block while later
+    blocks still read their halos."""
+    def aval(ref):
+        kind, v = ref
+        return lin.nodes[v].aval if kind == _REF_NODE else lin.leaves[v]
+
+    def word(a, or_bool=False) -> bool:
+        return (a.dtype.itemsize == 4 and a.dtype.kind in "fiu") or (or_bool and a.dtype == bool)
+
+    target = lin.spec[s][1][0]
+    full = tuple(aval(target).shape)
+    if len(full) != 2 or not word(aval(target)):
+        return None
+    nodes = sorted(tree)  # (operands before the node that reads them: `_Linear`'s order)
+    sources, reads, scalars, steps = [], [], [], []
+    for i in nodes:
+        fn, refs, kwargs = lin.spec[i]
+        if not word(lin.nodes[i].aval, or_bool=True):
+            return None
+        if tree[i] is not None:  # a read
+            source, slot = refs[0], None
+            if source == target:
+                if any(tree[i]):
+                    return None
+            else:
+                if source not in sources:
+                    if not word(aval(source)):
+                        return None
+                    sources.append(source)
+                    reads.append(set())
+                slot = sources.index(source)
+                reads[slot].add(tree[i])
+            steps.append((None, slot, tree[i]))
+            continue
+        operands = []
+        for ref in refs:
+            if ref[0] == _REF_STATIC:
+                operands.append(("static", type(ref[1]), ref[1]))
+            elif ref[0] == _REF_NODE and ref[1] in tree:
+                operands.append(("tile", None, nodes.index(ref[1])))
+            else:
+                if not word(aval(ref)) or getattr(aval(ref), "weak_type", False):
+                    return None
+                if ref not in scalars:
+                    scalars.append(ref)
+                operands.append(("scalar", None, scalars.index(ref)))
+        steps.append((fn, tuple(operands), tuple(sorted(kwargs.items()))))
+    rows = stencil.block_rows(full, reads)
+    if rows is None:
+        return None
+    expression = _Expression(tuple(steps))
+    try:
+        hash(expression)
+    except TypeError:  # a static that does not hash
+        return None
+    return _Kernel(tuple(nodes), target, tuple(sources), tuple(tuple(sorted(r)) for r in reads),
+                   tuple(scalars), expression, rows)
+
+
+def _full_shape_plan(lin: _Linear, out_indices: list[int], platform: str = ""):
     """Which window stores of `lin` are computed over their array's full,
-    aligned shape, and which window reads are shifted for them:
+    aligned shape, which window reads are shifted for them, and which of the
+    stores run as a kernel on `platform`:
     ({index of a getitem: its origin less the store's}, {index of a setitem:
-    (the window's starts, its sizes)}).
+    (the window's starts, its sizes)}, {index of a setitem: its `_Kernel`}).
 
     XLA writes `a[1:-1, 1:-1] = f(b[...])` as a stencil into a window-shaped
     temporary and a `dynamic-update-slice` of it at an offset the tiling does
@@ -583,7 +752,11 @@ def _full_shape_plan(lin: _Linear, out_indices: list[int]):
     shape, the element-wise operators (ELEMENTWISE_OPS) over them, python
     scalars and 0-d arrays; and nothing but the store reads any node of that
     expression (a view the user holds, or another consumer, needs the window
-    itself). Every other store and read keeps `setitem_op` / `getitem_op`."""
+    itself). Every other store and read keeps `setitem_op` / `getitem_op`.
+
+    Of two lowerings of such a store the kernel is taken where the program
+    runs on a TPU and `_kernel_plan` accepts the store; everywhere else the
+    select is."""
     spec = lin.spec
     avals = [node.aval for node in lin.nodes]
 
@@ -631,6 +804,7 @@ def _full_shape_plan(lin: _Linear, out_indices: list[int]):
 
     shifts: dict[int, tuple] = {}
     stores: dict[int, tuple] = {}
+    kernels: dict[int, _Kernel] = {}
     for s, (fn, refs, _) in enumerate(spec):
         if fn is not setitem_op or refs[1][0] != _REF_NODE:
             continue
@@ -649,7 +823,15 @@ def _full_shape_plan(lin: _Linear, out_indices: list[int]):
         if all(readers[i] == read_here[i] for i in tree):
             shifts.update((i, shift) for i, shift in tree.items() if shift is not None)
             stores[s] = window
-    return shifts, stores
+            kernel = _kernel_plan(lin, s, tree) if platform == "tpu" else None
+            if kernel is not None:
+                kernels[s] = kernel
+    return shifts, stores, kernels
+
+
+# The kernel's builder, under a name of this module: a test on the CPU puts
+# `functools.partial(stencil.window_store, interpret=True)` here.
+window_store = stencil.window_store
 
 
 def _shifted(arr, shift):
@@ -675,16 +857,28 @@ def _select_window(arr, value, starts, sizes):
     return value if mask is None else jnp.where(mask, value, arr)
 
 
-def _make_runner(spec, out_indices, shifts, stores):
+def _make_runner(spec, out_indices, shifts, stores, kernels):
+    in_a_kernel = {i for kernel in kernels.values() for i in kernel.nodes}
+
     def run(*leaves):
         vals = []
+
+        def value_of(ref):
+            kind, v = ref
+            return vals[v] if kind == _REF_NODE else leaves[v]
+
         for i, (fn, refs, kwargs) in enumerate(spec):
-            args = [
-                vals[v] if kind == _REF_NODE
-                else leaves[v] if kind == _REF_LEAF
-                else v
-                for kind, v in refs
-            ]
+            if i in in_a_kernel:  # evaluated strip by strip, inside its store's kernel
+                vals.append(None)
+                continue
+            if i in kernels:
+                kernel = kernels[i]
+                vals.append(window_store(
+                    value_of(kernel.target), [value_of(ref) for ref in kernel.sources], kernel.reads,
+                    [value_of(ref) for ref in kernel.scalars], kernel.expression, *stores[i],
+                    kernel.block_rows, target_read=kernel.expression.reads_target))
+                continue
+            args = [v if kind == _REF_STATIC else value_of((kind, v)) for kind, v in refs]
             if i in shifts:
                 vals.append(_shifted(args[0], shifts[i]))
             elif i in stores:
@@ -709,6 +903,22 @@ def materialize_all(roots: list[Node]) -> list[jax.Array]:
         return [root.value for root in roots]
 
 
+def _platform(leaves) -> str:
+    """Where a program over `leaves` runs: the platform of the one device each
+    of its arrays lives on, jax's default where it has none (a program that
+    creates its arrays), "" where they disagree or one is sharded."""
+    platforms = set()
+    for leaf in leaves:
+        if isinstance(leaf, jax.Array):
+            devices = leaf.devices()
+            if len(devices) != 1:
+                return ""
+            platforms.update(d.platform for d in devices)
+    if not platforms:
+        return jax.default_backend()
+    return platforms.pop() if len(platforms) == 1 else ""
+
+
 def _run(roots: list[Node]) -> None:
     lin = _Linear(roots)
     # Which values come back shapes the compiled output tuple, and which
@@ -716,16 +926,22 @@ def _run(roots: list[Node]) -> None:
     out_indices = _outputs(lin, roots)
     donated = _donatable(lin, out_indices)
     key = (lin.key, tuple(out_indices), tuple(donated))
+
+    def build(platform: str):
+        shifts, stores, kernels = _full_shape_plan(lin, out_indices, platform)
+        returned = _paired(lin, out_indices, donated)
+        runner = jax.jit(_make_runner(lin.spec, returned, shifts, stores, kernels),
+                         donate_argnums=tuple(donated))
+        _exec_cache[key] = (runner, returned, len(stores), len(kernels))
+        return _exec_cache[key]
+
     cached = _exec_cache.get(key)
     if cached is None:
         if len(_exec_cache) >= _CACHE_LIMIT:
             _exec_cache.clear()
         counters.exec_cache_misses += 1
-        shifts, stores = _full_shape_plan(lin, out_indices)
-        runner = jax.jit(_make_runner(lin.spec, out_indices, shifts, stores),
-                         donate_argnums=tuple(donated))
-        cached = _exec_cache[key] = (runner, len(stores))
-    runner, aligned_stores = cached
+        cached = build(_platform(lin.leaves))
+    runner, returned, aligned_stores, kernel_stores = cached
     leaves = []
     for leaf in lin.leaves:
         if not isinstance(leaf, jax.Array):
@@ -738,9 +954,20 @@ def _run(roots: list[Node]) -> None:
     counters.aligned_stores += aligned_stores
     called = time.perf_counter()
     with jax.profiler.TraceAnnotation("shim.materialize"), precision_scope():
-        outs = runner(*leaves)
+        try:
+            outs = runner(*leaves)
+        except Exception:  # noqa: BLE001 — whatever a kernel's build or the chip's compiler raised
+            if not kernel_stores or any(leaf.is_deleted() for leaf in leaves):
+                raise
+            # A kernel never fails a turn: the program again with every store
+            # as the select it was, from now on (an error of the program's own
+            # comes back from that one too).
+            logger.warning("a window store's kernel was refused; the select form runs", exc_info=True)
+            runner, returned, _, kernel_stores = build("")
+            outs = runner(*leaves)
+    counters.kernel_stores += kernel_stores
     counters.leave_out(time.perf_counter() - called)
-    for i, value in zip(out_indices, outs):
+    for i, value in zip(returned, outs):
         node = lin.nodes[i]
         for owner in node.live_owners():
             owner._concrete = value

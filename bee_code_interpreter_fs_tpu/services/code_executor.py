@@ -148,6 +148,7 @@ SHIM_PHASES = {
     "h2d_bytes": "shim_h2d_bytes",
     "donated_bytes": "shim_donated_bytes",
     "aligned_stores": "shim_aligned_stores",
+    "kernel_stores": "shim_kernel_stores",
     "fallbacks": "shim_fallbacks",
     "host_s": "shim_host",
 }

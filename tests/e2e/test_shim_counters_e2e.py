@@ -1,6 +1,6 @@
 """The numpy shim's counters end to end (ISSUE 31): a `/v1/execute` of array
 code through the HTTP API, the real C++ executor and a warm runner that has
-the shim installed comes back with the nine `shim_*` keys in
+the shim installed comes back with the ten `shim_*` keys in
 `Result.phases`; the next turn, on the sandbox that `/reset` put back, reads
 0 where the shim did nothing; and no histogram observes any of them. A
 sandbox without the shim (the no-JAX plumbing mode) stamps none. Nothing

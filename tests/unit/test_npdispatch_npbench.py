@@ -4,14 +4,16 @@ against stock numpy, every output array; `np.fromfunction` / `np.indices`
 built on the device; dead leaves donated, held ones never; and the counters
 of what the shim did. Nothing here times anything."""
 
+import functools
 import gc
 
 import jax
+import ml_dtypes
 import numpy as real_np
 import pytest
 
 from bee_code_interpreter_fs_tpu.ops import npdispatch
-from bee_code_interpreter_fs_tpu.ops.npdispatch import lazy
+from bee_code_interpreter_fs_tpu.ops.npdispatch import lazy, stencil
 from bee_code_interpreter_fs_tpu.ops.npdispatch.shim import TpuArray
 
 THRESHOLD = 1000
@@ -374,14 +376,267 @@ def test_a_full_shape_store_leaves_no_scatter_and_no_window_in_the_program(np_sh
     B[1:-1, 1:-1] = 0.25 * (A[1:-1, 1:-1] + A[1:-1, :-2] + A[1:-1, 2:] + A[2:, 1:-1] + A[:-2, 1:-1])
     lin = lazy._Linear([B._node])
     out = [len(lin.spec) - 1]
-    shifts, stores = _full_shape_plan(lin, out)
+    shifts, stores, kernels = _full_shape_plan(lin, out)
+    assert kernels == {}  # no TPU named: the select, as on any CPU
     assert sorted(shifts.values()) == [(-1, 0), (0, -1), (0, 0), (0, 1), (1, 0)]
     assert list(stores.values()) == [((1, 1), (SIDE - 2, SIDE - 2))]
-    new = str(jax.make_jaxpr(_make_runner(lin.spec, out, shifts, stores))(*lin.leaves))
-    old = str(jax.make_jaxpr(_make_runner(lin.spec, out, {}, {}))(*lin.leaves))
+    new = str(jax.make_jaxpr(_make_runner(lin.spec, out, shifts, stores, {}))(*lin.leaves))
+    old = str(jax.make_jaxpr(_make_runner(lin.spec, out, {}, {}, {}))(*lin.leaves))
     assert "scatter" in old and "slice" in old and f"{SIDE - 2},{SIDE - 2}" in old
     assert "scatter" not in new and "slice" not in new and f"{SIDE - 2},{SIDE - 2}" not in new
     assert new.count(" pad[") == 4 and "select_n" in new
+
+
+# -- a window store as one kernel ----------------------------------------------------
+
+ROWS, LANES = 64, 384  # whole registers, four row blocks of 16 rows, and a row of more than its two edges
+
+
+def _as_on_a_tpu(patch):
+    """The plan is told its leaves live on a TPU and the kernel is built for
+    the interpreter: a CPU has neither the device nor Mosaic."""
+    patch.setattr(lazy, "_platform", lambda leaves: "tpu")
+    patch.setattr(lazy, "window_store", functools.partial(stencil.window_store, interpret=True))
+
+
+@pytest.fixture
+def as_on_a_tpu(monkeypatch):
+    _as_on_a_tpu(monkeypatch)
+
+
+def grids(np, n, shape=(ROWS, LANES), dtype="float32"):
+    return [ints(np, shape, 3 + k, dtype) for k in range(n)]
+
+
+def _k_jacobi_half_step(np):
+    A, B = grids(np, 2)
+    B[1:-1, 1:-1] = 0.25 * (A[1:-1, 1:-1] + A[1:-1, :-2] + A[1:-1, 2:] + A[2:, 1:-1] + A[:-2, 1:-1])
+    return A, B
+
+
+def _k_jacobi_two_chunks(np):
+    """A row of 2304 lanes is two chunks of 1152, with a read across their seam."""
+    A, B = grids(np, 2, (32, 2304))
+    B[1:-1, 1:-1] = 0.25 * (A[1:-1, 1:-1] + A[1:-1, :-2] + A[1:-1, 2:] + A[2:, 1:-1] + A[:-2, 1:-1])
+    return A, B
+
+
+def _k_fdtd_ey(np):
+    ey, hz = grids(np, 2)
+    fict = np.fromfunction(lambda i: i + 2, (3,), dtype="float32")
+    ey[0, :] = fict[1]  # no window store: a row in place, as ever
+    ey[1:, :] -= 0.5 * (hz[1:, :] - hz[:-1, :])
+    return ey, hz
+
+
+def _k_fdtd_ex(np):
+    ex, hz = grids(np, 2)
+    ex[:, 1:] -= 0.5 * (hz[:, 1:] - hz[:, :-1])
+    return ex, hz
+
+
+def _k_fdtd_hz(np):
+    ex, ey, hz = grids(np, 3)
+    hz[:-1, :-1] -= 0.75 * (ex[:-1, 1:] - ex[:-1, :-1] + ey[1:, :-1] - ey[:-1, :-1])
+    return ex, ey, hz
+
+
+def _k_origins(np):
+    """Windows whose origins are (1, 1), (1, 0), (0, 1) and (0, 0), each read
+    across a corner, and two as far as the halo reaches."""
+    a, b, c, d, e, f, g = grids(np, 7)
+    b[1:, 1:] = a[1:, 1:] - 0.5 * a[:-1, :-1]
+    c[1:, :-1] = a[1:, :-1] - 0.5 * a[:-1, 1:]
+    d[:-1, 1:] = a[:-1, 1:] - 0.5 * a[1:, :-1]
+    e[:-1, :-1] = a[:-1, :-1] - 0.5 * a[1:, 1:]
+    f[8:-8, :] = a[:-16, :] + a[16:, :]
+    g[:, 128:] = a[:, 128:] - a[:, :-128]
+    return b, c, d, e, f, g
+
+
+def _k_scalars(np):
+    """A python scalar, a numpy scalar and two 0-d arrays (one of integers) among the operands."""
+    a, b = grids(np, 2)
+    k, m = grids(np, 2, dtype="int32")
+    b[1:-1, 1:-1] = a[2, 3] * a[1:-1, 1:-1] + np.float32(0.5) * a[2:, 1:-1] - 2.0
+    m[1:, 1:] = k[:-1, 1:] * k[3, 4] + (k[1:, :-1] << 1)
+    return b, m
+
+
+def _k_two_steps_donated(np):
+    A, B = grids(np, 2)
+    assert float(A[0, 1]) == 4.0 and float(B[0, 1]) == 5.0  # both computed: leaves of the next program
+    for _ in range(2):
+        B[1:-1, 1:-1] = 0.25 * (A[1:-1, 1:-1] + A[1:-1, :-2] + A[1:-1, 2:] + A[2:, 1:-1] + A[:-2, 1:-1])
+        A[1:-1, 1:-1] = 0.25 * (B[1:-1, 1:-1] + B[1:-1, :-2] + B[1:-1, 2:] + B[2:, 1:-1] + B[:-2, 1:-1])
+    return A, B
+
+
+# name: (the statements, the window stores among them)
+KERNEL_STORES = {
+    "jacobi half-step": (_k_jacobi_half_step, 1), "jacobi over two chunks of lanes": (_k_jacobi_two_chunks, 1),
+    "fdtd ey": (_k_fdtd_ey, 1), "fdtd ex": (_k_fdtd_ex, 1),
+    "fdtd hz": (_k_fdtd_hz, 1), "four origins and the halo's reach": (_k_origins, 6),
+    "scalars and 0-d arrays": (_k_scalars, 2), "two steps, both grids donated": (_k_two_steps_donated, 4),
+}
+
+
+def _run_statements(np, statements):
+    lazy._exec_cache.clear()
+    lazy.counters.reset()
+    got = [real_np.asarray(x) for x in statements(np)]
+    return got, lazy.counters.take()
+
+
+@pytest.mark.parametrize("name", sorted(KERNEL_STORES))
+def test_a_window_store_as_a_kernel_equals_the_select_and_stock_numpy(name, np_shim, monkeypatch):
+    """The two lowerings of a planned store and stock numpy, value for value:
+    the kernel (interpreted here) evaluates the expression's own operators in
+    their own order on strips; the counters say which lowering ran."""
+    statements, n_stores = KERNEL_STORES[name]
+    want = [real_np.asarray(x) for x in statements(real_np)]
+    as_select, select_counts = _run_statements(np_shim, statements)
+    with monkeypatch.context() as patch:
+        _as_on_a_tpu(patch)
+        as_kernel, kernel_counts = _run_statements(np_shim, statements)
+        # the same statements again: the same programs, counted per execution
+        lazy.counters.reset()
+        again = [real_np.asarray(x) for x in statements(np_shim)]
+        assert lazy.counters.take()["kernel_stores"] == n_stores
+    for k, s, a, w in zip(as_kernel, as_select, again, want, strict=True):
+        assert k.dtype == w.dtype and real_np.array_equal(k, w) and real_np.array_equal(s, w)
+        assert real_np.array_equal(a, w)
+    assert (select_counts["aligned_stores"], select_counts["kernel_stores"]) == (n_stores, 0)
+    assert (kernel_counts["aligned_stores"], kernel_counts["kernel_stores"]) == (n_stores, n_stores)
+    assert kernel_counts["fallbacks"] == 0 and kernel_counts["programs"] == select_counts["programs"]
+    if name == "two steps, both grids donated":
+        assert kernel_counts["donated_bytes"] == select_counts["donated_bytes"] == 2 * 4 * ROWS * LANES
+
+
+def _not_rank_1(np):
+    a = ints(np, (ROWS * LANES,))
+    a[1:-1] = 0.5 * (a[:-2] + a[2:])
+    return (a,)
+
+
+def _not_rank_3(np):
+    a, b = grids(np, 2, (8, 16, 128))
+    b[1:-1, 1:-1, 1:-1] = 0.25 * (a[2:, 1:-1, 1:-1] + a[:-2, 1:-1, 1:-1] + a[1:-1, 2:, 1:-1])
+    return (b,)
+
+
+def _stencil_of(dtype, shape=(ROWS, LANES)):
+    def statements(np):
+        a, b = (x.astype(dtype) for x in grids(np, 2, shape))
+        b[1:-1, 1:-1] = a[1:-1, 1:-1] + a[2:, 1:-1] + a[1:-1, :-2]
+        return (b,)
+    return statements
+
+
+def _not_float64(np):
+    with jax.enable_x64(True):
+        return (real_np.asarray(_stencil_of("float64")(np)[0]),)
+
+
+def _not_a_shift_beyond_the_halo(np):
+    a, b = grids(np, 2)
+    b[9:, :] = a[9:, :] + a[:-9, :]
+    c, d = grids(np, 2, (ROWS, 4 * LANES))
+    d[:, 129:] = c[:, 129:] + c[:, :-129]
+    return b, d
+
+
+def _not_the_target_at_a_shift(np):
+    a, b = grids(np, 2)
+    a[1:-1, 1:-1] = 0.5 * (b[1:-1, 1:-1] + a[2:, 1:-1])  # reads rows the kernel would have overwritten
+    return (a,)
+
+
+def _not_a_view_still_held(np):
+    a, b = grids(np, 2)
+    view = b[1:-1, 1:-1] + b[2:, 2:]
+    a[1:-1, 1:-1] = 0.5 * view  # not even planned: the window itself is asked for
+    return a, view * 1.0
+
+
+# name: (the statements, the stores that keep the full-shape select; the rest keep the slices)
+NO_KERNEL = {
+    "rank 1": (_not_rank_1, 1), "rank 3": (_not_rank_3, 1), "float64": (_not_float64, 1),
+    "int8": (_stencil_of("int8"), 1), "bfloat16": (_stencil_of(ml_dtypes.bfloat16), 1),
+    "a last dimension of 192": (_stencil_of("float32", (ROWS, 192)), 1),
+    "rows that are no whole registers": (_stencil_of("float32", (60, LANES)), 1),
+    "too few rows for four blocks": (_stencil_of("float32", (24, LANES)), 1),
+    "a shift beyond the halo": (_not_a_shift_beyond_the_halo, 2),
+    "the target read at a shift": (_not_the_target_at_a_shift, 1),
+    "a view the user holds": (_not_a_view_still_held, 0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(NO_KERNEL))
+def test_a_store_the_kernel_does_not_take_keeps_the_select(name, np_shim, as_on_a_tpu):
+    """Each thing `_kernel_plan` and `stencil.block_rows` refuse, on what would
+    be a TPU: the values are stock numpy's and no store ran as a kernel."""
+    statements, selects = NO_KERNEL[name]
+    want = [real_np.asarray(x) for x in statements(real_np)]
+    got, counts = _run_statements(np_shim, statements)
+    for g, w in zip(got, want, strict=True):
+        assert g.dtype == w.dtype and real_np.array_equal(g, w)
+    assert (counts["aligned_stores"], counts["kernel_stores"], counts["fallbacks"]) == (selects, 0, 0)
+
+
+def test_a_kernel_that_is_refused_never_fails_the_turn(np_shim, monkeypatch, caplog):
+    """On a CPU Mosaic is not there to compile the kernel: the program is
+    built again with every store as a select, runs, and is the runner from
+    then on; nothing is counted as a kernel store."""
+    monkeypatch.setattr(lazy, "_platform", lambda leaves: "tpu")  # and no interpreter
+    want = [real_np.asarray(x) for x in _k_two_steps_donated(real_np)]
+    for misses in (3, 0):  # each grid's creation and the steps; then all from the cache
+        if misses:
+            lazy._exec_cache.clear()
+        lazy.counters.reset()
+        got = [real_np.asarray(x) for x in _k_two_steps_donated(np_shim)]
+        counts = lazy.counters.take()
+        assert all(real_np.array_equal(g, w) for g, w in zip(got, want, strict=True))
+        assert (counts["aligned_stores"], counts["kernel_stores"]) == (4, 0)
+        assert counts["exec_cache_misses"] == misses and counts["donated_bytes"] == 2 * 4 * ROWS * LANES
+    assert sum("kernel was refused" in r.message for r in caplog.records) == 1
+
+
+def test_a_donated_grid_is_paired_with_the_output_stored_into_it(np_shim):
+    """fdtd's three fields, asked for in another order than they were made
+    (the payload prints hz first): each is updated in ITS buffer. jax pairs
+    donated arguments with outputs of their shape by position, so `_paired`
+    returns the outputs in the leaves' order; unpaired, every field would be
+    written into another's buffer, which an in-place kernel pays with a copy
+    of the grid on the way in and one on the way out."""
+    ex, ey, hz = grids(np_shim, 3)
+    fields = {"ex": ex, "ey": ey, "hz": hz}
+    for field in fields.values():
+        float(field[0, 1])  # computed: a leaf of the next program
+    before = {name: field._concrete.unsafe_buffer_pointer() for name, field in fields.items()}
+    for _ in range(2):
+        ey[1:, :] -= 0.5 * (hz[1:, :] - hz[:-1, :])
+        ex[:, 1:] -= 0.5 * (hz[:, 1:] - hz[:, :-1])
+        hz[:-1, :-1] -= 0.75 * (ex[:-1, 1:] - ex[:-1, :-1] + ey[1:, :-1] - ey[:-1, :-1])
+    float(hz[0, 1])  # one program computes all three
+    assert {name: field._concrete.unsafe_buffer_pointer() for name, field in fields.items()} == before
+    assert lazy.counters.take()["donated_bytes"] == 3 * 4 * ROWS * LANES
+
+
+def test_the_kernels_blocks_fit_the_memory_they_state():
+    """`block_rows` from the shapes alone: the run's grids get the largest
+    divisor under the limits, and every block it names fits `VMEM_LIMIT_BYTES`
+    with the pipeline's second buffer."""
+    jacobi = [{(0, 0), (0, -1), (0, 1), (1, 0), (-1, 0)}]
+    assert stencil.block_rows((24576, 24576), jacobi) == 128
+    assert stencil.block_rows((17920, 23296), [{(0, 1), (0, 0)}, {(1, 0), (0, 0)}]) == 80  # fdtd's hz
+    assert stencil.block_rows((17920, 23296), [{(0, 0), (-1, 0)}]) == 128
+    assert stencil.block_rows((ROWS, LANES), jacobi) == 16
+    assert stencil.block_rows((32, 2**20), jacobi) is None  # a block of 8 rows is 32 MiB
+    for shape, reads in (((24576, 24576), jacobi), ((17920, 23296), [{(0, 1)}, {(1, 0)}])):
+        rows = stencil.block_rows(shape, reads)
+        blocks = 4 + 3 * len(reads)
+        assert blocks * rows * (shape[1] + 256) * 4 + 4 * 8 * shape[1] * 4 <= stencil.VMEM_LIMIT_BYTES
 
 
 # -- creation from index grids -------------------------------------------------
@@ -765,7 +1020,7 @@ def test_counters_of_a_hand_made_graph(np_shim):
     # (the shipped copy of `host` is dead after the add, and c has its shape)
     assert taken == {"programs": 1, "exec_cache_misses": 1, "nodes": 4, "flushes": 0,
                      "h2d_bytes": host.nbytes, "donated_bytes": host.nbytes, "aligned_stores": 0,
-                     "fallbacks": 0, "host_s": taken["host_s"]}
+                     "kernel_stores": 0, "fallbacks": 0, "host_s": taken["host_s"]}
     assert 0.0 < taken["host_s"] < 60.0
     # a, b and c came back as outputs: each now reads in a program of one node
     assert float(b[1]) == 2.0 and float(c[2]) == 4.0
@@ -777,6 +1032,7 @@ def test_counters_of_a_hand_made_graph(np_shim):
         assert float(a[1]) == 3.0
         taken = lazy.counters.take()
         assert (taken["aligned_stores"], taken["nodes"], taken["exec_cache_misses"]) == (1, 6, misses)
+        assert taken["kernel_stores"] == 0, "a rank-1 store, and on the CPU: the select"
     assert lazy.counters.take() == dict.fromkeys(lazy.Counters.FIELDS, 0), "taken is zeroed"
 
 
