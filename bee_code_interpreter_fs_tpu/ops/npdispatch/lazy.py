@@ -110,8 +110,15 @@ class Counters:
                        cache at its limit shows here as the misses that follow)
     nodes              nodes those programs executed
     flushes            materializations forced by MAX_GRAPH_NODES
-    h2d_bytes          host arrays shipped to the device by `jnp.asarray` in
-                       `build_node` and `materialize`
+    h2d_arrays         host arrays shipped to the device, each copy one: by
+                       `ship`, the one place the shim makes such a copy (an
+                       array placed when it is read from a file, an ndarray
+                       operand of a node or of an eager call, alone or inside
+                       a list or tuple)
+    h2d_bytes          the bytes of those copies, as they lie on the device
+    h2d_s              seconds inside those copies, until the runtime has taken
+                       the bytes: the host's part. The copy over the link goes
+                       on behind it and shows in the program that waits for it
     donated_bytes      leaves donated to the program that consumed them
     aligned_stores     window stores (`a[1:-1, 1:-1] = f(b[...])`) that a
                        program executed over the array's full shape
@@ -128,23 +135,23 @@ class Counters:
                        of the compiled runner left out
     """
 
-    FIELDS = ("programs", "exec_cache_misses", "nodes", "flushes", "h2d_bytes",
-              "donated_bytes", "aligned_stores", "kernel_stores", "fallbacks", "host_s")
+    FIELDS = ("programs", "exec_cache_misses", "nodes", "flushes", "h2d_arrays", "h2d_bytes",
+              "h2d_s", "donated_bytes", "aligned_stores", "kernel_stores", "fallbacks", "host_s")
 
     def __init__(self) -> None:
         self.reset()
 
     def reset(self) -> None:
         self.programs = self.exec_cache_misses = self.nodes = self.flushes = 0
-        self.h2d_bytes = self.donated_bytes = self.aligned_stores = self.kernel_stores = 0
-        self.fallbacks = 0
-        self.host_s = 0.0
+        self.h2d_arrays = self.h2d_bytes = self.donated_bytes = 0
+        self.aligned_stores = self.kernel_stores = self.fallbacks = 0
+        self.h2d_s = self.host_s = 0.0
         self._depth = 0  # build_node -> flush -> materialize nest: count once
         self._entered = self._outside = 0.0
 
     def take(self) -> dict:
         taken = {name: getattr(self, name) for name in self.FIELDS}
-        taken["host_s"] = round(taken["host_s"], 6)
+        taken["h2d_s"], taken["host_s"] = round(taken["h2d_s"], 6), round(taken["host_s"], 6)
         self.reset()
         return taken
 
@@ -165,6 +172,20 @@ class Counters:
 
 
 counters = Counters()
+
+
+def ship(host, dtype=None) -> jax.Array:
+    """`host`, an ndarray, as an array on the device: the one place the shim
+    copies from the host, so that every copy is counted, timed and, inside a
+    capture, seen (`shim.h2d`, a no-op outside one). Raises what `jnp.asarray`
+    raises of a dtype the device has none for."""
+    started = time.perf_counter()
+    with jax.profiler.TraceAnnotation("shim.h2d"):
+        shipped = jnp.asarray(host, dtype=dtype)
+    counters.h2d_arrays += 1
+    counters.h2d_bytes += shipped.nbytes
+    counters.h2d_s += time.perf_counter() - started
+    return shipped
 
 
 class Node:
@@ -363,10 +384,9 @@ def _build_node(op_name: str, fn: Callable, args, kwargs) -> Node | None:
             snapshot = host_memo.get(id(value))
             if snapshot is None:
                 try:
-                    snapshot = host_memo[id(value)] = jnp.asarray(value)
+                    snapshot = host_memo[id(value)] = ship(value)
                 except (TypeError, ValueError):
                     return None  # e.g. object dtype: run eagerly instead
-                counters.h2d_bytes += value.nbytes
             arg_refs[i] = (_REF_LEAF, snapshot)
     return Node(op_name, fn, arg_refs, kwargs, aval, n_nodes)
 
@@ -944,10 +964,7 @@ def _run(roots: list[Node]) -> None:
     runner, returned, aligned_stores, kernel_stores = cached
     leaves = []
     for leaf in lin.leaves:
-        if not isinstance(leaf, jax.Array):
-            counters.h2d_bytes += leaf.nbytes
-            leaf = jnp.asarray(leaf)
-        leaves.append(leaf)
+        leaves.append(leaf if isinstance(leaf, jax.Array) else ship(leaf))
     counters.programs += 1
     counters.nodes += len(lin.spec)
     counters.donated_bytes += sum(leaves[li].nbytes for li in donated)

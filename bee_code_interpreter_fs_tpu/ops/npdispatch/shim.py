@@ -219,9 +219,16 @@ def _result_wrap(value):
 
 
 def _unwrap_jnp(value):
-    """Convert shim-level values into jnp-compatible ones (forces lazy)."""
+    """Convert shim-level values into jnp-compatible ones (forces lazy). A host
+    ndarray, alone or inside a list or tuple, is shipped here and counted; one
+    the device has no dtype for goes on as it is, for the function to refuse."""
     if isinstance(value, TpuArray):
         return value._arr
+    if isinstance(value, real_np.ndarray):
+        try:
+            return lazy.ship(value)
+        except (TypeError, ValueError):
+            return value
     if isinstance(value, (tuple, list)):
         return type(value)(_unwrap_jnp(v) for v in value)
     return value
@@ -311,6 +318,8 @@ class TpuArray:
             self._set_node(arr)
         elif isinstance(arr, jax.Array):
             self._concrete = arr
+        elif isinstance(arr, real_np.ndarray):
+            self._concrete = lazy.ship(arr)
         else:
             self._concrete = jnp.asarray(arr)
 
@@ -558,6 +567,9 @@ class TpuArray:
     def tobytes(self, order="C"):
         return real_np.asarray(self._arr).tobytes(order)
 
+    def tofile(self, fid, sep="", format="%s"):
+        real_np.asarray(self._arr).tofile(fid, sep=sep, format=format)
+
     def fill(self, value):
         self.__setitem__(Ellipsis, value)
 
@@ -773,6 +785,17 @@ _EAGER_ONLY = {"allclose", "array_equal", "histogram", "meshgrid", "unique",
                "split", "array_split"}
 
 
+def _concatenate_arrays(*arrays, **kwargs):
+    """`np.concatenate([np.fromfile(p) for p in shards])` as an op of the lazy
+    graph, which takes each array as an operand of its own: the shards, the
+    join and what is computed from it are one program, and the joined array is
+    written once."""
+    return jnp.concatenate(list(arrays), **kwargs)
+
+
+_ARRAYS = (TpuArray, real_np.ndarray, jax.Array)
+
+
 def _operand_size(value) -> int:
     if isinstance(value, real_np.ndarray):
         return int(value.size)
@@ -807,6 +830,7 @@ class _Dispatcher:
         self.threshold = threshold
         self.kind = kind
         self.lazy_ok = name.rsplit(".", 1)[-1] not in _EAGER_ONLY
+        self.joins_arrays = name == "concatenate"
         self.__name__ = getattr(np_fn, "__name__", name.rsplit(".", 1)[-1])
         self.__qualname__ = self.__name__
         self.__doc__ = getattr(np_fn, "__doc__", None)
@@ -866,7 +890,12 @@ class _Dispatcher:
             # precision policy (explicit, warned once at install — not jax's
             # silent per-call truncation).
             args, kwargs = _canonicalize_dtype_args(args, kwargs)
-            result = try_lazy(self.name, self.jnp_fn, args, kwargs) if self.lazy_ok else None
+            result = None
+            arrays = args[0] if self.joins_arrays and len(args) == 1 else None
+            if isinstance(arrays, (list, tuple)) and arrays and all(isinstance(a, _ARRAYS) for a in arrays):
+                result = try_lazy(f"{self.name}.arrays", _concatenate_arrays, tuple(arrays), kwargs)
+            elif self.lazy_ok:
+                result = try_lazy(self.name, self.jnp_fn, args, kwargs)
             if result is None:
                 result = eager_device(self.jnp_fn, args, kwargs)
             if result is not NotImplemented:
@@ -944,6 +973,56 @@ def _grid_overrides(threshold: int) -> dict[str, Callable]:
     return {"indices": indices, "fromfunction": fromfunction}
 
 
+def _placed(host, threshold: int):
+    """An array that was just read from a file or a buffer, where it lives
+    from now on: at or over the dispatch threshold a device-resident
+    `TpuArray`, shipped ONCE, here, however often it is used afterwards
+    (an ndarray operand is shipped again by every call that takes it), so
+    that the operators on it build the lazy graph and nothing of its size
+    runs in host numpy. Decided from its size and its dtype, as
+    `_grid_dtype` decides for a grid: under the threshold, and for what the
+    device could not hold as numpy does (int64 / uint64 under the integer
+    policy; object, string and structured dtypes; a memmap, which the caller
+    asked to leave in the file), stock numpy's own array. float64 and
+    complex128 are held in 32 bits and say so once (the float policy). The
+    host's copy is let go as soon as the runtime has taken the bytes."""
+    if type(host) is not real_np.ndarray or host.size < threshold or host.dtype.kind not in "biufc":
+        return host
+    if host.dtype.name in _WIDE_INT_NAMES and not _x64_enabled():
+        _announce_policy_once()
+        return host
+    try:
+        return TpuArray(lazy.ship(host, canonical_dtype(host.dtype)))
+    except (TypeError, ValueError):
+        return host
+
+
+def _load_overrides(threshold: int) -> dict[str, Callable]:
+    """`np.fromfile`, `np.load` and `np.frombuffer`: stock numpy reads, and
+    the array read is `_placed`. (`np.load` of an `.npz` gives numpy's own
+    lazy archive, whose members are read by numpy when they are asked for.)
+    `np.frombuffer` over memory that can still be written (a bytearray, an
+    mmap, shared memory) stays numpy's view of it: a write on either side is
+    seen on the other, which a device array could not give. Over `bytes` or a
+    read-only buffer the view is read-only too, and is placed."""
+    def placing(np_fn):
+        @functools.wraps(np_fn)
+        def load(*args, **kwargs):
+            return _placed(np_fn(*args, **kwargs), threshold)
+        return load
+
+    @functools.wraps(real_np.frombuffer)
+    def frombuffer(*args, **kwargs):
+        view = real_np.frombuffer(*args, **kwargs)
+        return view if view.flags.writeable else _placed(view, threshold)
+
+    return {
+        "fromfile": placing(real_np.fromfile),
+        "load": placing(real_np.load),
+        "frombuffer": frombuffer,
+    }
+
+
 class _SubmoduleShim(types.ModuleType):
     """Proxy for numpy.linalg / numpy.fft: jnp first for device arrays."""
 
@@ -1001,6 +1080,7 @@ class _NumpyShim(types.ModuleType):
                 name, np_fn, getattr(jnp, name, None), threshold, kind="compute"
             )
         self._overrides.update(_grid_overrides(threshold))
+        self._overrides.update(_load_overrides(threshold))
         from .random import RandomShim
 
         self._overrides["random"] = RandomShim(threshold)

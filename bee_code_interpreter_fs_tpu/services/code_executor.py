@@ -138,14 +138,16 @@ STAGE_PHASES = (
 # What the numpy shim counted during the turn's user code (npdispatch's
 # `lazy.Counters`, taken by the warm runner), stamped into Result.phases on
 # every served turn of a runner that has the shim installed, 0 where it did
-# nothing. Counts and bytes, and one duration (`shim_host`, seconds): none is
-# in LATENCY_PHASES, so the histogram sees none of them.
+# nothing. Counts and bytes, and two durations (`shim_host` and `shim_h2d`,
+# seconds): none is in LATENCY_PHASES, so the histogram sees none of them.
 SHIM_PHASES = {
     "programs": "shim_programs",
     "exec_cache_misses": "shim_exec_cache_misses",
     "nodes": "shim_nodes",
     "flushes": "shim_flushes",
+    "h2d_arrays": "shim_h2d_arrays",
     "h2d_bytes": "shim_h2d_bytes",
+    "h2d_s": "shim_h2d",
     "donated_bytes": "shim_donated_bytes",
     "aligned_stores": "shim_aligned_stores",
     "kernel_stores": "shim_kernel_stores",
