@@ -114,7 +114,9 @@ class Counters:
                        `ship`, the one place the shim makes such a copy (an
                        array placed when it is read from a file, an ndarray
                        operand of a node or of an eager call, alone or inside
-                       a list or tuple)
+                       a list or tuple). Not the `bins + 1` edges of a
+                       histogram, which the shim makes itself and hands to
+                       its program as an operand
     h2d_bytes          the bytes of those copies, as they lie on the device
     h2d_s              seconds inside those copies, until the runtime has taken
                        the bytes: the host's part. The copy over the link goes
@@ -127,6 +129,11 @@ class Counters:
     kernel_stores      those of them that ran as one Pallas kernel, each
                        grid streamed once (`stencil.window_store`); the rest
                        ran as one select fusion
+    histograms         calls of `np.histogram` that ran as the shim's one program
+                       (`shim._histogram_program`: every element compared
+                       against numpy's own edges, the bins summed in the same
+                       program); a call that kept `jnp.histogram` or numpy is
+                       not counted
     fallbacks          calls the shim routed to the device that ran under
                        stock numpy after all (`np.fromfunction` of a function
                        a TpuArray cannot serve, a jnp function that refused
@@ -136,7 +143,8 @@ class Counters:
     """
 
     FIELDS = ("programs", "exec_cache_misses", "nodes", "flushes", "h2d_arrays", "h2d_bytes",
-              "h2d_s", "donated_bytes", "aligned_stores", "kernel_stores", "fallbacks", "host_s")
+              "h2d_s", "donated_bytes", "aligned_stores", "kernel_stores", "histograms", "fallbacks",
+              "host_s")
 
     def __init__(self) -> None:
         self.reset()
@@ -144,7 +152,7 @@ class Counters:
     def reset(self) -> None:
         self.programs = self.exec_cache_misses = self.nodes = self.flushes = 0
         self.h2d_arrays = self.h2d_bytes = self.donated_bytes = 0
-        self.aligned_stores = self.kernel_stores = self.fallbacks = 0
+        self.aligned_stores = self.kernel_stores = self.histograms = self.fallbacks = 0
         self.h2d_s = self.host_s = 0.0
         self._depth = 0  # build_node -> flush -> materialize nest: count once
         self._entered = self._outside = 0.0

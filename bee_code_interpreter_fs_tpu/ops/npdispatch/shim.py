@@ -781,6 +781,9 @@ COMPUTE_FNS = (
 
 # Functions whose results are scalars/bools used in control flow — keep eager
 # (lazy would immediately force anyway, with extra tracing overhead).
+# `histogram` is here for the calls that keep `jnp.histogram` (two results, so
+# never a node of the graph); a call `_histogram_takes` accepts never comes
+# this way: it is one compiled program of the shim's own (`_histogram_overrides`).
 _EAGER_ONLY = {"allclose", "array_equal", "histogram", "meshgrid", "unique",
                "split", "array_split"}
 
@@ -973,6 +976,136 @@ def _grid_overrides(threshold: int) -> dict[str, Callable]:
     return {"indices": indices, "fromfunction": fromfunction}
 
 
+# `np.histogram` as ONE program: every element compared against every edge in
+# the fusion that sums the bins. `jnp.histogram` finds the bins with
+# `searchsorted`'s default `scan`, a serial `while` of one gather over the
+# whole vector for each halving of the edges, and sums them with a scatter-add
+# (on a v5e, 1e7 float32 elements and 1001 edges: 0.57 s and 0.09 s; the
+# program 0.016 s: PERF.md, PR 36).
+_HISTOGRAM_BLOCK = 8192  # elements in one partial sum; the partial sums are summed in turn
+# The comparisons cost `a.size * (bins + 1)` and the scan `a.size * log2(bins + 1)`
+# gathers, so where one overtakes the other is read from the edge count alone:
+# on a v5e 1.1 ps a comparison against 5.7 ns a gather, even at 2**17 edges
+# (1e7 elements, 65,537 edges: 0.71 s against the scan's 1.36; 16,385: 0.18
+# against 1.21). Over this many bins `jnp.histogram` as before.
+_HISTOGRAM_MAX_BINS = 2 ** 16
+_HISTOGRAM_MAX_ELEMENTS = 2 ** 31  # a bin counts in int32
+
+
+@jax.jit
+def _histogram_program(a, edges, weights):
+    """The sums of `weights` (the counts, in int32, where it is None) of the
+    elements of `a` that lie in each bin `edges[j] <= x < edges[j + 1]`, a NaN
+    in none. `edges` is an operand: one executable serves every dataset of
+    a shape. Summed in blocks of `_HISTOGRAM_BLOCK` elements and then over the
+    blocks (the barrier keeps XLA from merging the two sums into one), so no
+    sum runs serially over the vector; the comparison is fused into the first
+    sum, so nothing of `a.size * bins` elements is ever written."""
+    a = a.ravel()
+    pad = -a.size % _HISTOGRAM_BLOCK
+    blocks = jnp.pad(a, (0, pad), constant_values=jnp.nan).reshape(-1, _HISTOGRAM_BLOCK, 1)
+    inside = (blocks >= edges[:-1]) & (blocks < edges[1:])
+    if weights is None:
+        partial = inside.sum(axis=1, dtype=jnp.int32)
+    else:
+        weights = jnp.pad(weights.ravel(), (0, pad)).reshape(-1, _HISTOGRAM_BLOCK, 1)
+        partial = jnp.where(inside, weights, 0).sum(axis=1)
+    return jax.lax.optimization_barrier(partial).sum(axis=0)
+
+
+def _histogram_extent(a):
+    """The least and the greatest element of `a`, as one array of two."""
+    return jnp.stack([jnp.min(a), jnp.max(a)])
+
+
+def _edges_on_device(edges, dtype):
+    """numpy's edges as the program compares against them, in the vector's own
+    float type: each the least value of that type at or over the edge, so
+    that `x >= edge` and `x < edge` read the same for every `x` of the type
+    where the edges were given in a wider one; and the last the least value
+    OVER the edge, which closes the last bin on the right as numpy does."""
+    rounded = edges.astype(dtype)
+    above = real_np.nextafter(rounded, dtype.type(real_np.inf))
+    rounded = real_np.where(rounded < edges, above, rounded)
+    rounded[-1] = rounded[-1] if rounded[-1] > edges[-1] else above[-1]
+    return rounded
+
+
+def _device_dtype(value):
+    """The dtype `value` has, or would have, on the device; None for no array."""
+    if isinstance(value, (TpuArray, jax.Array)):
+        return real_np.dtype(value.dtype)
+    if isinstance(value, real_np.ndarray):
+        return real_np.dtype(canonical_dtype(value.dtype))
+    return None
+
+
+def _histogram_takes(size: int, dtype, bins, weights_dtype) -> str:
+    """Which `np.histogram` a call runs, read from the call itself: "program",
+    the shim's own; "jnp", `jnp.histogram` as before this op existed; "numpy",
+    stock numpy on the host. `dtype` and `weights_dtype` are the device's
+    (`_device_dtype`), the latter None where the call has no weights."""
+    floats = ("float32", "float64")  # (float64 on the device only under APP_NUMPY_DISPATCH_X64)
+    if size >= _HISTOGRAM_MAX_ELEMENTS:
+        return "numpy"
+    if dtype is None or dtype.name not in floats or isinstance(bins, str):
+        return "jnp"  # an estimator's name; integers, booleans, 16-bit floats
+    if weights_dtype is not None and weights_dtype.name not in floats:
+        return "jnp"  # complex, integer and object weights
+    if isinstance(bins, (int, real_np.integer)):
+        n_edges = int(bins) + 1
+    else:
+        n_edges = len(bins) if real_np.ndim(bins) == 1 else 0
+    if not 2 <= n_edges <= _HISTOGRAM_MAX_BINS + 1:
+        return "jnp"  # (and whatever `bins` is that is neither a count nor edges)
+    return "program"
+
+
+def _histogram_overrides(todays: "_Dispatcher") -> dict[str, Callable]:
+    """`np.histogram` of a float vector on the device, exact by numpy's rule
+    on numpy's edges. For `bins` a number the vector's least and greatest
+    value come to the host (one program, eight bytes), and numpy itself makes
+    the edges from them (`np.histogram_bin_edges` over those two values:
+    its rounding, its widening of an empty range, its errors); for `bins` a
+    sequence the edges are the ones given. The edges returned are that host
+    array. The bins are filled by `_histogram_program`. `density` is numpy's
+    own expression over the bins brought to the host. What `_histogram_takes`
+    leaves, `todays` runs as it ran before."""
+    @functools.wraps(real_np.histogram)
+    def histogram(a, bins=10, range=None, density=None, weights=None):
+        call = dict(bins=bins, range=range, density=density, weights=weights)
+        if not todays._use_device((a, bins, weights), {}):
+            return todays(a, **call)
+        if isinstance(bins, _ARRAYS):
+            bins = real_np.asarray(bins)
+        if weights is not None and not isinstance(weights, _ARRAYS):
+            weights = real_np.asarray(weights)
+        dtype = _device_dtype(a)
+        route = _histogram_takes(int(getattr(a, "size", 0)), dtype, bins, _device_dtype(weights))
+        if route == "numpy":
+            lazy.counters.fallbacks += 1
+            return real_np.histogram(_unwrap_np(a), **{k: _unwrap_np(v) for k, v in call.items()})
+        if route == "jnp":
+            return todays(a, **call)
+        if weights is not None and weights.shape != a.shape:
+            raise ValueError("weights should have the same shape as a.")
+        a = a if isinstance(a, TpuArray) else TpuArray(a)
+        seen = real_np.empty(0, dtype)  # what numpy reads of the data to make its edges
+        if real_np.ndim(bins) == 0 and range is None and a.size:
+            seen = real_np.asarray(a._lazy_or_eager("histogram.extent", _histogram_extent, (a,), {}))
+        edges = real_np.histogram_bin_edges(seen, bins, range)
+        if edges.dtype.kind not in "iuf" or not real_np.isfinite(edges).all():
+            return todays(a, **call)  # an edge at infinity closes no bin in a float comparison
+        counts = _histogram_program(a._arr, _edges_on_device(edges, dtype), _unwrap_jnp(weights))
+        lazy.counters.histograms += 1
+        if density:
+            counts = real_np.asarray(counts)
+            return counts / real_np.array(real_np.diff(edges), float) / counts.sum(), edges
+        return TpuArray(counts), edges
+
+    return {"histogram": histogram}
+
+
 def _placed(host, threshold: int):
     """An array that was just read from a file or a buffer, where it lives
     from now on: at or over the dispatch threshold a device-resident
@@ -1080,6 +1213,7 @@ class _NumpyShim(types.ModuleType):
                 name, np_fn, getattr(jnp, name, None), threshold, kind="compute"
             )
         self._overrides.update(_grid_overrides(threshold))
+        self._overrides.update(_histogram_overrides(self._overrides["histogram"]))
         self._overrides.update(_load_overrides(threshold))
         from .random import RandomShim
 

@@ -151,6 +151,7 @@ SHIM_PHASES = {
     "donated_bytes": "shim_donated_bytes",
     "aligned_stores": "shim_aligned_stores",
     "kernel_stores": "shim_kernel_stores",
+    "histograms": "shim_histograms",
     "fallbacks": "shim_fallbacks",
     "host_s": "shim_host",
 }

@@ -1,6 +1,6 @@
 """The numpy shim's counters end to end (ISSUE 31): a `/v1/execute` of array
 code through the HTTP API, the real C++ executor and a warm runner that has
-the shim installed comes back with the twelve `shim_*` keys in
+the shim installed comes back with the thirteen `shim_*` keys in
 `Result.phases`; the next turn, on the sandbox that `/reset` put back, reads
 0 where the shim did nothing; and no histogram observes any of them. A
 sandbox without the shim (the no-JAX plumbing mode) stamps none. Nothing
@@ -84,12 +84,15 @@ async def test_shim_counters_of_a_served_array_turn_and_of_the_next(tmp_path):
         await executor.close()
 
 
-# a file of 2**18 float32, read and used twice: it crosses once (ISSUE 35)
+# a file of 2**18 float32, read and used three times: it crosses once (ISSUE 35);
+# its histogram is one program of the shim's own (ISSUE 36)
 FILE_TURN = (
     "import numpy as np\n"
     "x = np.fromfile('x.bin', dtype=np.float32)\n"
     "y = x * 2.0 + 1.0\n"
     "print(type(x).__name__, type(y).__name__, float(np.max(x)), float(y.sum()))\n"
+    "counts, edges = np.histogram(x, 7)\n"
+    "print(type(counts).__name__, np.asarray(counts).tolist(), type(edges).__name__, edges.tolist())\n"
 )
 
 
@@ -102,10 +105,13 @@ async def test_a_turn_over_an_uploaded_file_ships_it_once(tmp_path):
         resp = await client.post("/v1/execute", json={"source_code": FILE_TURN, "files": {"/workspace/x.bin": object_id}})
         body = await resp.json()
         assert body["exit_code"] == 0, body["stderr"]
-        assert body["stdout"] == f"TpuArray TpuArray 6.0 {float((data * 2.0 + 1.0).sum())}\n"
+        counts, edges = np.histogram(data, 7)
+        assert body["stdout"] == (f"TpuArray TpuArray 6.0 {float((data * 2.0 + 1.0).sum())}\n"
+                                  f"TpuArray {counts.tolist()} ndarray {edges.tolist()}\n")
         phases = body["phases"]
         assert phases["upload_bytes"] == phases["shim_h2d_bytes"] == float(data.nbytes)
         assert phases["shim_h2d_arrays"] == 1.0 and phases["shim_h2d"] > 0.0 and phases["shim_fallbacks"] == 0.0
+        assert phases["shim_histograms"] == 1.0
     finally:
         await client.close()
         await executor.close()

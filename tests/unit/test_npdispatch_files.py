@@ -247,7 +247,9 @@ def test_host_arrays_inside_a_list_are_counted(np_shim):
     assert (taken["h2d_arrays"], taken["h2d_bytes"]) == (2, 2 * hosts[0].nbytes)
 
 
-def test_the_eager_path_counts_what_it_ships(np_shim):
+def test_a_histogram_of_host_arrays_counts_what_it_ships(np_shim):
+    """Both operands cross once and are counted; the edges, made on the host
+    from two scalars, are an operand of the program and no array of the user's."""
     radius, data = host_array("float32") / 251, host_array("float32") + 1
     counts, edges = np_shim.histogram(radius, 16, weights=data)
     want, want_edges = real_np.histogram(radius, 16, weights=data)
@@ -255,6 +257,207 @@ def test_the_eager_path_counts_what_it_ships(np_shim):
     assert real_np.allclose(real_np.asarray(edges), want_edges, rtol=1e-6)
     taken = lazy.counters.take()
     assert (taken["h2d_arrays"], taken["h2d_bytes"], taken["fallbacks"]) == (2, radius.nbytes + data.nbytes, 0)
+    assert taken["histograms"] == 1
+
+
+# -- np.histogram: one program of the shim's own, on numpy's edges -----------------------
+
+
+def grid_floats(n=N, seed=11, scale=1.0):
+    """Seeded float32 values on a grid of 2**-24, as the benchmark's files hold them."""
+    words = real_np.random.default_rng(seed).integers(0, 2 ** 24, n, dtype=real_np.uint32)
+    return words.astype(real_np.float32) * real_np.float32(scale * 2.0 ** -24)
+
+
+def on_the_edges():
+    """Elements exactly on the first, an inner and the last edge of four bins over [0, 1]."""
+    x = grid_floats()
+    x[:6] = [0.0, 0.25, 0.5, 0.5, 1.0, 1.0]
+    return x
+
+
+# name: (the vector, what the call states besides it)
+HISTOGRAMS = {
+    "a_count_of_bins": (grid_floats, {"bins": 16}),
+    "the_default_bins": (grid_floats, {}),
+    "a_thousand_bins": (grid_floats, {"bins": 1000}),
+    "weights": (grid_floats, {"bins": 16, "weights": lambda: grid_floats(seed=12)}),
+    "a_range_inside_the_data": (grid_floats, {"bins": 10, "range": (0.2, 0.7)}),
+    "a_range_outside_the_data": (grid_floats, {"bins": 5, "range": (-1, 2)}),
+    "a_range_and_weights": (grid_floats, {"bins": 7, "range": (0.1, 0.9), "weights": lambda: grid_floats(seed=13)}),
+    "elements_on_an_inner_and_on_the_last_edge": (on_the_edges, {"bins": 4, "range": (0.0, 1.0)}),
+    "elements_on_the_edges_of_the_data": (on_the_edges, {"bins": 4}),
+    "all_elements_equal": (lambda: real_np.full(N, 0.75, real_np.float32), {"bins": 6}),
+    "two_dimensions": (lambda: grid_floats().reshape(40, -1), {"bins": 9}),
+    "two_dimensions_and_weights": (lambda: grid_floats().reshape(40, -1),
+                                   {"bins": 9, "weights": lambda: grid_floats(seed=14).reshape(40, -1)}),
+    "edges_that_are_not_uniform": (grid_floats, {"bins": [0.0, 0.1, 0.35, 0.8, 1.0]}),
+    "edges_between_two_floats": (lambda: grid_floats(scale=0.4), {"bins": real_np.linspace(0.0, 0.4, 12)}),
+    "edges_that_are_integers": (lambda: grid_floats(scale=8.0), {"bins": [0, 1, 2, 5, 8]}),
+    "edges_inside_the_data_and_weights": (grid_floats, {"bins": [0.25, 0.5, 0.75], "weights": lambda: grid_floats(seed=15)}),
+    "density": (grid_floats, {"bins": 7, "density": True}),
+    "density_over_edges_that_are_not_uniform": (grid_floats, {"bins": [0.0, 0.1, 0.35, 0.8, 1.0], "density": True}),
+    "density_and_weights": (grid_floats, {"bins": 5, "density": True, "weights": lambda: grid_floats(seed=16)}),
+    "one_block_and_a_few": (lambda: grid_floats(n=shim._HISTOGRAM_BLOCK + 7), {"bins": 3}),
+    "an_empty_array": (lambda: real_np.empty(0, real_np.float32), {"bins": 4}),
+    "an_empty_array_and_a_range": (lambda: real_np.empty(0, real_np.float32), {"bins": 4, "range": (2, 3)}),
+}
+
+
+# (an empty host array is under the threshold, and stock numpy's to count)
+@pytest.mark.parametrize("name, held", [(name, held) for name in sorted(HISTOGRAMS) for held in ("host", "device")
+                                        if held == "device" or "empty" not in name])
+def test_a_histogram_equals_stock_numpys(np_shim, name, held):
+    """Counts EQUAL to numpy's, edges bit for bit, weighted sums within the
+    rounding of a float32 sum; from a host array over the threshold and from
+    an array that lives on the device; one program a call."""
+    make, call = HISTOGRAMS[name]
+    call = {key: value() if callable(value) else value for key, value in call.items()}
+    x = make()
+    want, want_edges = real_np.histogram(x, **call)
+    mine = dict(call)
+    if held == "device":
+        x = TpuArray(x)
+        if "weights" in mine:
+            mine["weights"] = TpuArray(mine["weights"])
+    lazy.counters.reset()
+    got, edges = np_shim.histogram(x, **mine)
+    taken = lazy.counters.take()
+    assert type(edges) is real_np.ndarray and edges.dtype == want_edges.dtype and real_np.array_equal(edges, want_edges)
+    assert got.shape == want.shape
+    if call.get("density"):
+        assert type(got) is real_np.ndarray and got.dtype == want.dtype
+    else:
+        assert isinstance(got, TpuArray) and got.dtype == ("float32" if "weights" in call else "int32")
+    if "weights" in call or call.get("density"):
+        assert real_np.abs(real_np.asarray(got) - want).max() <= 2e-6 * max(real_np.abs(want).max(), 1e-30)
+    else:
+        assert real_np.array_equal(real_np.asarray(got), want)
+    assert (taken["histograms"], taken["fallbacks"]) == (1, 0)
+    wants_the_extent = "range" not in call and real_np.ndim(call.get("bins", 10)) == 0 and x.size
+    assert taken["programs"] == (1 if wants_the_extent else 0), "the least and the greatest value, where numpy reads them"
+
+
+@pytest.mark.parametrize("fault, call, error", [
+    ("a_nan_in_the_data", {"bins": 8}, "autodetected range of \\[nan, nan\\] is not finite"),
+    ("no_nan", {"bins": 8, "range": (0, real_np.inf)}, "supplied range of \\[0, inf\\] is not finite"),
+    ("no_nan", {"bins": 8, "range": (real_np.nan, 1)}, "is not finite"),
+    ("no_nan", {"bins": 8, "range": (1, 0)}, "max must be larger than min in range parameter"),
+    ("no_nan", {"bins": [0.0, 0.5, 0.25]}, "`bins` must increase monotonically, when an array"),
+    ("weights_of_another_shape", {"bins": 8}, "weights should have the same shape as a"),
+])
+def test_a_histogram_raises_what_numpy_raises(np_shim, fault, call, error):
+    x = grid_floats()
+    if fault == "a_nan_in_the_data":
+        x[17] = real_np.nan
+    if fault == "weights_of_another_shape":
+        call = dict(call, weights=grid_floats(n=N - 1))
+    with pytest.raises(ValueError, match=error):
+        real_np.histogram(x, **call)
+    with pytest.raises(ValueError, match=error):
+        np_shim.histogram(TpuArray(x), **call)
+    assert lazy.counters.take()["histograms"] == 0
+
+
+def test_a_nan_beside_a_stated_range_falls_in_no_bin(np_shim):
+    x = grid_floats()
+    x[::7] = real_np.nan
+    want, _ = real_np.histogram(x, 8, range=(0, 1))
+    got, _ = np_shim.histogram(TpuArray(x), 8, range=(0, 1))
+    assert real_np.array_equal(real_np.asarray(got), want) and want.sum() < x.size
+
+
+def test_a_bin_counts_past_the_end_of_a_float32s_integers(np_shim):
+    """`jnp.histogram` sums float32 ones and stops at 2**24; the program counts in int32."""
+    n = 2 ** 24 + 3
+    counts, edges = np_shim.histogram(np_shim.full(n, 0.75, dtype="float32"), 4)
+    assert real_np.asarray(counts).tolist() == [0, 0, n, 0] and edges.tolist() == [0.25, 0.5, 0.75, 1.0, 1.25]
+
+
+def test_two_datasets_of_one_shape_share_one_executable(np_shim):
+    """The edges are an operand, so what differs between two turns' data compiles nothing."""
+    np_shim.histogram(TpuArray(grid_floats(seed=21)), 16)
+    compiled = shim._histogram_program._cache_size()
+    np_shim.histogram(TpuArray(grid_floats(seed=22, scale=3.0)), 16)
+    assert shim._histogram_program._cache_size() == compiled
+
+
+@pytest.fixture
+def todays_histogram(monkeypatch):
+    """`jnp.histogram` as the shim's dispatcher takes it when it is installed: the calls it got."""
+    calls = []
+    inner = jax.numpy.histogram
+
+    def histogram(*args, **kwargs):
+        calls.append(kwargs)
+        return inner(*args, **kwargs)
+
+    monkeypatch.setattr(jax.numpy, "histogram", histogram)
+    return calls
+
+
+# What keeps `jnp.histogram`, op by op, as before the program existed: (the vector, the call)
+KEPT = {
+    "an_estimators_name": (grid_floats, {"bins": "auto"}),
+    "integers": (lambda: real_np.arange(N, dtype=real_np.int32) % 97, {"bins": 8}),
+    "sixteen_bit_floats": (lambda: grid_floats().astype(real_np.float16), {"bins": 8}),
+    "complex_weights": (grid_floats, {"bins": 8, "weights": lambda: grid_floats(seed=3).astype(real_np.complex64)}),
+    "integer_weights": (grid_floats, {"bins": 8, "weights": lambda: real_np.arange(N, dtype=real_np.int32) % 5}),
+    "an_edge_at_infinity": (grid_floats, {"bins": [0.0, 0.5, real_np.inf]}),
+    "no_bin": (grid_floats, {"bins": 0}),
+    "more_edges_than_comparing_against_each_saves": (grid_floats, {"bins": 64}),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KEPT))
+def test_what_the_program_does_not_take_keeps_todays_path(todays_histogram, np_shim, monkeypatch, name):
+    make, call = KEPT[name]
+    call = {key: value() if callable(value) else value for key, value in call.items()}
+    if name == "more_edges_than_comparing_against_each_saves":
+        monkeypatch.setattr(shim, "_HISTOGRAM_MAX_BINS", 63)
+    x = TpuArray(make())
+    try:
+        got = np_shim.histogram(x, **call)
+    except ValueError:
+        got = None  # (what `jnp.histogram` raises of no bin is the caller's to see, as before)
+    assert len(todays_histogram) == 1, "jnp.histogram ran, once"
+    assert lazy.counters.take()["histograms"] == 0
+    if name in ("integers", "more_edges_than_comparing_against_each_saves", "an_edge_at_infinity"):
+        want, _ = real_np.histogram(real_np.asarray(x), **call)
+        assert real_np.array_equal(real_np.asarray(got[0]), want)
+
+
+def test_a_vector_whose_bins_could_pass_int32_keeps_numpy(todays_histogram, np_shim, monkeypatch):
+    assert shim._histogram_takes(2 ** 31 - 1, real_np.dtype("float32"), 4, None) == "program"
+    assert shim._histogram_takes(2 ** 31, real_np.dtype("float32"), 4, None) == "numpy"
+    monkeypatch.setattr(shim, "_HISTOGRAM_MAX_ELEMENTS", N)
+    counts, edges = np_shim.histogram(TpuArray(grid_floats()), 8)
+    assert type(counts) is real_np.ndarray and counts.dtype == real_np.int64 and counts.sum() == N
+    taken = lazy.counters.take()
+    assert (taken["histograms"], taken["fallbacks"], len(todays_histogram)) == (0, 1, 0)
+
+
+@pytest.mark.parametrize("size, dtype, bins, weights, route", [
+    (10 ** 7, "float32", 1000, None, "program"),
+    (10 ** 7, "float32", 1000, "float32", "program"),
+    (10 ** 7, "float32", tuple(range(1001)), None, "program"),
+    (10 ** 7, "float32", "fd", None, "jnp"),
+    (10 ** 7, "int32", 1000, None, "jnp"),
+    (10 ** 7, "bool", 2, None, "jnp"),
+    (10 ** 7, "float32", 1000, "complex64", "jnp"),
+    (10 ** 7, "float32", 1000, "object", "jnp"),
+    (10 ** 7, "float32", 10.5, None, "jnp"),
+    (10 ** 7, "float32", ((0, 1), (2, 3)), None, "jnp"),
+    (10 ** 7, None, 1000, None, "jnp"),
+    (2 ** 30, "float32", 1000, None, "program"),
+    (10 ** 7, "float32", 2 ** 16, None, "program"),
+    (10 ** 7, "float32", 2 ** 16 + 1, None, "jnp"),
+    (2 ** 18, "float32", range(2 ** 16 + 2), None, "jnp"),
+    (2 ** 31, "float32", 1000, None, "numpy"),
+])
+def test_the_route_is_read_from_the_call(size, dtype, bins, weights, route):
+    dtype, weights = (None if name is None else real_np.dtype(name) for name in (dtype, weights))
+    assert shim._histogram_takes(size, dtype, bins, weights) == route
 
 
 def test_each_copy_runs_under_a_shim_h2d_annotation(np_shim, tmp_path, monkeypatch):

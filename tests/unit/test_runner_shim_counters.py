@@ -20,7 +20,7 @@ from bee_code_interpreter_fs_tpu.services.perf_observer import OBSERVED_PHASES
 
 RUNNER_PY = Path(__file__).resolve().parents[2] / "executor" / "runner.py"
 COUNTERS = ("programs", "exec_cache_misses", "nodes", "flushes", "h2d_arrays", "h2d_bytes", "h2d_s", "donated_bytes",
-            "aligned_stores", "kernel_stores", "fallbacks", "host_s")
+            "aligned_stores", "kernel_stores", "histograms", "fallbacks", "host_s")
 
 
 @pytest.fixture()
@@ -102,11 +102,11 @@ def test_a_counter_that_fails_never_fails_the_turn(runner, monkeypatch):
 
 def test_shim_phases_are_stamped_under_fixed_names_as_numbers():
     body = {"shim": {"programs": 5, "exec_cache_misses": 0, "nodes": 306, "flushes": 1, "h2d_arrays": 8, "h2d_bytes": 1114112,
-                     "h2d_s": 0.0123456789, "donated_bytes": 4831838208, "aligned_stores": 26, "kernel_stores": 24, "fallbacks": 2, "host_s": 0.123456789, "minted_by_user_code": 7}}
+                     "h2d_s": 0.0123456789, "donated_bytes": 4831838208, "aligned_stores": 26, "kernel_stores": 24, "histograms": 4, "fallbacks": 2, "host_s": 0.123456789, "minted_by_user_code": 7}}
     phases = CodeExecutor._shim_phases(body)
     assert phases == {
         "shim_programs": 5.0, "shim_exec_cache_misses": 0.0, "shim_nodes": 306.0, "shim_flushes": 1.0,
-        "shim_h2d_arrays": 8.0, "shim_h2d_bytes": 1114112.0, "shim_h2d": 0.012346, "shim_donated_bytes": 4831838208.0, "shim_aligned_stores": 26.0, "shim_kernel_stores": 24.0, "shim_fallbacks": 2.0, "shim_host": 0.123457,
+        "shim_h2d_arrays": 8.0, "shim_h2d_bytes": 1114112.0, "shim_h2d": 0.012346, "shim_donated_bytes": 4831838208.0, "shim_aligned_stores": 26.0, "shim_kernel_stores": 24.0, "shim_histograms": 4.0, "shim_fallbacks": 2.0, "shim_host": 0.123457,
     }
     assert all(isinstance(v, float) for v in phases.values())
 
@@ -127,7 +127,7 @@ def test_no_shim_phase_is_a_latency_phase():
     """The histogram's allowlist and the perf observer's baselines see none
     of the new keys (the PR 6 / PR 7 discipline)."""
     keys = set(SHIM_PHASES.values())
-    assert len(keys) == 12 and tuple(SHIM_PHASES) == COUNTERS
+    assert len(keys) == 13 and tuple(SHIM_PHASES) == COUNTERS
     assert not keys & LATENCY_PHASES
     assert not keys & set(OBSERVED_PHASES)
     assert not keys & set(STAGE_PHASES)

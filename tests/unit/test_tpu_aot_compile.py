@@ -307,6 +307,34 @@ def test_npbench_stencil_programs_are_one_aligned_pass_per_window_store(
         assert _device_bytes(compiled) + grids * grid_bytes < V5E_HBM_BYTES
 
 
+@pytest.mark.parametrize("weighted", [False, True], ids=["counts", "weighted"])
+def test_the_histogram_is_one_program_with_its_edges_as_an_operand(one_chip, weighted):
+    """`np.histogram` over `npfiles.c3`'s vector (`azimint_hist`: N 10,000,000,
+    npt 1000): the shim's program compiled for the described chip. The edges
+    are a parameter, so two datasets of one shape share the executable; no
+    `while` (the scan `jnp.histogram` searches with) and no gather; and the
+    comparison of every element against every edge is never written out: the
+    temporaries stay under three times the vector's bytes (a padded copy of
+    each operand and the partial sums), where `N x (bins + 1)` would be 10 GB."""
+    from bee_code_interpreter_fs_tpu.ops.npdispatch import shim
+
+    n, edges = 10_000_000, 1001
+    vector = _spec((n,), jnp.float32, one_chip)
+    compiled = shim._histogram_program.lower(
+        vector, _spec((edges,), jnp.float32, one_chip), vector if weighted else None).compile()
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    parameters = re.findall(r"= (\S+) parameter\(\d\)", entry[:entry.index("\n}")])
+    assert sorted(p.split("{")[0] for p in parameters) == sorted(
+        ["f32[10000000]", "f32[1001]"] + ["f32[10000000]"] * weighted)
+    constants = re.findall(r"= (\S+) constant\(", text)
+    assert constants and not any(c.startswith(("f32[1001]", "f32[1000]")) for c in constants), "the edges are no constant"
+    assert " while(" not in text and " gather(" not in text and " scatter(" not in text
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 3 * 4 * n
+    assert memory.output_size_in_bytes <= 4096, "the bins, padded to a tile"
+
+
 def test_prewarm_kernel_set_compiles_for_v5e(topo, one_chip, monkeypatch):
     """The pre-warm snippets run at every service start
     (services/compile_cache.PREWARM_SOURCES). Each is executed with jax.jit
