@@ -134,6 +134,18 @@ class Counters:
                        against numpy's own edges, the bins summed in the same
                        program); a call that kept `jnp.histogram` or numpy is
                        not counted
+    dots               dot-like nodes (`dot`, `matmul`, `@`, `inner`, `tensordot`,
+                       `einsum`: DOT_OPS) that a program executed, counted
+                       per execution
+    dot_flops          what those contractions ask of the chip: 2 x the elements
+                       of each result x the product of the contracted
+                       dimensions, read from the nodes' shapes (`_dot_flops`)
+    ufunc_methods      calls of a numpy ufunc's method (`np.add.outer`,
+                       `np.minimum.reduce`, `np.maximum.accumulate`, `np.add.at`)
+                       that ran on the device as the `jnp` ufunc's own method:
+                       a node of a program, counted when it is executed, or an
+                       eager call. One that ran under numpy on host copies of
+                       device arrays is a fallback
     fallbacks          calls the shim routed to the device that ran under
                        stock numpy after all (`np.fromfunction` of a function
                        a TpuArray cannot serve, a jnp function that refused
@@ -143,8 +155,8 @@ class Counters:
     """
 
     FIELDS = ("programs", "exec_cache_misses", "nodes", "flushes", "h2d_arrays", "h2d_bytes",
-              "h2d_s", "donated_bytes", "aligned_stores", "kernel_stores", "histograms", "fallbacks",
-              "host_s")
+              "h2d_s", "donated_bytes", "aligned_stores", "kernel_stores", "histograms", "dots",
+              "dot_flops", "ufunc_methods", "fallbacks", "host_s")
 
     def __init__(self) -> None:
         self.reset()
@@ -153,6 +165,7 @@ class Counters:
         self.programs = self.exec_cache_misses = self.nodes = self.flushes = 0
         self.h2d_arrays = self.h2d_bytes = self.donated_bytes = 0
         self.aligned_stores = self.kernel_stores = self.histograms = self.fallbacks = 0
+        self.dots = self.dot_flops = self.ufunc_methods = 0
         self.h2d_s = self.host_s = 0.0
         self._depth = 0  # build_node -> flush -> materialize nest: count once
         self._entered = self._outside = 0.0
@@ -417,9 +430,20 @@ def precision_scope():
 
 # Materialization: linearize DAG -> structure key -> cached jitted runner.
 
-# structure key -> (the jitted runner, the nodes whose values it returns, in its
-# order, the window stores it runs at full shape, those of them as a kernel)
-_exec_cache: dict[tuple, tuple[Callable, list, int, int]] = {}
+class _Program(NamedTuple):
+    """A compiled runner and what one execution of it counts."""
+
+    runner: Callable
+    returned: list  # the nodes whose values it returns, in its order
+    aligned_stores: int  # the window stores it runs at full shape
+    kernel_stores: int  # those of them as a kernel
+    dots: int
+    dot_flops: int
+    ufunc_methods: int
+
+
+# structure key -> its program
+_exec_cache: dict[tuple, _Program] = {}
 _CACHE_LIMIT = 512
 
 
@@ -857,6 +881,57 @@ def _full_shape_plan(lin: _Linear, out_indices: list[int], platform: str = ""):
     return shifts, stores, kernels
 
 
+# The contractions: what `np.dot`, `np.matmul` and `@`, `np.inner`,
+# `np.tensordot` and `np.einsum` call on the device. On a TPU they are the
+# work of the MXU, at `MATMUL_PRECISION`.
+DOT_OPS = (jnp.dot, jnp.matmul, jnp.inner, jnp.tensordot, jnp.einsum)
+
+
+def _eqns(jaxpr):
+    """Every equation of `jaxpr` and of the jaxprs inside its equations."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            inner = getattr(param, "jaxpr", param)  # a ClosedJaxpr holds its Jaxpr
+            if hasattr(inner, "eqns"):
+                yield from _eqns(inner)
+
+
+def _dot_flops(fn, refs, kwargs, aval_of) -> int:
+    """The floating-point operations of one contraction node, from shapes
+    alone: for each `dot_general` that `fn` is over operands of these shapes,
+    2 x the elements of its result x the product of the contracted dimensions
+    (a multiplication and an addition for each). What any execution does,
+    whatever the passes the chip makes of each multiplication."""
+    def call(*arrays):
+        given = iter(arrays)
+        return fn(*[v if kind == _REF_STATIC else next(given) for kind, v in refs], **kwargs)
+
+    operands = [aval_of(ref) for ref in refs if ref[0] != _REF_STATIC]
+    closed = jax.make_jaxpr(call)(*[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in operands])
+    flops = 0
+    for eqn in _eqns(closed.jaxpr):
+        if eqn.primitive.name == "dot_general":
+            (contracted, _), _ = eqn.params["dimension_numbers"]
+            left = eqn.invars[0].aval.shape
+            flops += 2 * math.prod(eqn.outvars[0].aval.shape) * math.prod(left[d] for d in contracted)
+    return flops
+
+
+def _census(lin: _Linear) -> tuple[int, int, int]:
+    """(contraction nodes, their operations, ufunc-method nodes) of one
+    execution of `lin`'s program. A ufunc's method is a node whose function
+    is a bound method of a `jnp.ufunc`, or `at_op` (`shim._UfuncDispatcher`)."""
+    def aval_of(ref):
+        kind, v = ref
+        return lin.nodes[v].aval if kind == _REF_NODE else lin.leaves[v]
+
+    dots = [(fn, refs, kwargs) for fn, refs, kwargs in lin.spec if any(fn is op for op in DOT_OPS)]
+    methods = sum(1 for fn, _, _ in lin.spec
+                  if fn is at_op or isinstance(getattr(fn, "__self__", None), jnp.ufunc))
+    return len(dots), sum(_dot_flops(*dot, aval_of) for dot in dots), methods
+
+
 # The kernel's builder, under a name of this module: a test on the CPU puts
 # `functools.partial(stencil.window_store, interpret=True)` here.
 window_store = stencil.window_store
@@ -955,44 +1030,46 @@ def _run(roots: list[Node]) -> None:
     donated = _donatable(lin, out_indices)
     key = (lin.key, tuple(out_indices), tuple(donated))
 
-    def build(platform: str):
+    def build(platform: str) -> _Program:
         shifts, stores, kernels = _full_shape_plan(lin, out_indices, platform)
         returned = _paired(lin, out_indices, donated)
         runner = jax.jit(_make_runner(lin.spec, returned, shifts, stores, kernels),
                          donate_argnums=tuple(donated))
-        _exec_cache[key] = (runner, returned, len(stores), len(kernels))
+        _exec_cache[key] = _Program(runner, returned, len(stores), len(kernels), *_census(lin))
         return _exec_cache[key]
 
-    cached = _exec_cache.get(key)
-    if cached is None:
+    program = _exec_cache.get(key)
+    if program is None:
         if len(_exec_cache) >= _CACHE_LIMIT:
             _exec_cache.clear()
         counters.exec_cache_misses += 1
-        cached = build(_platform(lin.leaves))
-    runner, returned, aligned_stores, kernel_stores = cached
+        program = build(_platform(lin.leaves))
     leaves = []
     for leaf in lin.leaves:
         leaves.append(leaf if isinstance(leaf, jax.Array) else ship(leaf))
     counters.programs += 1
     counters.nodes += len(lin.spec)
     counters.donated_bytes += sum(leaves[li].nbytes for li in donated)
-    counters.aligned_stores += aligned_stores
+    counters.aligned_stores += program.aligned_stores
+    counters.dots += program.dots
+    counters.dot_flops += program.dot_flops
+    counters.ufunc_methods += program.ufunc_methods
     called = time.perf_counter()
     with jax.profiler.TraceAnnotation("shim.materialize"), precision_scope():
         try:
-            outs = runner(*leaves)
+            outs = program.runner(*leaves)
         except Exception:  # noqa: BLE001 — whatever a kernel's build or the chip's compiler raised
-            if not kernel_stores or any(leaf.is_deleted() for leaf in leaves):
+            if not program.kernel_stores or any(leaf.is_deleted() for leaf in leaves):
                 raise
             # A kernel never fails a turn: the program again with every store
             # as the select it was, from now on (an error of the program's own
             # comes back from that one too).
             logger.warning("a window store's kernel was refused; the select form runs", exc_info=True)
-            runner, returned, _, kernel_stores = build("")
-            outs = runner(*leaves)
-    counters.kernel_stores += kernel_stores
+            program = build("")
+            outs = program.runner(*leaves)
+    counters.kernel_stores += program.kernel_stores
     counters.leave_out(time.perf_counter() - called)
-    for i, value in zip(returned, outs):
+    for i, value in zip(program.returned, outs):
         node = lin.nodes[i]
         for owner in node.live_owners():
             owner._concrete = value
@@ -1013,6 +1090,12 @@ def getitem_op(arr, idx):
 
 def setitem_op(arr, value, idx):
     return arr.at[idx].set(value)
+
+
+def at_op(arr, value, idx, update):
+    """`np.<ufunc>.at(arr, idx, value)` as a functional update (`update`: the
+    indexed update's name, `add`, `max`, ...); repeated indices accumulate."""
+    return getattr(arr.at[idx], update)(value)
 
 
 def astype_op(arr, dtype):

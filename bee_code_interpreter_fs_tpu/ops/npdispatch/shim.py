@@ -334,6 +334,14 @@ class TpuArray:
     def _from_node(cls, node: "lazy.Node") -> "TpuArray":
         return cls(node)
 
+    def _take(self, result: "TpuArray") -> None:
+        """Become `result`: how an in-place update (`a += b`, `np.add.at(a,
+        ...)`) rebinds this array to the functional update's value."""
+        if result._node is not None:
+            self._set_node(result._node)
+        else:
+            self._concrete, self._node = result._concrete, None
+
     def _force(self) -> jax.Array:
         if self._concrete is None:
             # (materialize writes every live owner back, this one among them)
@@ -379,6 +387,10 @@ class TpuArray:
         numpy on host copies, as it was before this hook existed."""
         if out is not None and _contains_tpu_array(out):
             return NotImplemented  # numpy's TypeError: no ufunc writes into a TpuArray
+        if method == "at" and isinstance(inputs[0], TpuArray):
+            # in place: a ufunc the shim does not dispatch (`np.negative.at(t, i)`)
+            inputs[0]._take(_at_on_host(ufunc, *inputs))
+            return None
         result = NotImplemented
         operators = _UFUNC_OPERATORS.get(ufunc)
         if method == "__call__" and operators is not None and len(inputs) == 2 and not kwargs:
@@ -598,6 +610,57 @@ class TpuArray:
         return _result_wrap(attr)
 
 
+# ---------------------------------------------------------------------------
+# `x % y` of floats. numpy's is exact (C's fmod, then the divisor's sign). The
+# TPU's `rem` is `x - trunc(x / y) * y` in the working precision: where the
+# product needs more bits than a float has, or the quotient rounds the other
+# way, it is off by the rounding, and `(i * j % N) / N` of an index product
+# over 2**24 reads 0 where numpy reads (N - 8) / N (PERF.md, PR 37: 15,612 of
+# 23,400 elements of `k * 20700 % 20700`). So the shim computes it itself.
+
+def _split(a):
+    """Veltkamp's split: `a == hi + lo`, each of half the significand's bits,
+    so that a product of two halves is exact."""
+    bits = (jnp.finfo(a.dtype).nmant + 2) // 2
+    c = a * (2.0 ** bits + 1.0)
+    hi = c - (c - a)
+    return hi, a - hi
+
+
+def _fmod_of_magnitudes(ax, ay, q):
+    """`fmod(ax, ay)` of two finite magnitudes, exact, from a quotient `q`
+    that is the truncated one, one under it or up to two over it (a division
+    that rounds up to a whole number, or is not correctly rounded):
+    `ax - q * ay` with the product's exact error taken out (Dekker's product
+    of split halves: no fused multiply-add is assumed), then brought into
+    `[0, ay)`."""
+    p = q * ay
+    (qh, ql), (yh, yl) = _split(q), _split(ay)
+    m = (ax - p) - (((qh * yh - p) + qh * yl + ql * yh) + ql * yl)
+    m = jnp.where(m < 0, m + ay, m)
+    m = jnp.where(m >= ay, m - ay, m)
+    return jnp.where(m < 0, m + ay, m)
+
+
+def remainder(x, y):
+    """`np.remainder(x, y)` (`x % y`, `np.mod`) on the device, exact for
+    float32 (and float64 where the device has it) as numpy's is:
+    `_fmod_of_magnitudes`, then numpy's signs and its answers for an
+    infinite divisor, a zero divisor and a non-finite dividend. Integers and
+    16-bit floats keep `jnp.remainder`. (A quotient of 2**24 and over is no
+    longer told from its neighbour by a float: there the result is as close
+    as the chip's own.)"""
+    dtype = jnp.result_type(x, y)
+    if not jnp.issubdtype(dtype, jnp.floating) or jnp.finfo(dtype).nmant < 23:
+        return jnp.remainder(x, y)
+    x, y = jnp.asarray(x, dtype), jnp.asarray(y, dtype)
+    ax, ay = jnp.abs(x), jnp.abs(y)
+    m = jnp.where(jnp.isinf(ay), ax, _fmod_of_magnitudes(ax, ay, jnp.trunc(ax / ay)))
+    m = jnp.where(((x < 0) != (y < 0)) & (m != 0), ay - m, m)
+    undefined = jnp.isnan(x) | jnp.isnan(y) | jnp.isinf(ax) | (ay == 0)
+    return jnp.where(undefined, jnp.nan, jnp.copysign(m, y))
+
+
 # Lazily-dispatched ndarray methods (stay on device, stay lazy).
 def _lazy_method(np_name: str, jnp_fn):
     def method(self, *args, **kwargs):
@@ -667,7 +730,7 @@ def _binop(name: str, jnp_fn, swap: bool = False):
 _BINOPS = {
     "__add__": jnp.add, "__sub__": jnp.subtract, "__mul__": jnp.multiply,
     "__truediv__": jnp.true_divide, "__floordiv__": jnp.floor_divide,
-    "__mod__": jnp.mod, "__pow__": jnp.power, "__matmul__": jnp.matmul,
+    "__mod__": remainder, "__pow__": jnp.power, "__matmul__": jnp.matmul,
     "__and__": jnp.bitwise_and, "__or__": jnp.bitwise_or,
     "__xor__": jnp.bitwise_xor, "__lshift__": jnp.left_shift,
     "__rshift__": jnp.right_shift, "__lt__": jnp.less,
@@ -722,10 +785,7 @@ for _name in (
             if result is NotImplemented:
                 return NotImplemented
             if isinstance(result, TpuArray):
-                if result._node is not None:
-                    self._set_node(result._node)
-                else:
-                    self._concrete, self._node = result._concrete, None
+                self._take(result)
             else:
                 self._concrete, self._node = jnp.asarray(result), None
             return self
@@ -778,6 +838,9 @@ COMPUTE_FNS = (
     "array_equal", "triu", "tril", "diag", "diagonal", "meshgrid", "cov",
     "corrcoef", "apply_along_axis", "atleast_1d", "atleast_2d", "atleast_3d",
 )
+
+# What a compute function runs on the device where that is not `jnp`'s of the name.
+_DEVICE_FNS = {"mod": remainder, "remainder": remainder}
 
 # Functions whose results are scalars/bools used in control flow — keep eager
 # (lazy would immediately force anyway, with extra tracing overhead).
@@ -840,7 +903,9 @@ class _Dispatcher:
         self.__module__ = getattr(np_fn, "__module__", "numpy")
         self.__wrapped__ = np_fn
 
-    def _use_device(self, args, kwargs) -> bool:
+    def _use_device(self, args, kwargs, outer: bool = False) -> bool:
+        """`outer`: the call is an outer product of its first two operands, as
+        `np.outer`'s own is (a ufunc's `outer`)."""
         if self.jnp_fn is None:
             return False
         # Integer exactness policy: wide-int requests/operands and
@@ -881,7 +946,7 @@ class _Dispatcher:
             return False
         if _contains_tpu_array(values):
             return True
-        if self.name == "outer" and len(args) >= 2:
+        if (outer or self.name == "outer") and len(args) >= 2:
             # small operands, big result: np.outer of two vectors under the
             # threshold is a matrix over it, and belongs where it is used
             return _operand_size(args[0]) * _operand_size(args[1]) >= self.threshold
@@ -911,6 +976,118 @@ class _Dispatcher:
 
     def __repr__(self):
         return f"<tpu-dispatched numpy.{self.name}>"
+
+
+# A ufunc's reduction whose accumulator numpy promotes to the platform
+# integer, by the name of the function of the same meaning
+# (`_INT_EXACT_REDUCTIONS`): `np.add.reduce` of int32 is `np.sum` of it.
+_UFUNC_ACCUMULATORS = {
+    ("add", "reduce"): "sum", ("multiply", "reduce"): "prod",
+    ("add", "accumulate"): "cumsum", ("multiply", "accumulate"): "cumprod",
+}
+
+
+class _UfuncDispatcher(_Dispatcher):
+    """A `_Dispatcher` over one of numpy's ufuncs (`np.add`, `np.minimum`,
+    ...), which answers what numpy's ufunc answers: its methods and its
+    attributes. `isinstance(np.add, np.ufunc)` stays False: a ufunc is a type
+    of numpy's own that nothing can subclass; code that asks it takes the
+    generic branch it has for any callable.
+
+    `outer`, `reduce`, `accumulate`: where the call goes to the device by
+    `_use_device`'s own rule for its operands (a TpuArray, an ndarray at or
+    over the threshold; the integer policy as for `sum` and `cumsum`), the
+    `jnp` ufunc's own method, a node of the lazy graph where `build_node` takes
+    it; everywhere else numpy's, on the real ufunc. Only eight of these ufuncs
+    have the methods in `jnp` (add, subtract, multiply, maximum, minimum,
+    logical_and / or / xor). `at`: numpy's semantics (in place, repeated
+    indices accumulate); a TpuArray target is rebound to the functional
+    update, as `__setitem__` does: jax's indexed update of the same meaning
+    for add, subtract, multiply, maximum and minimum. `reduceat` and every
+    attribute (`nin`, `nout`, `identity`, `types`, ...): the real ufunc's. A
+    method that `jnp` lacks, and every `reduceat`, runs under numpy on host
+    copies of the device arrays it was given, correct and counted in
+    `counters.fallbacks`."""
+
+    def __getattr__(self, name):
+        if name == "np_fn":  # (before __init__ has run: a copy, an unpickle)
+            raise AttributeError(name)
+        return getattr(self.np_fn, name)
+
+    def _on_host(self, method: str, args, kwargs):
+        if _contains_tpu_array(list(args) + list(kwargs.values())):
+            lazy.counters.fallbacks += 1
+        return getattr(self.np_fn, method)(
+            *_unwrap_np(list(args)), **{k: _unwrap_np(v) for k, v in kwargs.items()})
+
+    def _on_device(self, method: str, fn, args, kwargs):
+        """`fn` lazily (counted by the program that executes the node), else
+        eagerly (counted here); NotImplemented where it refuses the arguments."""
+        result = try_lazy(f"{self.name}.{method}", fn, args, kwargs)
+        if result is None:
+            result = eager_device(fn, args, kwargs)
+            if result is not NotImplemented:
+                lazy.counters.ufunc_methods += 1
+        return result
+
+    def _method(self, method: str, args, kwargs):
+        fn = getattr(self.jnp_fn, method, None)  # (only a `jnp.ufunc` has them)
+        accumulator = _UFUNC_ACCUMULATORS.get((self.name, method), "")
+        if (fn is not None and kwargs.get("out") is None  # (numpy writes into `out`; jnp has none)
+                and not _int_reduction_needs_host(accumulator, args, kwargs)
+                and self._use_device(args, kwargs, outer=method == "outer")):
+            result = self._on_device(method, fn, *_canonicalize_dtype_args(args, kwargs))
+            if result is not NotImplemented:
+                return result
+        return self._on_host(method, args, kwargs)
+
+    def outer(self, *args, **kwargs):
+        return self._method("outer", args, kwargs)
+
+    def reduce(self, *args, **kwargs):
+        return self._method("reduce", args, kwargs)
+
+    def accumulate(self, *args, **kwargs):
+        return self._method("accumulate", args, kwargs)
+
+    def reduceat(self, *args, **kwargs):
+        return self._on_host("reduceat", args, kwargs)
+
+    def at(self, a, indices, *b):
+        if not isinstance(a, TpuArray):
+            return self._on_host("at", (a, indices, *b), {})  # numpy's own, in the caller's array
+        update = _AT_UPDATES.get(self.name)
+        result = NotImplemented
+        # (64-bit indices are shipped as `a[indices]` ships them; a value of
+        # another kind than `a`'s is cast numpy's way, by numpy)
+        if (update is not None and len(b) == 1 and self._use_device((a, *b), {})
+                and real_np.can_cast(_value_dtype(b[0]), a.dtype, "same_kind")):
+            if isinstance(indices, list):
+                indices = (indices,)  # numpy: a list indexes the first axis; jax refuses a bare one
+            result = self._on_device("at", lazy.at_op, (a, b[0], indices, update), {})
+        if result is NotImplemented:
+            result = _at_on_host(self.np_fn, a, indices, *b)
+        a._take(result)
+        return None
+
+
+# `np.<ufunc>.at` as jax's indexed update of the same meaning, where it has one
+# that is exact: repeated indices accumulate in both.
+_AT_UPDATES = {"add": "add", "subtract": "subtract", "multiply": "multiply", "maximum": "max", "minimum": "min"}
+
+
+def _value_dtype(value):
+    dtype = getattr(value, "dtype", None)
+    return real_np.dtype(dtype) if dtype is not None else real_np.result_type(value)
+
+
+def _at_on_host(ufunc, target: TpuArray, *rest) -> TpuArray:
+    """`ufunc.at(target, *rest)` by numpy on a host copy of `target`, shipped
+    back: correct, and counted as a fallback."""
+    host = real_np.array(target)
+    ufunc.at(host, *_unwrap_np(list(rest)))
+    lazy.counters.fallbacks += 1
+    return TpuArray(host)
 
 
 def _grid_dtype(dimensions, dtype, threshold: int):
@@ -1209,8 +1386,9 @@ class _NumpyShim(types.ModuleType):
             np_fn = getattr(real_np, name, None)
             if np_fn is None:
                 continue
-            self._overrides[name] = _Dispatcher(
-                name, np_fn, getattr(jnp, name, None), threshold, kind="compute"
+            dispatcher = _UfuncDispatcher if isinstance(np_fn, real_np.ufunc) else _Dispatcher
+            self._overrides[name] = dispatcher(
+                name, np_fn, _DEVICE_FNS.get(name, getattr(jnp, name, None)), threshold, kind="compute"
             )
         self._overrides.update(_grid_overrides(threshold))
         self._overrides.update(_histogram_overrides(self._overrides["histogram"]))

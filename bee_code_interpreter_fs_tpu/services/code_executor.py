@@ -152,6 +152,9 @@ SHIM_PHASES = {
     "aligned_stores": "shim_aligned_stores",
     "kernel_stores": "shim_kernel_stores",
     "histograms": "shim_histograms",
+    "dots": "shim_dots",
+    "dot_flops": "shim_dot_flops",
+    "ufunc_methods": "shim_ufunc_methods",
     "fallbacks": "shim_fallbacks",
     "host_s": "shim_host",
 }

@@ -176,7 +176,8 @@ def _take_stages(sent_mono) -> list | None:
 def _take_shim() -> dict | None:
     """The numpy shim's counters of the request in hand (programs run, runner
     cache misses, nodes, flushes, host arrays shipped with their bytes and
-    seconds, bytes donated, host seconds inside the shim), taken and zeroed
+    seconds, bytes donated, contractions and their operations, ufunc methods,
+    host seconds inside the shim), taken and zeroed
     the way `_take_stages` takes the stage clocks; None in a runner whose
     interpreter started without the shim."""
     shim = sys.modules.get("bee_code_interpreter_fs_tpu.ops.npdispatch")

@@ -1,6 +1,6 @@
 """The numpy shim's counters end to end (ISSUE 31): a `/v1/execute` of array
 code through the HTTP API, the real C++ executor and a warm runner that has
-the shim installed comes back with the thirteen `shim_*` keys in
+the shim installed comes back with the sixteen `shim_*` keys in
 `Result.phases`; the next turn, on the sandbox that `/reset` put back, reads
 0 where the shim did nothing; and no histogram observes any of them. A
 sandbox without the shim (the no-JAX plumbing mode) stamps none. Nothing
@@ -112,6 +112,44 @@ async def test_a_turn_over_an_uploaded_file_ships_it_once(tmp_path):
         assert phases["upload_bytes"] == phases["shim_h2d_bytes"] == float(data.nbytes)
         assert phases["shim_h2d_arrays"] == 1.0 and phases["shim_h2d"] > 0.0 and phases["shim_fallbacks"] == 0.0
         assert phases["shim_histograms"] == 1.0
+    finally:
+        await client.close()
+        await executor.close()
+
+
+# a product and an all-pairs step over the shipped threshold (ISSUE 37): one
+# contraction of 2 * 384 * 512 * 448 operations, three calls of a ufunc's method
+LINALG_TURN = (
+    "import numpy as np\n"
+    "A = np.fromfunction(lambda i, j: (i * j + 1) % 7 / 7, (384, 512), dtype=np.float32)\n"
+    "B = np.fromfunction(lambda i, j: (i * j + 2) % 5 / 5, (512, 448), dtype=np.float32)\n"
+    "C = np.fromfunction(lambda i, j: (i + j) % 3 / 3, (384, 448), dtype=np.float32)\n"
+    "C[:] = 1.5 * A @ B + 1.2 * C\n"
+    "path = np.fromfunction(lambda i, j: i * j % 7 + 1, (400, 400), dtype=np.int32)\n"
+    "for k in range(3):\n"
+    "    path[:] = np.minimum(path[:], np.add.outer(path[:, k], path[k, :]))\n"
+    "print(type(C).__name__, float(C[5, 7]), int(path.sum(axis=1, dtype=np.int32)[9]), np.add.nin)\n"
+)
+
+
+async def test_a_turn_of_products_and_ufunc_methods_is_counted(tmp_path):
+    client, executor = await make_client(tmp_path, warm_import_jax=True)
+    try:
+        resp = await client.post("/v1/execute", json={"source_code": LINALG_TURN})
+        body = await resp.json()
+        assert body["exit_code"] == 0, body["stderr"]
+        i, j = np.indices((384, 512)), np.indices((512, 448))
+        A, B = ((i[0] * i[1] + 1) % 7 / 7).astype(np.float32), ((j[0] * j[1] + 2) % 5 / 5).astype(np.float32)
+        want = 1.5 * float(A[5] @ B[:, 7]) + 1.2 * (12 % 3 / 3)
+        path = np.fromfunction(lambda i, j: i * j % 7 + 1, (400, 400), dtype=np.int32)
+        for k in range(3):
+            path[:] = np.minimum(path[:], np.add.outer(path[:, k], path[k, :]))
+        kind, value, row, nin = body["stdout"].split()
+        assert (kind, int(row), nin) == ("TpuArray", int(path[9].sum()), "2")
+        assert float(value) == pytest.approx(want, rel=1e-5)
+        phases = body["phases"]
+        assert phases["shim_dots"] == 1.0 and phases["shim_dot_flops"] == 2.0 * 384 * 512 * 448
+        assert phases["shim_ufunc_methods"] == 3.0 and phases["shim_fallbacks"] == 0.0
     finally:
         await client.close()
         await executor.close()

@@ -1020,7 +1020,8 @@ def test_counters_of_a_hand_made_graph(np_shim):
     # (the shipped copy of `host` is dead after the add, and c has its shape)
     assert taken == {"programs": 1, "exec_cache_misses": 1, "nodes": 4, "flushes": 0, "h2d_arrays": 1,
                      "h2d_bytes": host.nbytes, "h2d_s": taken["h2d_s"], "donated_bytes": host.nbytes,
-                     "aligned_stores": 0, "kernel_stores": 0, "histograms": 0, "fallbacks": 0, "host_s": taken["host_s"]}
+                     "aligned_stores": 0, "kernel_stores": 0, "histograms": 0, "dots": 0, "dot_flops": 0, "ufunc_methods": 0,
+                     "fallbacks": 0, "host_s": taken["host_s"]}
     assert 0.0 < taken["h2d_s"] < taken["host_s"] < 60.0, "this copy was made while its node was built"
     # a, b and c came back as outputs: each now reads in a program of one node
     assert float(b[1]) == 2.0 and float(c[2]) == 4.0

@@ -20,7 +20,7 @@ from bee_code_interpreter_fs_tpu.services.perf_observer import OBSERVED_PHASES
 
 RUNNER_PY = Path(__file__).resolve().parents[2] / "executor" / "runner.py"
 COUNTERS = ("programs", "exec_cache_misses", "nodes", "flushes", "h2d_arrays", "h2d_bytes", "h2d_s", "donated_bytes",
-            "aligned_stores", "kernel_stores", "histograms", "fallbacks", "host_s")
+            "aligned_stores", "kernel_stores", "histograms", "dots", "dot_flops", "ufunc_methods", "fallbacks", "host_s")
 
 
 @pytest.fixture()
@@ -66,6 +66,47 @@ def test_a_turns_counters_are_taken_once_and_a_turn_without_arrays_reads_zero(ru
         npdispatch.uninstall()
 
 
+# over threshold=1000: a product, a chain of three, three steps of all-pairs paths (ISSUE 37)
+LINALG_TURNS = {
+    "gemm": ("import numpy as np\n"
+             "A = np.fromfunction(lambda i, j: (i * j + 1) % 7 / 7, (40, 50), dtype=np.float32)\n"
+             "B = np.fromfunction(lambda i, j: (i * j + 2) % 5 / 5, (50, 60), dtype=np.float32)\n"
+             "C = np.fromfunction(lambda i, j: (i + j) % 3 / 3, (40, 60), dtype=np.float32)\n"
+             "C[:] = 1.5 * A @ B + 1.2 * C\nprint(float(C.sum()))\n",
+             {"dots": 1, "dot_flops": 2 * 40 * 50 * 60, "ufunc_methods": 0}),
+    "k3mm": ("import numpy as np\n"
+             "A, B, C, D = (np.fromfunction(lambda i, j: (i * j + 1) % 7 / 7, s, dtype=np.float32)\n"
+             "              for s in ((40, 50), (50, 45), (45, 60), (60, 55)))\n"
+             "print(float((A @ B @ C @ D).sum()))\n",
+             {"dots": 3, "dot_flops": 2 * 40 * (50 * 45 + 45 * 60 + 60 * 55), "ufunc_methods": 0}),
+    "floyd_warshall": ("import numpy as np\n"
+                       "path = np.fromfunction(lambda i, j: i * j % 7 + 1, (40, 40), dtype=np.int32)\n"
+                       "for k in range(3):\n"
+                       "    path[:] = np.minimum(path[:], np.add.outer(path[:, k], path[k, :]))\n"
+                       "print(int(path[3, 5]))\n",
+                       {"dots": 0, "dot_flops": 0, "ufunc_methods": 3}),
+    "nothing": ("print(6 * 7)\n", {"dots": 0, "dot_flops": 0, "ufunc_methods": 0}),
+}
+
+
+@pytest.mark.parametrize("name", LINALG_TURNS)
+def test_a_turns_contractions_and_ufunc_methods_are_taken_and_stamped(runner, tmp_path, name):
+    """From the user's code to `Result.phases`: the runner takes the three
+    counters with the others, the control plane stamps them as numbers, 0 on
+    a turn that did nothing."""
+    source, want = LINALG_TURNS[name]
+    npdispatch.install(threshold=1000)
+    try:
+        runner._take_shim()
+        run_script(runner, tmp_path, source)
+        taken = runner._take_shim()
+    finally:
+        npdispatch.uninstall()
+    assert {key: taken[key] for key in want} == want and taken["fallbacks"] == 0
+    phases = CodeExecutor._shim_phases({"shim": taken})
+    assert {key: phases[SHIM_PHASES[key]] for key in want} == {key: float(value) for key, value in want.items()}
+
+
 def test_reset_zeroes_what_a_turn_left_untaken(runner, tmp_path):
     """A turn that died before its reply (a timeout kill is a respawn, but a
     batch or a snapshot op in between is not) leaves counts behind: the next
@@ -102,11 +143,11 @@ def test_a_counter_that_fails_never_fails_the_turn(runner, monkeypatch):
 
 def test_shim_phases_are_stamped_under_fixed_names_as_numbers():
     body = {"shim": {"programs": 5, "exec_cache_misses": 0, "nodes": 306, "flushes": 1, "h2d_arrays": 8, "h2d_bytes": 1114112,
-                     "h2d_s": 0.0123456789, "donated_bytes": 4831838208, "aligned_stores": 26, "kernel_stores": 24, "histograms": 4, "fallbacks": 2, "host_s": 0.123456789, "minted_by_user_code": 7}}
+                     "h2d_s": 0.0123456789, "donated_bytes": 4831838208, "aligned_stores": 26, "kernel_stores": 24, "histograms": 4, "dots": 3, "dot_flops": 5280000000000, "ufunc_methods": 16, "fallbacks": 2, "host_s": 0.123456789, "minted_by_user_code": 7}}
     phases = CodeExecutor._shim_phases(body)
     assert phases == {
         "shim_programs": 5.0, "shim_exec_cache_misses": 0.0, "shim_nodes": 306.0, "shim_flushes": 1.0,
-        "shim_h2d_arrays": 8.0, "shim_h2d_bytes": 1114112.0, "shim_h2d": 0.012346, "shim_donated_bytes": 4831838208.0, "shim_aligned_stores": 26.0, "shim_kernel_stores": 24.0, "shim_histograms": 4.0, "shim_fallbacks": 2.0, "shim_host": 0.123457,
+        "shim_h2d_arrays": 8.0, "shim_h2d_bytes": 1114112.0, "shim_h2d": 0.012346, "shim_donated_bytes": 4831838208.0, "shim_aligned_stores": 26.0, "shim_kernel_stores": 24.0, "shim_histograms": 4.0, "shim_dots": 3.0, "shim_dot_flops": 5280000000000.0, "shim_ufunc_methods": 16.0, "shim_fallbacks": 2.0, "shim_host": 0.123457,
     }
     assert all(isinstance(v, float) for v in phases.values())
 
@@ -123,11 +164,22 @@ def test_a_block_from_the_users_process_is_read_as_numbers_only():
     assert phases == dict.fromkeys(SHIM_PHASES.values(), 0.0)
 
 
+@pytest.mark.parametrize("key", ["shim_dots", "shim_dot_flops", "shim_ufunc_methods"])
+def test_the_linalg_keys_are_stamped_and_are_no_latency(key):
+    """0 from a block that lacks the counter (a runner from before it), the
+    number where it has it, and in no histogram's allowlist."""
+    assert key in SHIM_PHASES.values()
+    name = next(name for name, stamped in SHIM_PHASES.items() if stamped == key)
+    assert CodeExecutor._shim_phases({"shim": {"programs": 1}})[key] == 0.0
+    assert CodeExecutor._shim_phases({"shim": {name: 17437680000000}})[key] == 17437680000000.0
+    assert key not in LATENCY_PHASES and key not in OBSERVED_PHASES and key not in STAGE_PHASES
+
+
 def test_no_shim_phase_is_a_latency_phase():
     """The histogram's allowlist and the perf observer's baselines see none
     of the new keys (the PR 6 / PR 7 discipline)."""
     keys = set(SHIM_PHASES.values())
-    assert len(keys) == 13 and tuple(SHIM_PHASES) == COUNTERS
+    assert len(keys) == 16 and tuple(SHIM_PHASES) == COUNTERS
     assert not keys & LATENCY_PHASES
     assert not keys & set(OBSERVED_PHASES)
     assert not keys & set(STAGE_PHASES)

@@ -307,6 +307,62 @@ def test_npbench_stencil_programs_are_one_aligned_pass_per_window_store(
         assert _device_bytes(compiled) + grids * grid_bytes < V5E_HBM_BYTES
 
 
+# (payload, the matrices the turn holds when its first value is asked for, the
+# `dot_general`s of its one big program)
+LINALG = [
+    ("gemm", lambda p: 4 * (p["NI"] * p["NJ"] + p["NI"] * p["NK"] + p["NK"] * p["NJ"]), 1),
+    ("k3mm", lambda p: 4 * (p["NI"] * p["NK"] + p["NK"] * p["NJ"] + p["NJ"] * p["NM"] + p["NM"] * p["NL"]
+                            + p["NI"] * p["NL"]), 3),
+    ("floyd_warshall", lambda p: 4 * p["N"] * p["N"], 0),
+]
+
+
+@pytest.mark.parametrize("payload, held_bytes, products", LINALG, ids=[case[0] for case in LINALG])
+def test_nplinalg_payload_programs_fit_one_chip(one_chip, monkeypatch, capsys, payload, held_bytes, products):
+    """`benchmarks/chip/payloads/gemm.py`, `k3mm.py` and `floyd_warshall.py`
+    at the run's sizes, through the shim with its jit swapped for the AOT one:
+    every program of the turn compiles for one v5e chip; the first, which
+    makes the matrices, multiplies them and takes the printed elements, fits
+    the chip's 16 GB and returns what the turn holds, with temporaries of
+    less than that (gemm's product is written where C goes; k3mm's two
+    intermediates); no operand of a product is held in bfloat16; and
+    floyd_warshall's sixteen steps are one program in s32 with no 64-bit
+    value of the matrix's size. The counters read the payloads' floors at
+    the run's sizes. Structure and sizes only."""
+    from bee_code_interpreter_fs_tpu.ops import npdispatch
+    from bee_code_interpreter_fs_tpu.ops.npdispatch import lazy
+
+    payloads = REPO_ROOT / "benchmarks" / "chip" / "payloads"
+    params = json.loads((payloads / f"{payload}.json").read_text())["params"]
+    aot = _shim_jits_ahead_of_time(monkeypatch, _AotJit(one_chip, zeros=_untouched_zeros))
+    npdispatch.install()
+    try:
+        lazy.counters.reset()
+        runpy.run_path(str(payloads / f"{payload}.py"), init_globals={"P": params}, run_name="__main__")
+        taken = lazy.counters.take()
+    finally:
+        npdispatch.uninstall()
+    assert capsys.readouterr().out.startswith(f"{payload} ")
+    floor = json.loads((payloads / f"{payload}.json").read_text())["floor"]
+    assert taken["dots"] == products and taken["fallbacks"] == 0 and taken["programs"] == len(aot.compiled) == 2
+    assert taken["dot_flops"] == eval(floor.get("flops", "0"), {"__builtins__": {}}, dict(params))
+    assert taken["ufunc_methods"] == (params["K"] if payload == "floyd_warshall" else 0)
+    main = aot.compiled[0]
+    text = main.as_text()
+    held = held_bytes(params)
+    memory = main.memory_analysis()
+    # what the program returns is what the turn holds (the picked elements besides)
+    assert held <= memory.output_size_in_bytes < 1.01 * held  # (rows padded to the tiling)
+    assert _device_bytes(main) < V5E_HBM_BYTES
+    assert memory.temp_size_in_bytes < held
+    if payload == "floyd_warshall":
+        assert "s64[" not in text and "f32[16800" not in text
+    else:
+        assert not re.search(r"bf16\[\d{4,},\d{4,}\]", text), "no operand of a product is rounded to bfloat16"
+    for compiled in aot.compiled:
+        assert _device_bytes(compiled) + held < V5E_HBM_BYTES
+
+
 @pytest.mark.parametrize("weighted", [False, True], ids=["counts", "weighted"])
 def test_the_histogram_is_one_program_with_its_edges_as_an_operand(one_chip, weighted):
     """`np.histogram` over `npfiles.c3`'s vector (`azimint_hist`: N 10,000,000,
