@@ -254,7 +254,7 @@ class LocalSandboxBackend(SandboxBackend):
                 id=sandbox_id,
                 url=urls[0],
                 chip_count=chip_count,
-                meta={"dir": str(self.root / sandbox_id)},
+                meta={"dir": str(self.root / sandbox_id), "shares_storage": True},
             )
 
         # Multi-host slice group: one executor process per "host", all joined
@@ -313,7 +313,11 @@ class LocalSandboxBackend(SandboxBackend):
             url=urls[0],
             chip_count=chip_count,
             host_urls=urls,
-            meta={"hosts": host_ids, "dirs": [str(self.root / h) for h in host_ids]},
+            meta={
+                "hosts": host_ids,
+                "dirs": [str(self.root / h) for h in host_ids],
+                "shares_storage": True,
+            },
         )
 
     async def _warm_sandbox(
@@ -437,6 +441,13 @@ class LocalSandboxBackend(SandboxBackend):
                 "APP_LISTEN_ADDR": "127.0.0.1:0",
                 "APP_WORKSPACE": str(workspace),
                 "APP_RUNTIME_PACKAGES": str(runtime_packages),
+                # This host holds the control plane's storage directory
+                # too: the server copies a turn's input files out of it
+                # itself (spawn()'s `shares_storage` says so to the
+                # control plane).
+                "APP_STORAGE_OBJECTS_DIR": str(
+                    Path(self.config.file_storage_path).resolve()
+                ),
                 "APP_WARM_RUNNER": "1" if self.config.executor_warm_runner else "0",
                 # Warm-up waits for our POST /warmup — issued only after the
                 # per-chip TPU slot is acquired, so concurrent spawns never

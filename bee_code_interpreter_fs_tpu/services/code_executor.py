@@ -3294,6 +3294,9 @@ class CodeExecutor:
                     raise
                 upload_span.set_attribute("bytes_moved", stats.upload_bytes)
                 upload_span.set_attribute(
+                    "bytes_copied", stats.upload_copied_bytes
+                )
+                upload_span.set_attribute(
                     "bytes_skipped", stats.upload_skipped_bytes
                 )
                 upload_span.set_attribute("files_moved", stats.upload_files)
@@ -5138,7 +5141,10 @@ class CodeExecutor:
         state = sandbox.meta.get("transfer")
         if not isinstance(state, SandboxTransfer):
             state = SandboxTransfer(
-                enabled=self.config.transfer_manifest_enabled
+                enabled=self.config.transfer_manifest_enabled,
+                # What the backend that spawned the sandbox knows: its
+                # hosts see the storage directory (backends/local.py).
+                shares_storage=bool(sandbox.meta.get("shares_storage")),
             )
             sandbox.meta["transfer"] = state
         return state
@@ -5203,7 +5209,7 @@ class CodeExecutor:
             )
         # Input files never fully buffer in control-plane memory (a multi-GB
         # session file times N hosts would otherwise blow the heap).
-        await asyncio.gather(
+        copied = await asyncio.gather(
             *(
                 self._upload_file(client, base, rel, object_id, manifest)
                 for base, rel, object_id, manifest in uploads
@@ -5212,6 +5218,12 @@ class CodeExecutor:
         stats.upload_files += len(uploads)
         stats.upload_bytes += sum(
             sizes[object_id] for _, _, object_id, _ in uploads
+        )
+        stats.upload_copied_files += sum(1 for by_host in copied if by_host)
+        stats.upload_copied_bytes += sum(
+            sizes[object_id]
+            for (_, _, object_id, _), by_host in zip(uploads, copied)
+            if by_host
         )
 
     async def _resync_manifest(
@@ -5316,14 +5328,40 @@ class CodeExecutor:
         rel: str,
         object_id: str,
         manifest: HostManifest,
-    ) -> None:
+    ) -> bool:
+        """One input file into one host's workspace. True where the host's
+        own server copied it from the storage directory, False where the
+        bytes were streamed to it."""
+        # A real content sha on a host that speaks the manifest protocol:
+        # the id is a claim both sides can check. Old binaries and legacy
+        # opaque ids get the plain streamed PUT.
+        negotiable = manifest.supports is not False and bool(
+            SHA256_HEX_RE.match(object_id)
+        )
+        if negotiable and manifest.copies:
+            # The object's name IS the sha256 of its bytes, and the host
+            # sees it: the server copies it inside the kernel.
+            try:
+                resp = await client.post(
+                    f"{base}/copy-from-storage/workspace/{rel}",
+                    headers={"X-Storage-Object": object_id},
+                    # The answer comes when the whole file is copied: no
+                    # byte on the wire meanwhile to restart the clock.
+                    timeout=httpx.Timeout(30.0, read=300.0),
+                )
+            except httpx.HTTPError as e:
+                raise ExecutorError(f"upload of {rel} failed: {e}")
+            if resp.status_code in (200, 304, 413):
+                self._upload_answered(resp, rel, object_id, manifest)
+                return True
+            # The object or the directory is not visible from there (or the
+            # binary is from before the route): the declaration was wrong,
+            # for this host's life. Stream this file.
+            manifest.copies = False
         # `If-None-Match: <sha of the body being sent>` lets the server skip
         # the disk write (304) when the file already holds these bytes —
         # e.g. a path re-uploaded after the control plane lost its cache.
-        # Old binaries ignore the header; legacy opaque ids can't claim one.
-        headers = {}
-        if manifest.supports is not False and SHA256_HEX_RE.match(object_id):
-            headers["If-None-Match"] = object_id
+        headers = {"If-None-Match": object_id} if negotiable else {}
 
         async def stream():
             async with self.storage.reader(object_id) as reader:
@@ -5339,6 +5377,14 @@ class CodeExecutor:
             )
         except httpx.HTTPError as e:
             raise ExecutorError(f"upload of {rel} failed: {e}")
+        self._upload_answered(resp, rel, object_id, manifest)
+        return False
+
+    @staticmethod
+    def _upload_answered(
+        resp: httpx.Response, rel: str, object_id: str, manifest: HostManifest
+    ) -> None:
+        """What the host's answer to an upload means, streamed or copied."""
         if resp.status_code == 304:
             # Conditional hit: the host proved it already has this content.
             manifest.record_upload(rel, object_id)

@@ -14,6 +14,11 @@ content is genuinely new to the receiver:
 - **Old-binary fallback** — a host that answers without hashes (plain-string
   ``files`` array, 404 on ``/workspace-manifest``) is remembered as legacy
   and gets exactly the pre-manifest behavior: full uploads, full downloads.
+- **Copy from storage** — a host whose backend declared that it sees the
+  storage directory (``Sandbox.meta["shares_storage"]``: the local backend)
+  copies an input file object -> workspace itself, inside the kernel; the
+  bytes never cross the control plane. A host that refuses the route once
+  keeps the streamed PUT from then on.
 
 State lives in ``Sandbox.meta["transfer"]`` so it travels with the sandbox
 through the pool; generation turnover (``/reset``) wipes the workspace, so
@@ -94,11 +99,13 @@ class HostManifest:
     host speaks the manifest protocol: ``None`` until observed, ``True``
     after any hashed response, ``False`` once a response proves it legacy —
     after which no resync is ever attempted again (the endpoint would 404
-    on every execute)."""
+    on every execute). ``copies`` says the host's server can copy an input
+    file from the storage directory itself: what the sandbox's backend
+    declared, until the host refuses the route once."""
 
-    __slots__ = ("entries", "supports", "disabled")
+    __slots__ = ("entries", "supports", "disabled", "copies")
 
-    def __init__(self, disabled: bool = False) -> None:
+    def __init__(self, disabled: bool = False, copies: bool = False) -> None:
         # Seeded empty-KNOWN: a sandbox's workspace starts empty at spawn,
         # and reset() restores this same state after a workspace wipe.
         self.entries: dict[str, str] | None = {}
@@ -106,6 +113,7 @@ class HostManifest:
         # Hard off (config kill switch): permanently legacy — no state
         # updates may ever resurrect negotiation for this host.
         self.disabled = disabled
+        self.copies = copies
         if disabled:
             self.mark_legacy()
 
@@ -170,16 +178,21 @@ class SandboxTransfer:
     """Per-sandbox transfer state: one HostManifest per host URL.
 
     ``enabled=False`` (config kill switch) pins every host to the legacy
-    full-transfer path without touching the wire protocol."""
+    full-transfer path without touching the wire protocol.
+    ``shares_storage`` is the backend's declaration that the sandbox's hosts
+    see the storage directory (see ``HostManifest.copies``)."""
 
-    def __init__(self, enabled: bool = True) -> None:
+    def __init__(self, enabled: bool = True, shares_storage: bool = False) -> None:
         self.enabled = enabled
+        self.shares_storage = shares_storage
         self._hosts: dict[str, HostManifest] = {}
 
     def host(self, base_url: str) -> HostManifest:
         manifest = self._hosts.get(base_url)
         if manifest is None:
-            manifest = HostManifest(disabled=not self.enabled)
+            manifest = HostManifest(
+                disabled=not self.enabled, copies=self.shares_storage
+            )
             self._hosts[base_url] = manifest
         return manifest
 
@@ -198,6 +211,10 @@ class TransferStats:
 
     upload_bytes: int = 0
     upload_files: int = 0
+    # Of those, what the sandbox's own server copied from the storage
+    # directory: counted in upload_bytes / upload_files too.
+    upload_copied_bytes: int = 0
+    upload_copied_files: int = 0
     upload_skipped_bytes: int = 0
     upload_skipped_files: int = 0
     download_bytes: int = 0
@@ -210,6 +227,7 @@ class TransferStats:
         timings, so both API surfaces carry them unchanged)."""
         return {
             "upload_bytes": float(self.upload_bytes),
+            "upload_copied_bytes": float(self.upload_copied_bytes),
             "upload_skipped_bytes": float(self.upload_skipped_bytes),
             "download_bytes": float(self.download_bytes),
             "download_skipped_bytes": float(self.download_skipped_bytes),
@@ -224,6 +242,8 @@ class TransferStats:
         metrics.transfer_bytes.inc(self.download_bytes, direction="download")
         metrics.transfer_files.inc(self.upload_files, direction="upload")
         metrics.transfer_files.inc(self.download_files, direction="download")
+        metrics.transfer_copied_bytes.inc(self.upload_copied_bytes)
+        metrics.transfer_copied_files.inc(self.upload_copied_files)
         metrics.transfer_skipped_bytes.inc(
             self.upload_skipped_bytes, direction="upload"
         )

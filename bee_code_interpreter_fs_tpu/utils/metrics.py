@@ -408,6 +408,16 @@ class ExecutorMetrics:
             "Workspace files actually moved, by direction.",
             ("direction",),
         )
+        self.transfer_copied_bytes = self.registry.counter(
+            "code_interpreter_transfer_copied_bytes_total",
+            "Input file bytes a sandbox's own server copied from the storage "
+            "directory (also counted as uploaded).",
+        )
+        self.transfer_copied_files = self.registry.counter(
+            "code_interpreter_transfer_copied_files_total",
+            "Input files a sandbox's own server copied from the storage "
+            "directory (also counted as uploaded).",
+        )
         self.transfer_skipped_bytes = self.registry.counter(
             "code_interpreter_transfer_skipped_bytes_total",
             "Workspace file bytes NOT moved thanks to manifest delta "

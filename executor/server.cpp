@@ -22,7 +22,10 @@
 // waits for POST /warmup), APP_RUNNER_READY_TIMEOUT (180), APP_AUTO_INSTALL_DEPS
 // (0), APP_DEFAULT_TIMEOUT (60), APP_MAX_OUTPUT_BYTES (10485760),
 // APP_WORKSPACE_MANIFEST (1; 0 = legacy wire format: no sha256 manifest,
-// plain-string `files` arrays, no /workspace-manifest route).
+// plain-string `files` arrays, no /workspace-manifest route),
+// APP_STORAGE_OBJECTS_DIR (unset; the control plane's content-addressed
+// storage directory where the backend that spawned this server knows it to be
+// visible from here: turns on POST /copy-from-storage/workspace/<rel>).
 //
 // Resource governance (limits.hpp): APP_LIMIT_MEMORY_BYTES,
 // APP_LIMIT_CPU_SECONDS, APP_LIMIT_NPROC, APP_LIMIT_NOFILE,
@@ -45,6 +48,7 @@
 
 #include <algorithm>
 #include <atomic>
+#include <climits>
 #include <condition_variable>
 #include <cstdarg>
 #include <cstdio>
@@ -206,6 +210,11 @@ struct FileSig {
   }
 };
 
+FileSig sig_of(const struct stat& st) {
+  return FileSig{st.st_mtim.tv_sec * 1000000000LL + st.st_mtim.tv_nsec,
+                 st.st_size};
+}
+
 void scan_dir(const std::string& base, const std::string& rel,
               std::map<std::string, FileSig>& out) {
   std::string dir = rel.empty() ? base : base + "/" + rel;
@@ -221,8 +230,7 @@ void scan_dir(const std::string& base, const std::string& rel,
     if (S_ISDIR(st.st_mode)) {
       scan_dir(base, rel_child, out);
     } else if (S_ISREG(st.st_mode)) {
-      out[rel_child] = FileSig{
-          st.st_mtim.tv_sec * 1000000000LL + st.st_mtim.tv_nsec, st.st_size};
+      out[rel_child] = sig_of(st);
     }
   }
   closedir(d);
@@ -282,10 +290,7 @@ bool hash_workspace_file(const std::string& workspace, const std::string& rel,
   close(fd);
   if (n < 0) return false;
   hex_out = hasher.hex();
-  if (sig_out) {
-    *sig_out = FileSig{st.st_mtim.tv_sec * 1000000000LL + st.st_mtim.tv_nsec,
-                       st.st_size};
-  }
+  if (sig_out) *sig_out = sig_of(st);
   return true;
 }
 
@@ -962,6 +967,11 @@ struct ServerState {
   // /workspace-manifest, If-None-Match ignored — exactly the pre-manifest
   // binary, which is also how the control plane's fallback path is tested.
   bool manifest_enabled = true;
+  // The control plane's storage directory (objects named by the sha256 of
+  // their bytes), stated by the backend that spawned this server when both
+  // live on one host (APP_STORAGE_OBJECTS_DIR). Empty = not visible from
+  // here: no copy route, every input file arrives through the PUT.
+  std::string storage_dir;
   // Fleet compile cache (JAX persistent compilation cache served over
   // HTTP): the dir JAX_COMPILATION_CACHE_DIR names, exposed as
   // GET /compile-cache-manifest + hash-negotiated PUT/GET under
@@ -1106,130 +1116,183 @@ bool split_target(const std::string& target, std::string& prefix, std::string& r
   return !rel.empty();
 }
 
-void handle_upload(const minihttp::Request& req, minihttp::Conn& conn) {
-  std::string prefix, rel;
-  if (!split_target(req.target, prefix, rel)) {
-    conn.drain_body();
-    conn.send_response(400, "application/json", "{\"error\":\"bad path\"}");
-    return;
-  }
-  const std::string* base = prefix_base(prefix);
-  if (!base) {
-    conn.drain_body();
-    conn.send_response(404, "application/json", "{\"error\":\"unknown prefix\"}");
-    return;
-  }
+// Where a transfer into a served directory lands, and the manifest that
+// keeps its books: what the streamed PUT and the copy from storage share.
+struct UploadTarget {
+  std::string prefix;
+  std::string rel;
+  const std::string* base = nullptr;
+  // nullptrs for unmanifested prefixes (see prefix_manifest)
   std::map<std::string, ManifestEntry>* mani = nullptr;
   std::mutex* mani_mutex = nullptr;
-  prefix_manifest(prefix, mani, mani_mutex);
-  bool manifested = mani != nullptr;
+};
+
+// Splits and resolves a transfer's target. Answers the refusal itself (400
+// bad path, 404 unknown prefix; the body drained) and returns false.
+bool resolve_upload_target(const std::string& target, minihttp::Conn& conn,
+                           UploadTarget& out) {
+  if (!split_target(target, out.prefix, out.rel)) {
+    conn.drain_body();
+    conn.send_response(400, "application/json", "{\"error\":\"bad path\"}");
+    return false;
+  }
+  out.base = prefix_base(out.prefix);
+  if (!out.base) {
+    conn.drain_body();
+    conn.send_response(404, "application/json", "{\"error\":\"unknown prefix\"}");
+    return false;
+  }
+  prefix_manifest(out.prefix, out.mani, out.mani_mutex);
+  return true;
+}
+
+// The conditional skip: the manifest says the file at `rel` already holds
+// exactly the content `sha` names, and the disk signature still matches
+// (user code may have touched it since).
+bool target_already_holds(const UploadTarget& t, const std::string& sha) {
+  if (!t.mani || sha.empty()) return false;
+  FileSig cached{0, 0};
+  {
+    std::lock_guard<std::mutex> lock(*t.mani_mutex);
+    auto it = t.mani->find(t.rel);
+    if (it == t.mani->end() || it->second.sha != sha) return false;
+    cached = it->second.sig;
+  }
+  struct stat st;
+  int fd = open_confined(*t.base, t.rel, O_RDONLY, 0, /*create_dirs=*/false);
+  bool fresh = fd >= 0 && fstat(fd, &st) == 0 && S_ISREG(st.st_mode) &&
+               sig_of(st) == cached;
+  if (fd >= 0) close(fd);
+  return fresh;
+}
+
+// Opens the target for writing (a fresh or truncated regular file, confined
+// to its base). Answers the refusal itself (the body drained) and returns -1.
+int open_upload_target(const UploadTarget& t, minihttp::Conn& conn) {
+  int fd = open_confined(*t.base, t.rel, O_WRONLY | O_CREAT | O_TRUNC, 0644,
+                         /*create_dirs=*/true);
+  if (fd < 0) {
+    int status = errno == ELOOP || errno == ENOTDIR ? 403 : 500;
+    conn.drain_body();
+    conn.send_response(status, "application/json",
+                       "{\"error\":\"open failed (confined)\"}");
+  }
+  return fd;
+}
+
+// Workspace disk quota guards the transfer paths too: without it a client
+// (or a compromised control plane) could fill the sandbox disk through
+// uploads that never run any code. Returns the bytes this upload may still
+// write (LLONG_MAX where no quota applies): usage is measured once at upload
+// start, after O_TRUNC zeroed any file being overwritten. With the manifest
+// on, usage comes from the cached entry sizes (O(entries), no IO) — a full
+// recursive walk per upload would make an N-file sync O(N^2) stats; without
+// it, the walk.
+long long upload_quota_room(const UploadTarget& t) {
+  long long disk_cap =
+      t.prefix == "workspace" ? g_state.limit_caps.disk_bytes : 0;
+  if (disk_cap <= 0) return LLONG_MAX;
+  long long usage_before = 0;
+  if (t.mani) {
+    // Exclude the entry for the path being overwritten: O_TRUNC already
+    // freed those bytes, so counting the stale size would 413 legitimate
+    // re-uploads of changed files (the delta-sync's normal path) on any
+    // workspace near half its quota.
+    std::lock_guard<std::mutex> lock(*t.mani_mutex);
+    for (const auto& [entry_rel, entry] : *t.mani)
+      if (entry_rel != t.rel) usage_before += entry.sig.size;
+  } else {
+    usage_before = limits::dir_usage_bytes(*t.base);
+  }
+  return disk_cap - usage_before;
+}
+
+// Over quota: give the quota back (truncate what was written), drop the
+// stale manifest entry, and answer with the typed violation. Closes fd.
+void refuse_over_quota(const UploadTarget& t, int fd, minihttp::Conn& conn) {
+  ftruncate(fd, 0);
+  close(fd);
+  if (t.mani) {
+    std::lock_guard<std::mutex> lock(*t.mani_mutex);
+    t.mani->erase(t.rel);
+  }
+  conn.drain_body();
+  conn.send_response(413, "application/json",
+                     "{\"error\":\"workspace disk quota exceeded\","
+                     "\"violation\":\"disk_quota\"}");
+}
+
+// The upload landed: the manifest learns the sha now (where there is one),
+// so the post-execute scan never rehashes these bytes. Closes fd.
+void finish_upload(const UploadTarget& t, int fd, long long total,
+                   const std::string& sha, minihttp::Conn& conn) {
+  struct stat st;
+  bool have_sig = fstat(fd, &st) == 0;
+  close(fd);
+  minijson::Object resp;
+  resp["path"] = minijson::Value("/" + t.prefix + "/" + t.rel);
+  resp["size"] = minijson::Value(static_cast<int64_t>(total));
+  if (t.mani) {
+    if (have_sig) {
+      std::lock_guard<std::mutex> lock(*t.mani_mutex);
+      (*t.mani)[t.rel] = ManifestEntry{sha, sig_of(st)};
+    }
+    resp["sha256"] = minijson::Value(sha);
+  }
+  conn.send_response(200, "application/json", minijson::Value(resp).dump());
+}
+
+bool write_all(int fd, const char* data, size_t size) {
+  size_t off = 0;
+  while (off < size) {
+    ssize_t n = write(fd, data + off, size - off);
+    if (n < 0) {
+      if (errno == EINTR) continue;
+      return false;
+    }
+    off += static_cast<size_t>(n);
+  }
+  return true;
+}
+
+void handle_upload(const minihttp::Request& req, minihttp::Conn& conn) {
+  UploadTarget t;
+  if (!resolve_upload_target(req.target, conn, t)) return;
   // Conditional upload: `If-None-Match: <sha256 of the body being sent>`.
-  // When the manifest says the file at `rel` already holds exactly that
-  // content (and the disk signature still matches — user code may have
-  // touched it since), the body is drained and skipped with a 304: no disk
-  // write, no rehash. On mismatch the PUT proceeds as a normal upload — the
-  // header is a claim about the body, so writing it is always correct.
+  // On a hit the body is drained and skipped with a 304: no disk write, no
+  // rehash. On mismatch the PUT proceeds as a normal upload — the header is
+  // a claim about the body, so writing it is always correct.
   std::string cond = req.header("if-none-match");
   if (!cond.empty() && cond.front() == '"' && cond.back() == '"' && cond.size() >= 2)
     cond = cond.substr(1, cond.size() - 2);
-  if (manifested && !cond.empty()) {
-    bool matches = false;
-    FileSig cached{0, 0};
-    {
-      std::lock_guard<std::mutex> lock(*mani_mutex);
-      auto it = mani->find(rel);
-      if (it != mani->end() && it->second.sha == cond) {
-        matches = true;
-        cached = it->second.sig;
-      }
-    }
-    if (matches) {
-      struct stat st;
-      int fd = open_confined(*base, rel, O_RDONLY, 0, /*create_dirs=*/false);
-      bool fresh = fd >= 0 && fstat(fd, &st) == 0 && S_ISREG(st.st_mode) &&
-                   FileSig{st.st_mtim.tv_sec * 1000000000LL + st.st_mtim.tv_nsec,
-                           st.st_size} == cached;
-      if (fd >= 0) close(fd);
-      if (fresh) {
-        conn.drain_body();
-        conn.send_response(304, "application/json", "");
-        return;
-      }
-    }
-  }
-  int fd = open_confined(*base, rel, O_WRONLY | O_CREAT | O_TRUNC, 0644,
-                         /*create_dirs=*/true);
-  if (fd < 0) {
+  if (target_already_holds(t, cond)) {
     conn.drain_body();
-    int status = errno == ELOOP || errno == ENOTDIR ? 403 : 500;
-    conn.send_response(status, "application/json",
-                       "{\"error\":\"open failed (confined)\"}");
+    conn.send_response(304, "application/json", "");
     return;
   }
-  // Workspace disk quota guards the streaming path too: without it a client
-  // (or a compromised control plane) could fill the sandbox disk through
-  // PUTs that never run any code. Usage is measured once at upload start
-  // (after O_TRUNC zeroed any file being overwritten) and this body's bytes
-  // count against the remainder. With the manifest on, usage comes from the
-  // cached entry sizes (O(entries), no IO) — a full recursive walk per PUT
-  // would make an N-file sync O(N^2) stats; without it, the walk.
-  long long disk_cap =
-      prefix == "workspace" ? g_state.limit_caps.disk_bytes : 0;
-  long long usage_before = 0;
-  if (disk_cap > 0) {
-    if (manifested) {
-      // Exclude the entry for the path being overwritten: O_TRUNC above
-      // already freed those bytes, so counting the stale size would 413
-      // legitimate re-uploads of changed files (the delta-sync's normal
-      // path) on any workspace near half its quota.
-      std::lock_guard<std::mutex> lock(*mani_mutex);
-      for (const auto& [entry_rel, entry] : *mani)
-        if (entry_rel != rel) usage_before += entry.sig.size;
-    } else {
-      usage_before = limits::dir_usage_bytes(*base);
-    }
-  }
-  // Stream-hash while writing: the manifest learns the sha at upload time,
-  // so the post-execute scan never rehashes bytes the PUT already saw.
+  int fd = open_upload_target(t, conn);
+  if (fd < 0) return;
+  long long room = upload_quota_room(t);
+  // Stream-hash while writing.
   minisha::Sha256 hasher;
-  size_t total = 0;
+  long long total = 0;
   try {
     std::string chunk;
     while (true) {
       chunk.clear();
       if (conn.read_body_some(chunk, 1 << 20) == 0) break;
-      if (disk_cap > 0 &&
-          usage_before + static_cast<long long>(total + chunk.size()) >
-              disk_cap) {
-        // Over quota: give the quota back (truncate what we wrote), drop
-        // the stale manifest entry, and answer with the typed violation.
-        ftruncate(fd, 0);
-        close(fd);
-        if (manifested) {
-          std::lock_guard<std::mutex> lock(*mani_mutex);
-          mani->erase(rel);
-        }
-        conn.drain_body();
-        conn.send_response(
-            413, "application/json",
-            "{\"error\":\"workspace disk quota exceeded\","
-            "\"violation\":\"disk_quota\"}");
+      if (total + static_cast<long long>(chunk.size()) > room) {
+        refuse_over_quota(t, fd, conn);
         return;
       }
-      if (manifested) hasher.update(chunk.data(), chunk.size());
-      size_t off = 0;
-      while (off < chunk.size()) {
-        ssize_t n = write(fd, chunk.data() + off, chunk.size() - off);
-        if (n < 0) {
-          if (errno == EINTR) continue;
-          close(fd);
-          conn.send_response(500, "application/json",
-                             "{\"error\":\"write failed\"}");
-          return;
-        }
-        off += static_cast<size_t>(n);
+      if (t.mani) hasher.update(chunk.data(), chunk.size());
+      if (!write_all(fd, chunk.data(), chunk.size())) {
+        close(fd);
+        conn.send_response(500, "application/json",
+                           "{\"error\":\"write failed\"}");
+        return;
       }
-      total += chunk.size();
+      total += static_cast<long long>(chunk.size());
     }
   } catch (...) {
     // Client aborted mid-body (the control plane cancels sibling uploads
@@ -1238,24 +1301,110 @@ void handle_upload(const minihttp::Request& req, minihttp::Conn& conn) {
     close(fd);
     throw;
   }
-  struct stat st;
-  bool have_sig = fstat(fd, &st) == 0;
-  close(fd);
-  minijson::Object resp;
-  resp["path"] = minijson::Value("/" + prefix + "/" + rel);
-  resp["size"] = minijson::Value(static_cast<int64_t>(total));
-  if (manifested) {
-    std::string sha = hasher.hex();
-    if (have_sig) {
-      std::lock_guard<std::mutex> lock(*mani_mutex);
-      (*mani)[rel] = ManifestEntry{
-          sha,
-          FileSig{st.st_mtim.tv_sec * 1000000000LL + st.st_mtim.tv_nsec,
-                  st.st_size}};
+  finish_upload(t, fd, total, t.mani ? hasher.hex() : std::string(), conn);
+}
+
+bool is_sha256_hex(const std::string& s) {
+  if (s.size() != 64) return false;
+  for (char c : s)
+    if (!((c >= '0' && c <= '9') || (c >= 'a' && c <= 'f'))) return false;
+  return true;
+}
+
+// Copies `size` bytes from src to dst at their file offsets, inside the
+// kernel (which reflinks where the filesystem can); storage on another
+// filesystem, or a kernel without the call, gets a plain read / write loop
+// from wherever the copy stands.
+bool copy_object(int src, int dst, long long size) {
+  long long done = 0;
+  bool in_kernel = true;
+  std::vector<char> buf;
+  while (done < size) {
+    ssize_t n;
+    if (in_kernel) {
+      n = copy_file_range(src, nullptr, dst, nullptr,
+                          static_cast<size_t>(size - done), 0);
+      if (n < 0 && (errno == EXDEV || errno == ENOSYS || errno == EINVAL ||
+                    errno == EOPNOTSUPP)) {
+        in_kernel = false;
+        buf.resize(1 << 20);
+        continue;
+      }
+    } else {
+      n = read(src, buf.data(), buf.size());
+      if (n > 0 && !write_all(dst, buf.data(), static_cast<size_t>(n)))
+        return false;
     }
-    resp["sha256"] = minijson::Value(sha);
+    if (n < 0 && errno == EINTR) continue;
+    if (n <= 0) return false;  // an error, or an object shorter than its stat
+    done += n;
   }
-  conn.send_response(200, "application/json", minijson::Value(resp).dump());
+  return true;
+}
+
+const char kCopyRoute[] = "/copy-from-storage";
+
+// POST /copy-from-storage/workspace/<rel> with `x-storage-object: <sha256>`:
+// an input file reaches the workspace as ONE copy inside the kernel from the
+// storage object its sha names, where this server was spawned beside the
+// storage directory — no byte through the control plane, none hashed here
+// (the object's name IS the sha256 of its bytes: storage renames an object
+// into place only when whole). A route of its own, so that a binary from
+// before it answers 404 and the control plane keeps the PUT. The target is
+// a FRESH inode, never a link: user code that writes an input in place
+// cannot touch the object. Confinement, conditional skip, disk quota,
+// manifest entry and reply are the PUT's.
+void handle_copy_from_storage(const minihttp::Request& req, minihttp::Conn& conn) {
+  conn.drain_body();
+  if (g_state.storage_dir.empty() || !g_state.manifest_enabled) {
+    conn.send_response(404, "application/json", "{\"error\":\"no route\"}");
+    return;
+  }
+  UploadTarget t;
+  if (!resolve_upload_target(req.target.substr(sizeof(kCopyRoute) - 1), conn, t))
+    return;
+  if (t.prefix != "workspace") {
+    conn.send_response(404, "application/json", "{\"error\":\"unknown prefix\"}");
+    return;
+  }
+  std::string id = req.header("x-storage-object");
+  if (!is_sha256_hex(id)) {
+    conn.send_response(400, "application/json", "{\"error\":\"bad object id\"}");
+    return;
+  }
+  int src = open_confined(g_state.storage_dir, id, O_RDONLY, 0,
+                          /*create_dirs=*/false);
+  struct stat st;
+  if (src < 0 || fstat(src, &st) != 0 || !S_ISREG(st.st_mode)) {
+    if (src >= 0) close(src);
+    conn.send_response(404, "application/json", "{\"error\":\"no such object\"}");
+    return;
+  }
+  if (target_already_holds(t, id)) {
+    close(src);
+    conn.send_response(304, "application/json", "");
+    return;
+  }
+  int fd = open_upload_target(t, conn);
+  if (fd < 0) {
+    close(src);
+    return;
+  }
+  // The whole size is known before a byte is written.
+  if (st.st_size > upload_quota_room(t)) {
+    close(src);
+    refuse_over_quota(t, fd, conn);
+    return;
+  }
+  bool copied = copy_object(src, fd, st.st_size);
+  close(src);
+  if (!copied) {
+    ftruncate(fd, 0);
+    close(fd);
+    conn.send_response(500, "application/json", "{\"error\":\"copy failed\"}");
+    return;
+  }
+  finish_upload(t, fd, st.st_size, id, conn);
 }
 
 // GET /workspace-manifest — the resync surface: the full rel -> sha256 map
@@ -3068,6 +3217,9 @@ void route(const minihttp::Request& req, minihttp::Conn& conn) {
     handle_device_stats(req, conn);
   } else if (req.method == "GET" && req.target == "/readyz") {
     handle_readyz(req, conn);
+  } else if (req.method == "POST" &&
+             req.target.rfind(std::string(kCopyRoute) + "/", 0) == 0) {
+    handle_copy_from_storage(req, conn);
   } else if (req.method == "PUT") {
     handle_upload(req, conn);
   } else if (req.method == "GET" || req.method == "HEAD") {
@@ -3111,6 +3263,7 @@ int main() {
       g_state.warm_enabled && env_flag("APP_WARM_IMPORT_JAX", true);
   g_state.auto_install = env_flag("APP_AUTO_INSTALL_DEPS", false);
   g_state.manifest_enabled = env_flag("APP_WORKSPACE_MANIFEST", true);
+  g_state.storage_dir = env_or("APP_STORAGE_OBJECTS_DIR", "");
   {
     // The fleet compile cache serves the same dir JAX writes its
     // persistent compilation cache to; no dir (or APP_COMPILE_CACHE=0)

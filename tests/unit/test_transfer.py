@@ -157,12 +157,14 @@ def test_sandbox_transfer_reset_covers_all_hosts():
 def test_stats_phases_blob():
     stats = TransferStats(
         upload_bytes=10,
+        upload_copied_bytes=5,
         upload_skipped_bytes=20,
         download_bytes=30,
         download_skipped_bytes=40,
     )
     assert stats.as_phases() == {
         "upload_bytes": 10.0,
+        "upload_copied_bytes": 5.0,
         "upload_skipped_bytes": 20.0,
         "download_bytes": 30.0,
         "download_skipped_bytes": 40.0,
