@@ -92,12 +92,10 @@ def uninstall() -> None:
 
 
 def take_counters() -> dict | None:
-    """What the shim did since this was last called (`lazy.Counters`: programs,
-    exec_cache_misses, nodes, flushes, h2d_arrays, h2d_bytes, h2d_s, donated_bytes,
-    aligned_stores, kernel_stores, histograms, dots, dot_flops, ufunc_methods,
-    fallbacks, host_s), and
-    zero it; None where no shim is installed. The warm runner calls it at the
-    end of a turn's user code, for the reply, and on /reset."""
+    """What the shim did since this was last called (`lazy.Counters`, whose
+    docstring lists the fields and what each means), and zero it; None where
+    no shim is installed. The warm runner calls it at the end of a turn's
+    user code, for the reply, and on /reset."""
     if not _installed:
         return None
     from . import lazy

@@ -67,7 +67,11 @@ program returns its outputs in the order in which jax pairs them with the
 donated leaves they were stored into (`_paired`).
 
 `counters` counts what the shim did since it was last taken (the warm runner
-takes it at the end of each turn and stamps it into the reply).
+takes it at the end of each turn and stamps it into the reply), and times the
+stages of a turn's user code: numpy's reads of files (`read`), the copies to
+the device (`ship`), the shim's own host work, the calls that hand the device
+a program (`dispatched`), the waits for a value (`wait`) and the copies back
+(`fetch`).
 """
 
 from __future__ import annotations
@@ -103,13 +107,31 @@ _REF_STATIC = 2
 class Counters:
     """What the shim did since it was last taken. The warm runner takes it at
     the end of a turn's user code and zeroes it on /reset; the control plane
-    stamps it into Result.phases as `shim_<name>` (`host_s` as `shim_host`).
+    stamps it into Result.phases as `shim_<name>`, a `_s` left off (`host_s` as
+    `shim_host`): `code_executor.SHIM_PHASES`. THE list of the fields and
+    what each means is this one; `FIELDS` is read from `reset`.
+
+    The six `_s` fields are the STAGES of a turn's user code: every second
+    the shim spends lies in exactly one of them (a stage that runs inside
+    another's clock is left out of it, `spent`), so that with the
+    remainder (the user's own interpreter, stock numpy on host arrays,
+    fallbacks) they tile the runner's `user_code` stage. Each but `host_s`
+    is also an annotation of a running capture (`shim.load`, `shim.h2d`,
+    `shim.materialize` for the dispatch, `shim.wait`, `shim.d2h`).
 
     programs           executions of a compiled runner
     exec_cache_misses  runners built, that is traced (a clear-all of the runner
                        cache at its limit shows here as the misses that follow)
     nodes              nodes those programs executed
     flushes            materializations forced by MAX_GRAPH_NODES
+    load_files         calls of numpy's own `fromfile`, `load` and `frombuffer`
+                       under the shim (`read`): each file or buffer read,
+                       whether its array was then placed on the device or
+                       stayed numpy's (under the threshold, a wide integer)
+    load_bytes         `nbytes` of the arrays those calls returned (an `.npz`
+                       archive, whose members numpy reads later, counts 0)
+    load_s             seconds inside those calls: numpy's read alone, before
+                       the array is placed
     h2d_arrays         host arrays shipped to the device, each copy one: by
                        `ship`, the one place the shim makes such a copy (an
                        array placed when it is read from a file, an ndarray
@@ -120,7 +142,8 @@ class Counters:
     h2d_bytes          the bytes of those copies, as they lie on the device
     h2d_s              seconds inside those copies, until the runtime has taken
                        the bytes: the host's part. The copy over the link goes
-                       on behind it and shows in the program that waits for it
+                       on behind it and shows in `wait_s` of whoever asks
+                       for a value that needs it
     donated_bytes      leaves donated to the program that consumed them
     aligned_stores     window stores (`a[1:-1, 1:-1] = f(b[...])`) that a
                        program executed over the array's full shape
@@ -150,35 +173,55 @@ class Counters:
                        stock numpy after all (`np.fromfunction` of a function
                        a TpuArray cannot serve, a jnp function that refused
                        its arguments): correct, and seconds of host numpy
-    host_s             seconds inside `build_node` and `materialize`, the call
-                       of the compiled runner left out
+    host_s             seconds inside `build_node` and `materialize`, less what
+                       the other stages took inside them (the compiled
+                       runner's call, a leaf shipped on the way)
+    dispatch_s         seconds inside the calls that hand the device a program
+                       (`dispatched`): a compiled runner's (`_run`), an eager
+                       `jnp` call's (`shim.eager_device`), the histogram
+                       program's. The enqueue; on a miss of the runner cache
+                       the trace and XLA's compile
+    wait_s             seconds the host was blocked until a device value it
+                       had asked for was ready: `block_until_ready` on the
+                       forced array, in `fetch` (between asking for the copy
+                       and taking it) and in `TpuArray.block_until_ready`
+    d2h_arrays         device arrays copied to the host, each copy one: by
+                       `fetch`, the one place the shim makes such a copy
+                       (`__array__`, `float`, `print`, `tolist`, `tofile`, ...)
+    d2h_bytes          the bytes of those copies
+    d2h_s              seconds inside those copies, after the wait
     """
-
-    FIELDS = ("programs", "exec_cache_misses", "nodes", "flushes", "h2d_arrays", "h2d_bytes",
-              "h2d_s", "donated_bytes", "aligned_stores", "kernel_stores", "histograms", "dots",
-              "dot_flops", "ufunc_methods", "fallbacks", "host_s")
 
     def __init__(self) -> None:
         self.reset()
 
     def reset(self) -> None:
         self.programs = self.exec_cache_misses = self.nodes = self.flushes = 0
-        self.h2d_arrays = self.h2d_bytes = self.donated_bytes = 0
-        self.aligned_stores = self.kernel_stores = self.histograms = self.fallbacks = 0
-        self.dots = self.dot_flops = self.ufunc_methods = 0
-        self.h2d_s = self.host_s = 0.0
+        self.load_files = self.load_bytes = 0
+        self.load_s = 0.0
+        self.h2d_arrays = self.h2d_bytes = 0
+        self.h2d_s = 0.0
+        self.donated_bytes = self.aligned_stores = self.kernel_stores = self.histograms = 0
+        self.dots = self.dot_flops = self.ufunc_methods = self.fallbacks = 0
+        self.host_s = self.dispatch_s = self.wait_s = 0.0
+        self.d2h_arrays = self.d2h_bytes = 0
+        self.d2h_s = 0.0
         self._depth = 0  # build_node -> flush -> materialize nest: count once
         self._entered = self._outside = 0.0
 
     def take(self) -> dict:
-        taken = {name: getattr(self, name) for name in self.FIELDS}
-        taken["h2d_s"], taken["host_s"] = round(taken["h2d_s"], 6), round(taken["host_s"], 6)
+        taken = {name: round(value, 6) if name.endswith("_s") else value
+                 for name, value in vars(self).items() if not name.startswith("_")}
         self.reset()
         return taken
 
-    def leave_out(self, seconds: float) -> None:
-        """Time that passed inside the clock and is not the shim's own."""
-        self._outside += seconds
+    def spent(self, stage: str, since: float) -> None:
+        """The seconds since `since` (a `perf_counter` reading) are `stage`'s;
+        where they passed inside the clock of `host_s`, they are not its too."""
+        seconds = time.perf_counter() - since
+        setattr(self, stage, getattr(self, stage) + seconds)
+        if self._depth:
+            self._outside += seconds
 
     def __enter__(self) -> None:
         self._depth += 1
@@ -192,7 +235,21 @@ class Counters:
             self._outside = 0.0
 
 
+Counters.FIELDS = tuple(name for name in vars(Counters()) if not name.startswith("_"))
 counters = Counters()
+
+
+def read(np_fn, *args, **kwargs):
+    """`np_fn(*args, **kwargs)`, one of numpy's own reads of a file or a
+    buffer (`fromfile`, `load`, `frombuffer`), counted and timed before the
+    array is placed; inside a capture, seen (`shim.load`)."""
+    started = time.perf_counter()
+    with jax.profiler.TraceAnnotation("shim.load"):
+        loaded = np_fn(*args, **kwargs)
+    counters.spent("load_s", started)
+    counters.load_files += 1
+    counters.load_bytes += getattr(loaded, "nbytes", 0)
+    return loaded
 
 
 def ship(host, dtype=None) -> jax.Array:
@@ -203,10 +260,59 @@ def ship(host, dtype=None) -> jax.Array:
     started = time.perf_counter()
     with jax.profiler.TraceAnnotation("shim.h2d"):
         shipped = jnp.asarray(host, dtype=dtype)
+    counters.spent("h2d_s", started)
     counters.h2d_arrays += 1
     counters.h2d_bytes += shipped.nbytes
-    counters.h2d_s += time.perf_counter() - started
     return shipped
+
+
+def dispatched(fn, *args, **kwargs):
+    """`fn(*args, **kwargs)`, a call that hands the device a program: its
+    seconds are `dispatch_s` (the enqueue; a first call's trace and compile)."""
+    started = time.perf_counter()
+    try:
+        return fn(*args, **kwargs)
+    finally:
+        counters.spent("dispatch_s", started)
+
+
+def wait(arr: jax.Array) -> jax.Array:
+    """`arr` once the device has made it: the host blocked meanwhile, counted
+    in `wait_s` and, inside a capture, seen (`shim.wait`)."""
+    started = time.perf_counter()
+    with jax.profiler.TraceAnnotation("shim.wait"):
+        arr.block_until_ready()
+    counters.spent("wait_s", started)
+    return arr
+
+
+def fetch(arr: jax.Array) -> real_np.ndarray:
+    """`arr`, an array on the device, as an ndarray on the host: the one
+    place the shim copies to the host, beside `ship` for the way there, so
+    that every copy is counted, timed and, inside a capture, seen (`shim.d2h`).
+    The wait for the value comes first and is a stage of its own (`wait`):
+    only call this where the value was about to be copied out anyway. The
+    copy is ASKED for before the wait, as `np.asarray` alone would ask: the
+    runtime starts it when the value is made, with no trip through the host
+    in between, and `d2h_s` is what of it is left after the wait."""
+    arr.copy_to_host_async()
+    wait(arr)
+    started = time.perf_counter()
+    with jax.profiler.TraceAnnotation("shim.d2h"):
+        host = real_np.asarray(arr)
+    counters.spent("d2h_s", started)
+    counters.d2h_arrays += 1
+    counters.d2h_bytes += host.nbytes
+    return host
+
+
+def fetch_scalar(arr: jax.Array, convert):
+    """`convert(arr)` for `float`, `int`, `bool` and `complex`, through
+    `fetch`. What jax would refuse before it copied anything (an array that
+    is no scalar) it refuses here: its own error."""
+    if arr.ndim and not (convert is bool and arr.size == 1):
+        return convert(arr)
+    return convert(fetch(arr))
 
 
 class Node:
@@ -1068,7 +1174,7 @@ def _run(roots: list[Node]) -> None:
             program = build("")
             outs = program.runner(*leaves)
     counters.kernel_stores += program.kernel_stores
-    counters.leave_out(time.perf_counter() - called)
+    counters.spent("dispatch_s", called)
     for i, value in zip(program.returned, outs):
         node = lin.nodes[i]
         for owner in node.live_owners():

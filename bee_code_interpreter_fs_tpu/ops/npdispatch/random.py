@@ -142,9 +142,7 @@ class RandomShim(types.ModuleType):
             return TpuArray(jax.random.permutation(self._next_key(), x._arr))
         if isinstance(x, (int, real_np.integer)) and int(x) >= self._threshold:
             return TpuArray(jax.random.permutation(self._next_key(), int(x)))
-        return real_np.random.permutation(
-            real_np.asarray(x._arr) if isinstance(x, TpuArray) else x
-        )
+        return real_np.random.permutation(x)
 
     def shuffle(self, x):
         if isinstance(x, TpuArray):

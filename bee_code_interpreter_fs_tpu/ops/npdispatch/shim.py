@@ -237,7 +237,7 @@ def _unwrap_jnp(value):
 def _unwrap_np(value):
     """Convert shim-level values into host numpy ones (for fallback)."""
     if isinstance(value, TpuArray):
-        return real_np.asarray(value._arr)
+        return value._host()
     if isinstance(value, (tuple, list)):
         return type(value)(_unwrap_np(v) for v in value)
     return value
@@ -258,11 +258,10 @@ def eager_device(fn, args, kwargs):
     """Run the jnp op eagerly on device; NotImplemented on fallback errors
     (object dtype, unsupported kwarg, ...) so callers can try host numpy."""
     try:
+        args = _unwrap_jnp(list(args))
+        kwargs = {k: _unwrap_jnp(v) for k, v in kwargs.items()}
         with lazy.precision_scope():
-            result = fn(
-                *_unwrap_jnp(list(args)),
-                **{k: _unwrap_jnp(v) for k, v in kwargs.items()},
-            )
+            result = lazy.dispatched(fn, *args, **kwargs)
     except _FALLBACK_ERRORS:
         return NotImplemented
     return _result_wrap(result)
@@ -352,6 +351,15 @@ class TpuArray:
     def _arr(self) -> jax.Array:
         return self._force()
 
+    def _host(self) -> real_np.ndarray:
+        """The value on the host: forced, waited for, copied (`lazy.fetch`).
+        Every method that gives the caller a host value goes through here or
+        through `_scalar`, so that each copy is counted once."""
+        return lazy.fetch(self._force())
+
+    def _scalar(self, convert):
+        return lazy.fetch_scalar(self._force(), convert)
+
     @property
     def _aval(self):
         if self._node is not None:
@@ -366,7 +374,7 @@ class TpuArray:
 
     # -- interop -----------------------------------------------------------
     def __array__(self, dtype=None, copy=None):
-        host = real_np.asarray(self._arr)
+        host = self._host()
         return host.astype(dtype) if dtype is not None else host
 
     def __jax_array__(self):
@@ -410,7 +418,7 @@ class TpuArray:
         return result
 
     def block_until_ready(self):
-        self._force().block_until_ready()
+        lazy.wait(self._force())
         return self
 
     @property
@@ -455,7 +463,7 @@ class TpuArray:
 
     @property
     def flat(self):
-        return iter(real_np.asarray(self._arr).flat)
+        return iter(self._host().flat)
 
     # -- indexing ------------------------------------------------------------
     def __getitem__(self, idx):
@@ -490,32 +498,32 @@ class TpuArray:
             raise TypeError("iteration over a 0-d array")
         if self.ndim == 1:
             # iterate on host: per-element device reads would be pathological
-            return iter(real_np.asarray(self._arr))
+            return iter(self._host())
         return (TpuArray(row) for row in self._arr)
 
     # -- scalar coercion ------------------------------------------------------
     def __bool__(self):
-        return bool(self._arr)
+        return self._scalar(bool)
 
     def __float__(self):
-        return float(self._arr)
+        return self._scalar(float)
 
     def __int__(self):
-        return int(self._arr)
+        return self._scalar(int)
 
     def __index__(self):
-        return int(self._arr)
+        return self._scalar(int)
 
     def __complex__(self):
-        return complex(self._arr)
+        return self._scalar(complex)
 
     def __repr__(self):
-        return repr(real_np.asarray(self._arr)).replace("array(", "tpuarray(", 1)
+        return repr(self._host()).replace("array(", "tpuarray(", 1)
 
     def __format__(self, spec):
         if self.ndim == 0:
-            return format(self._arr.item(), spec)
-        return format(real_np.asarray(self._arr), spec)
+            return format(self._host().item(), spec)
+        return format(self._host(), spec)
 
     def __hash__(self):
         raise TypeError("unhashable type: 'TpuArray'")
@@ -528,16 +536,16 @@ class TpuArray:
         if kwargs.get("order", "K") not in ("K", "C", "A") or kwargs.get(
             "casting", "unsafe"
         ) != "unsafe":
-            return real_np.asarray(self._arr).astype(dtype, **kwargs)
+            return self._host().astype(dtype, **kwargs)
         if not _x64_enabled() and _dtype_name(dtype) in _WIDE_INT_NAMES:
             # jax would silently canonicalize int64->int32 (wrap); honor the
             # requested width exactly on host instead.
             _announce_policy_once()
-            return real_np.asarray(self._arr).astype(dtype, **kwargs)
+            return self._host().astype(dtype, **kwargs)
         dtype = canonical_dtype(dtype)
         result = self._lazy_or_eager("astype", lazy.astype_op, (self, dtype), {})
         if result is NotImplemented:  # e.g. object dtype — host numpy semantics
-            return real_np.asarray(self._arr).astype(dtype, **kwargs)
+            return self._host().astype(dtype, **kwargs)
         return result
 
     def reshape(self, *shape, order="C"):
@@ -571,16 +579,16 @@ class TpuArray:
         return TpuArray(jnp.array(self._arr, copy=True))
 
     def tolist(self):
-        return real_np.asarray(self._arr).tolist()
+        return self._host().tolist()
 
     def item(self, *args):
-        return self._arr.item(*args)
+        return self._host().item(*args)
 
     def tobytes(self, order="C"):
-        return real_np.asarray(self._arr).tobytes(order)
+        return self._host().tobytes(order)
 
     def tofile(self, fid, sep="", format="%s"):
-        real_np.asarray(self._arr).tofile(fid, sep=sep, format=format)
+        self._host().tofile(fid, sep=sep, format=format)
 
     def fill(self, value):
         self.__setitem__(Ellipsis, value)
@@ -671,7 +679,7 @@ def _lazy_method(np_name: str, jnp_fn):
             # accumulator to the platform int (or the caller explicitly
             # asked for a 64-bit one, e.g. a.sum(dtype=np.int64), which jax
             # would silently truncate); compute on host, exact.
-            return getattr(real_np.asarray(self._arr), np_name)(
+            return getattr(self._host(), np_name)(
                 *_unwrap_np(list(args)),
                 **{k: _unwrap_np(v) for k, v in kwargs.items()},
             )
@@ -715,7 +723,7 @@ def _binop(name: str, jnp_fn, swap: bool = False):
             host = getattr(real_np.ndarray, name, None)
             if host is None:
                 return NotImplemented
-            return host(real_np.asarray(self._arr), other)
+            return host(self._host(), other)
         if isinstance(other, (TpuArray, jax.Array, real_np.ndarray, int, float,
                               bool, complex, real_np.generic)):
             args = (other, self) if swap else (self, other)
@@ -1273,10 +1281,11 @@ def _histogram_overrides(todays: "_Dispatcher") -> dict[str, Callable]:
         edges = real_np.histogram_bin_edges(seen, bins, range)
         if edges.dtype.kind not in "iuf" or not real_np.isfinite(edges).all():
             return todays(a, **call)  # an edge at infinity closes no bin in a float comparison
-        counts = _histogram_program(a._arr, _edges_on_device(edges, dtype), _unwrap_jnp(weights))
+        counts = lazy.dispatched(
+            _histogram_program, a._arr, _edges_on_device(edges, dtype), _unwrap_jnp(weights))
         lazy.counters.histograms += 1
         if density:
-            counts = real_np.asarray(counts)
+            counts = lazy.fetch(counts)
             return counts / real_np.array(real_np.diff(edges), float) / counts.sum(), edges
         return TpuArray(counts), edges
 
@@ -1314,16 +1323,17 @@ def _load_overrides(threshold: int) -> dict[str, Callable]:
     `np.frombuffer` over memory that can still be written (a bytearray, an
     mmap, shared memory) stays numpy's view of it: a write on either side is
     seen on the other, which a device array could not give. Over `bytes` or a
-    read-only buffer the view is read-only too, and is placed."""
+    read-only buffer the view is read-only too, and is placed. numpy's own
+    call is counted and timed by `lazy.read`, whatever becomes of the array."""
     def placing(np_fn):
         @functools.wraps(np_fn)
         def load(*args, **kwargs):
-            return _placed(np_fn(*args, **kwargs), threshold)
+            return _placed(lazy.read(np_fn, *args, **kwargs), threshold)
         return load
 
     @functools.wraps(real_np.frombuffer)
     def frombuffer(*args, **kwargs):
-        view = real_np.frombuffer(*args, **kwargs)
+        view = lazy.read(real_np.frombuffer, *args, **kwargs)
         return view if view.flags.writeable else _placed(view, threshold)
 
     return {

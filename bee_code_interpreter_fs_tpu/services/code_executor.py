@@ -136,27 +136,24 @@ STAGE_PHASES = (
     "runner_after_user",
 )
 # What the numpy shim counted during the turn's user code (npdispatch's
-# `lazy.Counters`, taken by the warm runner), stamped into Result.phases on
-# every served turn of a runner that has the shim installed, 0 where it did
-# nothing. Counts and bytes, and two durations (`shim_host` and `shim_h2d`,
-# seconds): none is in LATENCY_PHASES, so the histogram sees none of them.
+# `lazy.Counters`, whose docstring says what each field means; taken by the
+# warm runner), stamped into Result.phases on every served turn of a runner
+# that has the shim installed, 0 where it did nothing: the field's name after
+# `shim_`, a duration's `_s` left off. Counts, bytes, and six durations in
+# seconds (`shim_load`, `shim_h2d`, `shim_host`, `shim_dispatch`, `shim_wait`,
+# `shim_d2h`: the stages that, with a remainder, tile `runner_user_code`).
+# None is in LATENCY_PHASES, so the histogram sees none of them.
 SHIM_PHASES = {
-    "programs": "shim_programs",
-    "exec_cache_misses": "shim_exec_cache_misses",
-    "nodes": "shim_nodes",
-    "flushes": "shim_flushes",
-    "h2d_arrays": "shim_h2d_arrays",
-    "h2d_bytes": "shim_h2d_bytes",
-    "h2d_s": "shim_h2d",
-    "donated_bytes": "shim_donated_bytes",
-    "aligned_stores": "shim_aligned_stores",
-    "kernel_stores": "shim_kernel_stores",
-    "histograms": "shim_histograms",
-    "dots": "shim_dots",
-    "dot_flops": "shim_dot_flops",
-    "ufunc_methods": "shim_ufunc_methods",
-    "fallbacks": "shim_fallbacks",
-    "host_s": "shim_host",
+    name: "shim_" + name.removesuffix("_s")
+    for name in (
+        "programs", "exec_cache_misses", "nodes", "flushes",
+        "load_files", "load_bytes", "load_s",
+        "h2d_arrays", "h2d_bytes", "h2d_s",
+        "donated_bytes", "aligned_stores", "kernel_stores", "histograms",
+        "dots", "dot_flops", "ufunc_methods", "fallbacks",
+        "host_s", "dispatch_s", "wait_s",
+        "d2h_arrays", "d2h_bytes", "d2h_s",
+    )
 }
 _RUNNER_BEFORE_USER = ("runner.prepare", "runner.profile_start", "runner.limits_arm")
 _RUNNER_AFTER_USER = ("runner.limits_restore", "runner.profile_stop", "runner.finish")
@@ -3410,6 +3407,7 @@ class CodeExecutor:
         stats.emit(self.metrics)
         phases = {**timer.as_dict(), **stats.as_phases()}
         phases.update(self._stage_phases(primary, phases.get("exec", 0.0)))
+        phases.update(self._user_cpu_phase(primary))
         phases.update(self._compile_cache_phases(sandbox, bodies))
         phases.update(self._shim_phases(primary))
         # Device-memory accounting: the hosts' wire blocks folded into
@@ -3423,6 +3421,9 @@ class CodeExecutor:
         # store — the tenant neither asked for nor receives it, and (the
         # PR 9 trusted-run rule) must not be billed its transfer.
         auto_profile = _auto_profile_var.get()
+        # A mark on the turn the service's own profiler captured: whoever
+        # reads a window's phases can tell which turns it reached into.
+        phases["auto_profiled"] = 0.0 if auto_profile is None else 1.0
         harvested_bytes = 0
         if auto_profile is not None:
             harvested_bytes = await self._harvest_profile(
@@ -3507,17 +3508,33 @@ class CodeExecutor:
         return {key: round(value, 6) for key, value in phases.items()}
 
     @staticmethod
+    def _user_cpu_phase(body) -> dict[str, float]:
+        """`runner_user_cpu` from host 0's `user_cpu_s`: the runner process's
+        CPU seconds (user and system, every thread) inside its `user_code`
+        stage, which says of that stage's wall whether the host computed or
+        slept. Nothing where the runner sent no number (a cold run, an older
+        runner)."""
+        value = body.get("user_cpu_s")
+        if isinstance(value, bool) or not isinstance(value, (int, float)):
+            return {}
+        return {"runner_user_cpu": round(max(0.0, float(value)), 6)}
+
+    @staticmethod
     def _shim_phases(body) -> dict[str, float]:
         """SHIM_PHASES from host 0's `shim` block; nothing where the runner
-        sent none (no shim installed, a cold run, an older binary). Only the
-        known names, and only numbers: the block comes from the process that
-        ran the user's code."""
+        sent none (no shim installed, a cold run, an older binary), and no key
+        for a name the block lacks (a runner from before the counter): a
+        metric that reads it then finds nothing, never a 0 that was not
+        measured. Only the known names, and only numbers: the block comes from
+        the process that ran the user's code."""
         block = body.get("shim")
         if not isinstance(block, dict):
             return {}
         phases = {}
         for name, key in SHIM_PHASES.items():
-            value = block.get(name, 0)
+            if name not in block:
+                continue
+            value = block[name]
             if isinstance(value, bool) or not isinstance(value, (int, float)):
                 value = 0
             phases[key] = round(max(0.0, float(value)), 6)

@@ -383,10 +383,20 @@ def summarize_profile(data: bytes, *, top_n: int = 10) -> dict:
 
     A capture that holds no device process (a CPU run; a turn that ran no
     device program) says ``device_plane: false`` and gives no busy share, no
-    device ops and no idle gaps: host events are not the device's. Where the
-    capture holds the warm runner's ``runner.*`` stage annotations (every
-    profiled turn's does), each idle gap is named by the stage that covers
-    its start (``during``), and the stages' own lengths are listed.
+    device ops and no idle gaps: host events are not the device's.
+
+    The capture shares its clock with the host: the warm runner's stages
+    (``runner.*``, every profiled turn's capture holds them) and the numpy
+    shim's (``shim.load``, ``shim.h2d``, ``shim.materialize``, ``shim.wait``,
+    ``shim.d2h``) are annotations of its host plane. ONE rule says what the
+    host was doing while the device sat idle: every idle microsecond, from
+    the capture's first event to its last, belongs to the innermost such
+    annotation that covers it, and an idle stretch that crosses an
+    annotation's boundary is split there. ``idle_by`` sums the idle time by
+    annotation (``none`` where none covers it) and, with ``device_busy_ms``,
+    adds up to ``span_ms``; ``idle_gaps`` lists the five longest stretches,
+    each ``during`` its annotation; the stages' own lengths are listed
+    (``runner_stages``; ``shim_stages``: count and total of each).
 
     Durations in the trace-event format are microseconds; everything here
     reports milliseconds."""
@@ -437,7 +447,8 @@ def summarize_profile(data: bytes, *, top_n: int = 10) -> dict:
     }
     ops: dict[str, list[float]] = {}
     device_spans: list[tuple[float, float]] = []
-    # The warm runner's stage annotations (host plane): (start, end, name).
+    # The warm runner's and the shim's stage annotations (host plane):
+    # (start, end, name).
     stages: list[tuple[float, float, str]] = []
     t_min = math.inf
     t_max = -math.inf
@@ -458,7 +469,7 @@ def summarize_profile(data: bytes, *, top_n: int = 10) -> dict:
             bucket = ops.setdefault(name, [0.0, 0.0])
             bucket[0] += float(dur)
             bucket[1] += 1.0
-        elif name.startswith("runner."):
+        elif name.startswith(("runner.", "shim.")):
             stages.append((float(ts), float(ts) + float(dur), name))
     if not math.isfinite(t_min):
         return {
@@ -474,7 +485,18 @@ def summarize_profile(data: bytes, *, top_n: int = 10) -> dict:
             "duration_ms": round((end - start) / 1e3, 3),
         }
         for start, end, name in stages
+        if name.startswith("runner.")
     ]
+    shim_totals: dict[str, list[float]] = {}  # name -> [count, microseconds]
+    for start, end, name in stages:
+        if name.startswith("shim."):
+            total = shim_totals.setdefault(name, [0, 0.0])
+            total[0] += 1
+            total[1] += end - start
+    shim_stages = {
+        name: {"count": count, "total_ms": round(total / 1e3, 3)}
+        for name, (count, total) in shim_totals.items()
+    }
     if not device_spans:
         summary = {
             "verdict": (
@@ -488,27 +510,52 @@ def summarize_profile(data: bytes, *, top_n: int = 10) -> dict:
         }
         if runner_stages:
             summary["runner_stages"] = runner_stages
+        if shim_stages:
+            summary["shim_stages"] = shim_stages
         return summary
 
-    def stage_at(moment: float) -> str | None:
-        """The innermost runner stage that covers `moment`."""
-        covering = [s for s in stages if s[0] <= moment < s[1]]
-        return max(covering, key=lambda s: s[0])[2] if covering else None
+    def split(start: float, end: float) -> list[tuple[float, float, str | None]]:
+        """The idle stretch [start, end) as (start, length, annotation): cut
+        wherever an annotation begins or ends inside it, each piece named by
+        the innermost annotation that covers it (the one that began last)."""
+        inside = [s for s in stages if s[0] < end and s[1] > start]
+        cuts = sorted(
+            {start, end}
+            | {min(max(edge, start), end) for s in inside for edge in s[:2]}
+        )
+        pieces: list[tuple[float, float, str | None]] = []
+        for begin, until in zip(cuts, cuts[1:]):
+            middle = (begin + until) / 2
+            covering = [s for s in inside if s[0] <= middle < s[1]]
+            name = max(covering, key=lambda s: (s[0], -s[1]))[2] if covering else None
+            if pieces and pieces[-1][2] == name:
+                pieces[-1] = (pieces[-1][0], pieces[-1][1] + until - begin, name)
+            else:
+                pieces.append((begin, until - begin, name))
+        return pieces
 
     # Busy wall = the union of device spans (ops overlap across cores);
-    # idle gaps are the holes in that union over the capture window.
+    # idle time is the capture's window less that union: before the first
+    # device op, the holes between ops, after the last.
     device_spans.sort()
     busy_us = 0.0
-    gaps: list[tuple[float, float]] = []
+    gaps: list[tuple[float, float, str | None]] = []
     cur_start, cur_end = device_spans[0]
+    if cur_start > t_min:
+        gaps.extend(split(t_min, cur_start))
     for start, end in device_spans[1:]:
         if start <= cur_end:
             cur_end = max(cur_end, end)
             continue
         busy_us += cur_end - cur_start
-        gaps.append((cur_end, start - cur_end))
+        gaps.extend(split(cur_end, start))
         cur_start, cur_end = start, end
     busy_us += cur_end - cur_start
+    if t_max > cur_end:
+        gaps.extend(split(cur_end, t_max))
+    idle_by: dict[str, float] = {}
+    for _start, length, name in gaps:
+        idle_by[name or "none"] = idle_by.get(name or "none", 0.0) + length
     span_us = max(t_max - t_min, 1e-9)
     total_op_us = sum(total for total, _count in ops.values()) or 1e-9
     gaps.sort(key=lambda g: g[1], reverse=True)
@@ -526,12 +573,11 @@ def summarize_profile(data: bytes, *, top_n: int = 10) -> dict:
         + (f"; top op: {top_ops[0][0]}" if top_ops else "")
     )
     idle_gaps = []
-    for start, length in gaps[:5]:
+    for start, length, during in gaps[:5]:
         gap = {
             "offset_ms": round((start - t_min) / 1e3, 3),
             "duration_ms": round(length / 1e3, 3),
         }
-        during = stage_at(start)
         if during is not None:
             gap["during"] = during
         idle_gaps.append(gap)
@@ -552,9 +598,15 @@ def summarize_profile(data: bytes, *, top_n: int = 10) -> dict:
             for name, (total, count) in top_ops
         ],
         "idle_gaps": idle_gaps,
+        "idle_by": {
+            name: round(total / 1e3, 3)
+            for name, total in sorted(idle_by.items(), key=lambda kv: -kv[1])
+        },
     }
     if runner_stages:
         summary["runner_stages"] = runner_stages
+    if shim_stages:
+        summary["shim_stages"] = shim_stages
     return summary
 
 

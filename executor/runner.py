@@ -13,9 +13,12 @@ Protocol: newline-delimited JSON. fd 3 = requests in, fd 4 = responses out.
 Request:  {"source_path": ..., "stdout_path": ..., "stderr_path": ..., "env": {...},
            "sent_mono": <the server's CLOCK_MONOTONIC at the pipe write>}
 Response: {"exit_code": int, "stages": [[name, start_offset_s, duration_s], ...],
+           "user_cpu_s": the process's CPU seconds inside the `user_code` stage,
            "shim": {<counter>: number, ...} where the numpy shim is installed}
 Ready line (sent once at boot):
-  {"ready": true, "backend": ..., "device_count": n, "device_kind": ...}
+  {"ready": true, "backend": ..., "device_count": n, "device_kind": ...,
+   "attach_stages": {"interpreter_start": s, "import_jax": s, "distributed_init": s,
+                     "devices": s, "first_compile": s}}
 
 User scripts run in-process via runpy with stdout/stderr redirected at the fd
 level, fresh sys.argv, and __main__ semantics.
@@ -105,6 +108,7 @@ def _cache_counts() -> tuple[int, int]:
 # a fixed set (the server's allow-list; they label a histogram upstream).
 
 _STAGES: list = []  # [(name, started)] of the request in hand
+_USER_CPU: list = []  # CPU seconds of its `user_code` stage (`_run_one`), until taken
 _STAGE_ANNOTATION: list = []  # the open TraceAnnotation, while a capture runs
 _ANNOTATING = False  # True only between the profiler's start and its stop
 # [(started, seconds)] of the full collection after the last reset's ack,
@@ -150,6 +154,7 @@ def _stage(name: str, started: float | None = None) -> None:
 
 def _begin_stages(name: str, started: float) -> None:
     del _STAGES[:]
+    del _USER_CPU[:]
     _annotate(False)
     _stage(name, started)
 
@@ -173,11 +178,23 @@ def _take_stages(sent_mono) -> list | None:
     return out
 
 
+def _process_cpu_s() -> float:
+    """The process's CPU seconds so far, user and system, every thread: the
+    clock the CPU guard reads (`_apply_user_rlimits`)."""
+    import resource
+
+    usage = resource.getrusage(resource.RUSAGE_SELF)
+    return usage.ru_utime + usage.ru_stime
+
+
+def _take_user_cpu() -> float | None:
+    """What `_run_one` measured, once; None where no user code ran."""
+    return round(_USER_CPU.pop(), 6) if _USER_CPU else None
+
+
 def _take_shim() -> dict | None:
-    """The numpy shim's counters of the request in hand (programs run, runner
-    cache misses, nodes, flushes, host arrays shipped with their bytes and
-    seconds, bytes donated, contractions and their operations, ufunc methods,
-    host seconds inside the shim), taken and zeroed
+    """The numpy shim's counters of the request in hand (`lazy.Counters` in
+    `ops/npdispatch`, whose docstring lists them), taken and zeroed
     the way `_take_stages` takes the stage clocks; None in a runner whose
     interpreter started without the shim."""
     shim = sys.modules.get("bee_code_interpreter_fs_tpu.ops.npdispatch")
@@ -242,14 +259,33 @@ def _warm_import() -> dict:
         # Explicit escape hatch (plumbing tests / no-JAX dev); on a slice
         # this forgoes the mesh knowingly.
         return info
+    # Where the attach's seconds go, step by step (GET /device-stats beside
+    # `attach_seconds`, and one line of the sandbox's log). `interpreter_start`
+    # is everything before this function: python's own start and
+    # sitecustomize, which imports jax and numpy to install the numpy shim, so
+    # that `import_jax` here finds the module loaded.
+    stages = info["attach_stages"] = {}
+    age = _process_age_s()
+    if age is not None:
+        stages["interpreter_start"] = age
+    mark = time.monotonic()
+
+    def step_done(name: str) -> None:
+        nonlocal mark
+        now = time.monotonic()
+        stages[name] = round(now - mark, 6)
+        mark = now
+
     try:
         cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
         import jax
 
+        step_done("import_jax")
         if cache_dir:
             _register_cache_listener()
 
         _distributed_init(jax)
+        step_done("distributed_init")
         if cache_dir:
             jax.config.update("jax_compilation_cache_dir", cache_dir)
             # Persist every kernel: the default 1s min-compile-time filter
@@ -259,6 +295,7 @@ def _warm_import() -> dict:
             jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
             jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         devices = jax.devices()
+        step_done("devices")
         info["backend"] = devices[0].platform
         info["device_count"] = len(devices)  # global across the slice
         # Device kind for the telemetry plane ("TPU v5 lite" etc.; CPU
@@ -278,11 +315,26 @@ def _warm_import() -> dict:
         import jax.numpy as jnp
 
         jnp.add(jnp.ones(()), 1.0).block_until_ready()
+        step_done("first_compile")
     except Exception:  # noqa: BLE001 — reported to the server, then fatal
         traceback.print_exc()
         _log("fatal: jax warm-up failed")
         info["ready"] = False
     return info
+
+
+def _process_age_s() -> float | None:
+    """Seconds since this process was started, by the kernel's own record
+    (/proc: the start in clock ticks since boot, against the uptime; both to
+    10 ms); None where /proc does not say."""
+    try:
+        with open("/proc/self/stat") as f:
+            started_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        return round(max(0.0, uptime - started_ticks / os.sysconf("SC_CLK_TCK")), 3)
+    except (OSError, ValueError, IndexError):
+        return None
 
 
 def _profile_requested(env: dict) -> bool:
@@ -472,8 +524,7 @@ def _apply_user_rlimits(limits: dict | None = None):
         cpu_budget = 0.0
     if cpu_budget > 0:
         try:
-            usage = resource.getrusage(resource.RUSAGE_SELF)
-            spent = usage.ru_utime + usage.ru_stime
+            spent = _process_cpu_s()
 
             def on_xcpu(signum, frame):
                 raise _CpuTimeExceeded(
@@ -613,9 +664,11 @@ def _run_one(req: dict) -> tuple[int, str | None]:
     try:
         sys.argv = [source_path]  # argv[0] stays the user's path
         _stage("user_code")
+        cpu_before = _process_cpu_s()
         try:
             runpy.run_path(run_path, run_name="__main__")
         finally:
+            _USER_CPU[:] = [_process_cpu_s() - cpu_before]
             _stage("limits_restore")
     except SystemExit as e:
         exit_code = _exit_code_of(e)
@@ -1417,6 +1470,9 @@ def main() -> None:
                     )
                     exit_code, violation = _run_one(req)
                     reply = {"exit_code": exit_code}
+                    user_cpu = _take_user_cpu()
+                    if user_cpu is not None:
+                        reply["user_cpu_s"] = user_cpu
                     shim = _take_shim()
                     if shim is not None:
                         reply["shim"] = shim

@@ -636,9 +636,13 @@ std::atomic<long long> g_runner_line_ms{0};
 std::atomic<long long> g_runner_pid_stat{0};
 std::atomic<bool> g_runner_ready_stat{false};
 std::atomic<int> g_device_count_stat{0};
-std::mutex g_device_info_mutex;  // guards the two strings below only
+std::mutex g_device_info_mutex;  // guards the three values below only
 std::string g_device_backend_stat = "none";
 std::string g_device_kind_stat;
+// Where the last attach's seconds went, as the runner's ready line said it
+// (`attach_stages`: interpreter_start, import_jax, distributed_init, devices,
+// first_compile).
+minijson::Value g_attach_stages_stat;
 
 // cgroup-v2 hard enforcement (cgroup.hpp): the boot-time delegation verdict,
 // the long-lived scope boxing the warm runner group (bounded by the
@@ -747,12 +751,14 @@ class WarmRunner {
       return false;
     }
     std::string device_kind;
+    minijson::Value attach_stages;
     try {
       auto msg = minijson::parse(line);
       ready_ = msg.get_bool("ready", false);
       backend_ = msg.get_string("backend", "unknown");
       device_count_ = static_cast<int>(msg.get_number("device_count", 0));
       device_kind = msg.get_string("device_kind", "");
+      attach_stages = msg.get("attach_stages");
     } catch (...) {
       ready_ = false;
     }
@@ -763,9 +769,12 @@ class WarmRunner {
       std::lock_guard<std::mutex> dlock(g_device_info_mutex);
       g_device_backend_stat = backend_;
       g_device_kind_stat = device_kind;
+      g_attach_stages_stat = attach_stages;
     }
     log_msg("warm runner ready=%d backend=%s devices=%d", (int)ready_,
             backend_.c_str(), device_count_);
+    if (attach_stages.is_object())
+      log_msg("warm runner attach stages (s): %s", attach_stages.dump().c_str());
     // ready=false is the runner saying its jax warm-up failed or attached
     // another platform than it was started for: reap it (it may hold the
     // chip) and let the warm-state machine report the failure.
@@ -1625,6 +1634,9 @@ struct RunOutcome {
   // (programs, cache misses, nodes, flushes, bytes shipped and donated, host
   // seconds); absent from a runner without the shim and from a cold run.
   minijson::Value shim;
+  // The runner's CPU seconds inside its `user_code` stage, as it sent them;
+  // absent from a cold run.
+  minijson::Value user_cpu_s;
 };
 
 // One entry of a `trace` block: a stage named `name`, nested in time (and,
@@ -1774,6 +1786,7 @@ RunOutcome run_user_code(const std::string& script_path,
             out.device_memory = resp.get("device_memory");
             out.runner_stages = resp.get("stages");
             out.shim = resp.get("shim");
+            out.user_cpu_s = resp.get("user_cpu_s");
             break;
           case WarmRunner::ExecResult::kTimeout:
             out.timed_out = true;
@@ -2382,6 +2395,7 @@ void handle_execute_impl(const minihttp::Request& req, minihttp::Conn& conn,
   // The numpy shim's counters, forwarded as sent: the control plane reads
   // the names it knows, as numbers, and nothing else.
   if (run.shim.is_object()) resp["shim"] = run.shim;
+  if (run.user_cpu_s.is_number()) resp["user_cpu_s"] = run.user_cpu_s;
   resp["warm"] = minijson::Value(ran_warm);
   // True when the warm runner was killed (timeout) or died during this
   // request: its in-process state is gone and a rewarm is in flight. The
@@ -2937,6 +2951,10 @@ void handle_device_stats(const minihttp::Request&, minihttp::Conn& conn) {
     std::lock_guard<std::mutex> dlock(g_device_info_mutex);
     resp["backend"] = minijson::Value(g_device_backend_stat);
     resp["device_kind"] = minijson::Value(g_device_kind_stat);
+    // The last attach's steps as the runner timed them, beside
+    // `attach_seconds` below (which also holds the spawn and the ready line).
+    if (g_attach_stages_stat.is_object())
+      resp["attach_stages"] = g_attach_stages_stat;
   }
   resp["device_count"] = minijson::Value(g_device_count_stat.load());
   resp["num_hosts"] = minijson::Value(g_state.num_hosts);

@@ -1,6 +1,6 @@
 """The numpy shim's counters end to end (ISSUE 31): a `/v1/execute` of array
 code through the HTTP API, the real C++ executor and a warm runner that has
-the shim installed comes back with the sixteen `shim_*` keys in
+the shim installed comes back with every `shim_*` key (`SHIM_PHASES`) in
 `Result.phases`; the next turn, on the sandbox that `/reset` put back, reads
 0 where the shim did nothing; and no histogram observes any of them. A
 sandbox without the shim (the no-JAX plumbing mode) stamps none. Nothing
@@ -112,6 +112,26 @@ async def test_a_turn_over_an_uploaded_file_ships_it_once(tmp_path):
         assert phases["upload_bytes"] == phases["shim_h2d_bytes"] == float(data.nbytes)
         assert phases["shim_h2d_arrays"] == 1.0 and phases["shim_h2d"] > 0.0 and phases["shim_fallbacks"] == 0.0
         assert phases["shim_histograms"] == 1.0
+        # ISSUE 39: what the turn's user code did with its seconds. numpy read the
+        # file once, every byte of it; the values it printed came back through
+        # `fetch` (two floats, the bins); the six stages lie inside the runner's
+        # `user_code` stage, none counted twice; the turn's host CPU beside them.
+        assert phases["shim_load_files"] == 1.0 and phases["shim_load_bytes"] == float(data.nbytes)
+        assert phases["shim_d2h_arrays"] >= 3.0 and phases["shim_d2h_bytes"] >= 4 + 4 + 7 * 4
+        stages = [phases[key] for key in ("shim_load", "shim_h2d", "shim_host", "shim_dispatch", "shim_wait", "shim_d2h")]
+        assert all(seconds > 0.0 for seconds in stages), stages
+        assert sum(stages) <= phases["runner_user_code"]
+        assert 0.0 < phases["runner_user_cpu"] < 60.0
+        assert phases["auto_profiled"] == 0.0
+        # ... and where the sandbox's attach went, beside `attach_seconds`
+        import httpx
+
+        (_lane, sandbox), = executor.live_hosts()
+        async with httpx.AsyncClient() as probe:
+            stats = (await probe.get(f"{sandbox.url}/device-stats")).json()
+        assert list(stats["attach_stages"]) == ["devices", "distributed_init", "first_compile", "import_jax", "interpreter_start"]
+        assert all(isinstance(v, float) and v >= 0.0 for v in stats["attach_stages"].values())
+        assert sum(stats["attach_stages"].values()) <= stats["attach_seconds"] + 0.01
     finally:
         await client.close()
         await executor.close()
@@ -162,6 +182,13 @@ async def test_a_sandbox_without_the_shim_stamps_no_shim_phase(tmp_path):
         body = await resp.json()
         assert body["stdout"] == "42\n"
         assert not set(SHIM_PHASES.values()) & set(body["phases"])
+        # the turn's host CPU is the runner's, shim or none; no jax, so no attach to stage
+        assert body["phases"]["runner_user_cpu"] >= 0.0 and body["phases"]["auto_profiled"] == 0.0
+        import httpx
+
+        (_lane, sandbox), = executor.live_hosts()
+        async with httpx.AsyncClient() as probe:
+            assert "attach_stages" not in (await probe.get(f"{sandbox.url}/device-stats")).json()
     finally:
         await client.close()
         await executor.close()

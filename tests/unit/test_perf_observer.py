@@ -492,6 +492,9 @@ def test_auto_profiled_request_harvests_and_bills_zero_transfer():
         assert rows[0]["reason"] == "regression:exec"
         assert rows[0]["tenant"] == "acct"
         assert rows[0]["trace_id"] == result.phases.get("trace_id")
+        # ... and the turn carries the mark, so that whoever reads a
+        # window's phases can tell the service's own captures (ISSUE 39).
+        assert result.phases["auto_profiled"] == 1.0
         data, _meta = executor.perf.store.get(rows[0]["id"])
         assert data == profile_bytes
         # Zero transfer bytes billed for the harvest (the PR 9
@@ -526,6 +529,7 @@ def test_client_requested_profile_is_not_harvested():
         # The tenant profiled itself: the zip stays in its files, the
         # bytes bill normally, nothing enters the store.
         assert "/workspace/profile.zip" in result.files
+        assert result.phases["auto_profiled"] == 0.0, "the client's own capture is no auto-profile"
         assert executor.perf.store.entry_count() == 0
         row = executor.usage.tenant_snapshot("acct")
         assert row["download_bytes"] == float(len(profile_bytes))
@@ -644,10 +648,11 @@ def test_summarize_profile_verdict_top_ops_share_and_gaps():
     assert summary["top_ops"][0]["total_ms"] == 7.0
     assert summary["top_ops"][0]["count"] == 2
     assert "python busywork" not in [op["name"] for op in summary["top_ops"]]
-    # The idle gap between the two busy stretches.
+    # The idle gap between the two busy stretches: under no annotation.
     assert summary["idle_gaps"] == [
         {"offset_ms": 4.0, "duration_ms": 2.0}
     ]
+    assert summary["idle_by"] == {"none": 2.0}
     assert "device busy 80%" in summary["verdict"]
     assert "fusion.3" in summary["verdict"]
 
@@ -679,8 +684,10 @@ def test_summarize_profile_without_a_device_process_claims_no_device_time():
 
 
 def test_summarize_profile_names_idle_gaps_by_the_runner_stage():
-    """Where the capture holds the warm runner's `runner.*` annotations,
-    each idle gap says which stage covered its start."""
+    """Where the capture holds the warm runner's `runner.*` annotations and
+    no `shim.*` one, the idle time reads by runner stage: each idle stretch
+    belongs to the stage that covers it, and one that crosses from a stage
+    into the next is split where the stage ends."""
     from bee_code_interpreter_fs_tpu.services.perf_observer import (
         summarize_profile,
     )
@@ -704,12 +711,74 @@ def test_summarize_profile_names_idle_gaps_by_the_runner_stage():
     assert summary["device_busy_ms"] == 5.0
     assert summary["idle_gaps"] == [
         {"offset_ms": 2.0, "duration_ms": 3.0, "during": "runner.user_code"},
-        {"offset_ms": 8.0, "duration_ms": 2.0, "during": "runner.user_code"},
+        {"offset_ms": 8.0, "duration_ms": 1.0, "during": "runner.user_code"},
+        {"offset_ms": 9.0, "duration_ms": 1.0, "during": "runner.limits_restore"},
+        {"offset_ms": 0.0, "duration_ms": 0.5, "during": "runner.limits_arm"},  # before the first device op
     ]
+    assert summary["idle_by"] == {
+        "runner.user_code": 4.0, "runner.limits_restore": 1.0, "runner.limits_arm": 0.5,
+    }
+    assert sum(summary["idle_by"].values()) + summary["device_busy_ms"] == summary["span_ms"] == 10.5
+    assert "shim_stages" not in summary
     assert [s["name"] for s in summary["runner_stages"]] == [
         "runner.limits_arm", "runner.user_code", "runner.limits_restore",
     ]
     assert "runner.user_code" not in [op["name"] for op in summary["top_ops"]]
+
+
+def test_summarize_profile_puts_idle_time_down_to_the_innermost_annotation():
+    """ISSUE 39: the shim's stages are annotations of the capture's host plane
+    beside the runner's. Every idle microsecond belongs to the innermost one
+    that covers it; a stretch that crosses a boundary is split there; what
+    `idle_by` sums and the device's busy time add up to the capture."""
+    from bee_code_interpreter_fs_tpu.services.perf_observer import (
+        summarize_profile,
+    )
+
+    events = [
+        {"ph": "M", "name": "process_name", "pid": 1,
+         "args": {"name": "/device:TPU:0"}},
+        {"ph": "M", "name": "process_name", "pid": 2,
+         "args": {"name": "/host:CPU"}},
+        {"ph": "X", "pid": 2, "name": "runner.limits_arm", "ts": 0, "dur": 1000},
+        {"ph": "X", "pid": 2, "name": "runner.user_code", "ts": 1000, "dur": 19000},
+        # inside the user's code: a file read, its copy, a program, the wait
+        # for its value, the copy back; then python of the user's own
+        {"ph": "X", "pid": 2, "name": "shim.load", "ts": 2000, "dur": 6000},
+        {"ph": "X", "pid": 2, "name": "shim.h2d", "ts": 8000, "dur": 500},
+        {"ph": "X", "pid": 2, "name": "shim.materialize", "ts": 9000, "dur": 1000},
+        {"ph": "X", "pid": 2, "name": "shim.wait", "ts": 10000, "dur": 5000},
+        {"ph": "X", "pid": 2, "name": "shim.d2h", "ts": 15000, "dur": 200},
+        # the device: a copy's tail, then the program, which the wait covers
+        {"ph": "X", "pid": 1, "name": "copy.1", "ts": 8200, "dur": 300},
+        {"ph": "X", "pid": 1, "name": "fusion.4", "ts": 11000, "dur": 3900},
+        {"ph": "X", "pid": 2, "name": "python busywork", "ts": 15200, "dur": 4800},
+    ]
+    summary = summarize_profile(_trace_zip(events))
+    assert summary["span_ms"] == 20.0 and summary["device_busy_ms"] == 4.2
+    # ONE stretch, 0 to 8200 us, crosses limits_arm -> user_code -> load -> h2d
+    # and is split at each boundary; 8500 to 11000 crosses h2d's end, the
+    # dispatch and into the wait.
+    assert summary["idle_by"] == {
+        "shim.load": 6.0, "runner.user_code": 6.3, "shim.wait": 1.1, "runner.limits_arm": 1.0,
+        "shim.materialize": 1.0, "shim.h2d": 0.2, "shim.d2h": 0.2,
+    }
+    assert sum(summary["idle_by"].values()) + summary["device_busy_ms"] == pytest.approx(summary["span_ms"])
+    assert summary["idle_gaps"] == [
+        {"offset_ms": 2.0, "duration_ms": 6.0, "during": "shim.load"},
+        {"offset_ms": 15.2, "duration_ms": 4.8, "during": "runner.user_code"},
+        {"offset_ms": 0.0, "duration_ms": 1.0, "during": "runner.limits_arm"},
+        {"offset_ms": 1.0, "duration_ms": 1.0, "during": "runner.user_code"},
+        {"offset_ms": 9.0, "duration_ms": 1.0, "during": "shim.materialize"},
+    ]
+    assert "largest idle gap 6.0ms" in summary["verdict"]
+    assert [s["name"] for s in summary["runner_stages"]] == ["runner.limits_arm", "runner.user_code"]
+    assert summary["shim_stages"] == {
+        "shim.load": {"count": 1, "total_ms": 6.0}, "shim.h2d": {"count": 1, "total_ms": 0.5},
+        "shim.materialize": {"count": 1, "total_ms": 1.0}, "shim.wait": {"count": 1, "total_ms": 5.0},
+        "shim.d2h": {"count": 1, "total_ms": 0.2},
+    }
+    assert not {op["name"] for op in summary["top_ops"]} & {"shim.load", "shim.wait", "runner.user_code"}
 
 
 def test_summarize_profile_degrades_without_a_trace_member():
